@@ -14,8 +14,8 @@ its DeviceCluster image alive across batches and ships only what changed:
 
 This is the host→HBM half of SURVEY.md §2.4's "informer delta stream →
 append-only update buffer DMA'd into HBM" design, replacing the previous
-full `DeviceCluster.from_host` per batch (hundreds of ms over a remote
-device link at 5k-node scale; the delta is ~100 KB).
+full `DeviceCluster.from_host` per batch (the whole snapshot re-uploaded
+at 5k-node scale; the delta is ~100 KB).
 """
 
 from __future__ import annotations

@@ -380,6 +380,17 @@ def _bucket_key(args, kwargs) -> tuple:
     return tuple(toks) + (("devices", ndev, mesh_shape),), ndev, mesh_shape
 
 
+def _in_trace(args, kwargs) -> bool:
+    """True when the call is part of an outer trace (one root tracing
+    through another, ``eval_shape``/``lower`` of the wrapper): some
+    argument leaf is a ``jax.core.Tracer`` — the public marker.  Host
+    dispatches only ever carry concrete arrays and statics."""
+    return any(
+        isinstance(leaf, jax.core.Tracer)
+        for leaf in jax.tree_util.tree_leaves((args, kwargs))
+    )
+
+
 def _abstract_spec(args, kwargs):
     """(args, kwargs) with array leaves replaced by ShapeDtypeStruct —
     retained per bucket for the lazy cost lowering.  Never holds the
@@ -469,7 +480,7 @@ class DispatchLedger:
         consumed).  Either way the per-kernel breaker books the failure,
         and an abandoned dispatch raises ``DispatchFailed`` for the
         caller's registered fallback engine."""
-        if not jax.core.trace_state_clean():
+        if _in_trace(args, kwargs):
             return fn(*args, **kwargs)
         # an OPEN breaker that a routing gate didn't consult: deny here
         # (counts toward the same half-open cooldown the gates feed)

@@ -1,7 +1,7 @@
 """Chained batch dispatch: gang + on-device self-append of placements.
 
-The throughput ceiling of the batched scheduler on a remote device link is
-host↔device round trips — with a naive loop every batch pays upload + sync +
+The throughput ceiling of the batched scheduler is host↔device round
+trips — with a naive loop every batch pays upload + sync +
 dispatch + fetch latencies.  `chain_dispatch` removes the host from the
 inter-batch critical path: one jit call runs the gang pipeline AND splices
 the batch's own committed pods (rows + flattened affinity terms, the device

@@ -64,7 +64,7 @@ class DTable:
     @classmethod
     def host_tree(cls, t: ConjunctionTable) -> "DTable":
         """numpy-leaved instance — callers device_put whole pytrees at once
-        (ONE transfer instead of one per field; remote device links care)."""
+        (ONE transfer instead of one per field)."""
         return cls(
             req_key=np.asarray(t.req_key, np.int32),
             req_op=np.asarray(t.req_op, np.int32),

@@ -2711,8 +2711,8 @@ class Scheduler:
 
     def _gang_tables(self, pb, vocab):
         """batch_tables' device arrays, reused across batches with the same
-        key sets + node labels (re-uploading them each batch costs transfer
-        round trips on remote device links)."""
+        key sets + node labels (re-uploading them each batch costs a
+        host→device transfer per table)."""
         import numpy as np
 
         hk_id = vocab.label_keys.lookup(HOSTNAME_LABEL)

@@ -93,6 +93,34 @@ def _drain(nodes, pods, return_sched: bool = False, **cfg_kw):
     return got
 
 
+def device_faults(sched) -> List:
+    """Diff rows for a drain whose DEVICE path was abandoned: a booked
+    breaker failure, a breaker that is not closed, or a batch handed to a
+    breaker fallback.  The fallback engines are bit-identical by design,
+    so without this a run in which the chip compiled nothing would still
+    certify zero diffs — fail loud instead (empty list = the device
+    answered every dispatch)."""
+    rows: List = []
+    for kernel, b in sorted(sched.kernels.breaker_rows().items()):
+        if b["state"] != "closed" or b["failures"] or b["trips"]:
+            rows.append(
+                (
+                    f"__breaker__{kernel}",
+                    f"{b['state']} failures={b['failures']} "
+                    f"trips={b['trips']} kind={b['last_kind']}",
+                    "closed",
+                )
+            )
+    for line in sched.prom.kernel_breaker_failures.expose():
+        # the counter never resets on a later success (the row above does)
+        if not line.startswith("#") and float(line.rsplit(" ", 1)[1]) > 0:
+            rows.append(("__breaker_failures__", line, 0))
+    fallbacks = sched.prom.wave_fallback.value(reason="breaker")
+    if fallbacks:
+        rows.append(("__breaker_fallbacks__", int(fallbacks), 0))
+    return rows
+
+
 def _diff(a: Dict, b: Dict) -> List:
     keys = set(a) | set(b)
     return sorted(
@@ -108,11 +136,15 @@ def check_cross_batch(n_nodes=10000, n_pods=50000) -> dict:
     nodes = _basic_nodes(n_nodes)
     pods = _basic_pods(n_pods)
     t0 = time.perf_counter()
-    big = _drain(nodes, copy.deepcopy(pods))
-    small = _drain(
-        nodes, copy.deepcopy(pods), batch_size=64, fast_batch_max=64
+    big, s_big = _drain(nodes, copy.deepcopy(pods), return_sched=True)
+    small, s_small = _drain(
+        nodes,
+        copy.deepcopy(pods),
+        return_sched=True,
+        batch_size=64,
+        fast_batch_max=64,
     )
-    diffs = _diff(big, small)
+    diffs = device_faults(s_big) + device_faults(s_small) + _diff(big, small)
     return {
         "nodes": n_nodes,
         "pods": n_pods,
@@ -152,8 +184,8 @@ def check_compat_vs_oracle(n_nodes=2000, n_pods=3000, seed=77) -> dict:
 
     state = OracleState.build(nodes)
     key = jax.random.PRNGKey(seed)
-    # one device call for ALL attempts' tie-break hashes: per-pod
-    # random.bits round trips cost ~100ms each over a remote device link
+    # one device call for ALL attempts' tie-break hashes instead of a
+    # dispatch + fetch round trip per pod
     h_all = np.asarray(
         jax.vmap(
             lambda a: jax.random.bits(
@@ -252,17 +284,23 @@ def _cross_pod_pods(n, seed=99):
     return pods
 
 
-def check_wave_vs_oracle(n_nodes=500, n_pods=2000) -> dict:
+def check_wave_vs_oracle(
+    n_nodes=500, n_pods=2000, make_pods=_cross_pod_pods, zones=6
+) -> dict:
     """Wave-dispatch drain (speculation + factored conflict resolution,
     ops/wave.py) vs the serial oracle on a mixed spread/anti-affinity
-    workload — the wave's bit-identity evidence at bench scale."""
+    workload — the wave's bit-identity evidence at bench scale.
+    ``make_pods(n)`` swaps the workload (chip_smoke.py passes the
+    TopologySpreading pods: every statics variant of the wave engine is
+    minutes of TPU compile, so its drain and its identity check share
+    one)."""
     import copy
 
     from kubernetes_tpu.oracle.pipeline import schedule_one
     from kubernetes_tpu.oracle.state import OracleState
 
-    nodes = _basic_nodes(n_nodes, zones=6)
-    pods = _cross_pod_pods(n_pods)
+    nodes = _basic_nodes(n_nodes, zones=zones)
+    pods = make_pods(n_pods)
     t0 = time.perf_counter()
     got, sched = _drain(nodes, copy.deepcopy(pods), return_sched=True)
     wave_batches = sched.metrics["wave_batches"]
@@ -275,7 +313,7 @@ def check_wave_vs_oracle(n_nodes=500, n_pods=2000) -> dict:
         if r.node is not None:
             pod.node_name = r.node
             state.place(pod)
-    diffs = _diff(got, want)
+    diffs = device_faults(sched) + _diff(got, want)
     n_diffs = len(diffs)
     if wave_batches == 0:
         # the check exists to certify the WAVE path; a silent fallback to
@@ -286,6 +324,11 @@ def check_wave_vs_oracle(n_nodes=500, n_pods=2000) -> dict:
         "nodes": n_nodes,
         "pods": n_pods,
         "wave_batches": wave_batches,
+        "kernel_dispatches": {
+            r["kernel"]: r["dispatches"]
+            for r in sched.kernels.table(cost=False)
+            if r["dispatches"]
+        },
         "bound_wave": sum(1 for v in got.values() if v),
         "bound_oracle": sum(1 for v in want.values() if v),
         "diffs": n_diffs,
@@ -486,7 +529,9 @@ def check_resident_vs_oracle(n_nodes=1000, n_pods=5000) -> dict:
     t0 = time.perf_counter()
     got, sched = _drain(nodes, copy.deepcopy(pods), return_sched=True)
     resident_batches = sched.metrics["resident_batches"]
-    off = _drain(nodes, copy.deepcopy(pods), resident_drain=False)
+    off, s_off = _drain(
+        nodes, copy.deepcopy(pods), return_sched=True, resident_drain=False
+    )
 
     state = OracleState.build(nodes)
     want: Dict[str, Optional[str]] = {}
@@ -496,7 +541,12 @@ def check_resident_vs_oracle(n_nodes=1000, n_pods=5000) -> dict:
         if r.node is not None:
             pod.node_name = r.node
             state.place(pod)
-    diffs = _diff(got, want) + _diff(got, off)
+    diffs = (
+        device_faults(sched)
+        + device_faults(s_off)
+        + _diff(got, want)
+        + _diff(got, off)
+    )
     n_diffs = len(diffs)
     if resident_batches == 0:
         # the check certifies the RESIDENT path; a silent fallback would
@@ -928,8 +978,12 @@ def check_multichip_vs_singlechip(
     pods axis) and a nodes-major mesh (all devices on the nodes axis).
     Decisions must be bit-identical in all three modes, and on a
     multi-device backend the mesh runs must PROVE engagement (scheduler
-    mesh resolved + ledger multi-device dispatches), or the check fails
-    loud — a silently-replicated run would make the parity claim vacuous.
+    mesh resolved and still full-width at the end, ledger multi-device
+    dispatches whose partitioned arguments span EVERY device — pod-major
+    arrays in the pods-major layout, node-major arrays in the nodes-major
+    one — and no dispatch abandoned to a breaker fallback), or the check
+    fails loud — a silently-replicated, degraded or host-answered run
+    would make the parity claim vacuous.
     On a single-device backend the check degrades to a 1x1 mesh identity
     (still zero diffs required) and reports devices=1."""
     import copy
@@ -951,8 +1005,8 @@ def check_multichip_vs_singlechip(
         )
         return got, got2, s, s2
 
-    base, gbase, _s, _s2 = drains(mesh_dispatch=False)
-    diffs: List = []
+    base, gbase, s_base, s2_base = drains(mesh_dispatch=False)
+    diffs: List = device_faults(s_base) + device_faults(s2_base)
     mesh_runs = {}
     for label, pods_axis in (("pods_major", None), ("nodes_major", 1)):
         got, ggot, s, s2 = drains(
@@ -961,18 +1015,45 @@ def check_multichip_vs_singlechip(
         diffs += [
             (f"{label}:{k}", a, b) for k, a, b in _diff(base, got)
         ] + [(f"{label}:gang:{k}", a, b) for k, a, b in _diff(gbase, ggot)]
+        diffs += [
+            (f"{label}:{k}", a, b)
+            for k, a, b in device_faults(s) + device_faults(s2)
+        ]
+        if s.mesh is None or s2.mesh is None:
+            # resolved at init, halved/dropped by _degrade_mesh on a fault
+            diffs.append((f"__{label}_mesh_resolved__", None, "mesh"))
+            mesh_runs[label] = {"mesh": None}
+            continue
         mesh_shape = f"{s.mesh.shape['pods']}x{s.mesh.shape['nodes']}"
         multi = (
             s.kernels.stats()["multi_device_dispatches"]
             + s2.kernels.stats()["multi_device_dispatches"]
         )
-        mesh_runs[label] = {"mesh": mesh_shape, "multi_device_dispatches": multi}
-        if s.mesh is None or s2.mesh is None:
-            diffs.append((f"__{label}_mesh_resolved__", None, "mesh"))
+        # the widest DISTINCT-device set any partitioned (non-replicated)
+        # dispatch argument spanned — read off the live arrays' shardings
+        # by the ledger at dispatch time
+        span = max(
+            max(r["devices"])
+            for led in (s.kernels, s2.kernels)
+            for r in led.table(cost=False)
+        )
+        mesh_runs[label] = {
+            "mesh": mesh_shape,
+            "mesh_devices": sorted(int(d.id) for d in s.mesh.devices.flat),
+            "multi_device_dispatches": multi,
+            "dispatch_device_span": span,
+        }
+        if int(s.mesh.devices.size) != devices:
+            diffs.append(
+                (f"__{label}_mesh_width__", int(s.mesh.devices.size), devices)
+            )
         if devices > 1 and multi == 0:
             # a mesh run whose dispatches never actually partitioned
             # proves nothing — fail loud rather than certify replication
             diffs.append((f"__{label}_engaged__", 0, ">=1"))
+        if devices > 1 and span != devices:
+            # everything on the first chip (or a subset) is not a mesh run
+            diffs.append((f"__{label}_device_span__", span, devices))
     return {
         "devices": devices,
         "nodes": n_nodes,
