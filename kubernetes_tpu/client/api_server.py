@@ -45,6 +45,7 @@ from urllib.parse import parse_qs, unquote, urlparse
 from kubernetes_tpu.api.codec import decode, encode
 from kubernetes_tpu.api.types import Node, Pod
 from kubernetes_tpu.client import wire_codec
+from kubernetes_tpu.metrics import annotation
 
 WATCH_WINDOW = 4096  # events kept per resource (watch_cache.go capacity)
 
@@ -234,19 +235,32 @@ class ApiServer:
             # per-request accounting context, set by _begin at the top of
             # each verb handler and consumed by _json at response time
             _acct = None
+            # the request's profiler span ``ktpu.apiserver.<verb>.<resource>``
+            # (metrics.annotation): opened by _begin, closed at response
+            # time on the same handler thread; nothing unless a profiler
+            # session is live
+            _ann = None
 
             def _begin(self, verb: str) -> None:
-                cp = server.cp
-                if cp is None or not cp.enabled:
-                    self._acct = None
-                    return
                 parts = [
                     p for p in urlparse(self.path).path.split("/") if p
                 ]
                 res = parts[2] if len(parts) >= 3 and parts[0] == "api" else (
                     parts[0] if parts else "other"
                 )
+                self._end_span()  # a request that never answered
+                self._ann = annotation(f"apiserver.{verb}.{res}").begin()
+                cp = server.cp
+                if cp is None or not cp.enabled:
+                    self._acct = None
+                    return
                 self._acct = (cp, verb, res, time.monotonic())
+
+            def _end_span(self) -> None:
+                ann = self._ann
+                if ann is not None:
+                    self._ann = None
+                    ann.end()
 
             # ----- content negotiation (Accept / Content-Type) ---------
             # JSON stays the DEBUG DEFAULT: a request that doesn't ask for
@@ -282,6 +296,7 @@ class ApiServer:
                     self._acct = None
                     cp, verb, res, t0 = acct
                     cp.note_request(verb, res, code, time.monotonic() - t0)
+                self._end_span()
 
             def _json(self, code: int, payload) -> None:
                 """Negotiated response: named for the historical default —
@@ -332,6 +347,7 @@ class ApiServer:
 
             def _watch(self, res: str, rv: int) -> None:
                 self._acct = None  # a stream, not a request latency
+                self._end_span()
                 cache = server.caches[res]
                 # join the watcher registry: fanout lag is the cache head
                 # rv minus this stream's delivered rv, scraped on demand
@@ -433,7 +449,9 @@ class ApiServer:
                     # (storage.go:169); per-item statuses come back so the
                     # scheduler can unwind exactly the pods that failed
                     results = []
+                    ann_lock = annotation("apiserver.lock_wait").begin()
                     with server._mu:
+                        ann_lock.end()
                         for item in body.get("items", []):
                             uid = item.get("uid")
                             pod = server.api.pods.get(uid)

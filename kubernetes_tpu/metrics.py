@@ -19,6 +19,8 @@ import time
 from dataclasses import dataclass, field
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
+from jax.profiler import TraceAnnotation
+
 # ---------------------------------------------------------------------------
 # core metric types
 # ---------------------------------------------------------------------------
@@ -378,9 +380,17 @@ PHASES = (
 class PhaseAccumulator:
     """Cumulative per-phase wall seconds + per-observation histogram feed.
 
-    ``add`` is called from the scheduling loop AND binding workers, so it
-    takes a lock; the frequency is per batch / per bind chunk (not per
-    pod), which keeps the overhead unmeasurable next to the phases
+    ``span`` is the one way an interval is written: where the work
+    happens, on the thread that does it.  It books the interval (``add``:
+    total, histogram, the tracer's ``complete_tail``) and holds a
+    ``jax.profiler.TraceAnnotation("ktpu.<name>", **ctx)`` open for it, so
+    while a profiler session is live the span also lands in the profiler's
+    host plane, on the device trace's clock.  The session is the switch:
+    with none live the annotation is one atomic check.
+
+    Spans are opened from the scheduling loop AND binding workers, so
+    booking takes a lock; the frequency is per batch / per bind slice (never
+    per pod), which keeps the overhead unmeasurable next to the phases
     themselves.  ``snapshot`` returns a plain dict — bench.py diffs two
     snapshots around the timed drain to report ``config0_phases``.
     """
@@ -395,6 +405,10 @@ class PhaseAccumulator:
         self.tracer = None
 
     def add(self, phase: str, dt: float) -> None:
+        """Book ``dt`` seconds that ended now.  ``span`` ends here; called
+        directly only for an interval no one thread spans (a bind slice's
+        wait in the pool's queue: submitted by the loop, picked up by a
+        worker)."""
         with self._mu:
             self._totals[phase] = self._totals.get(phase, 0.0) + dt
             if self.hist is not None:
@@ -403,9 +417,12 @@ class PhaseAccumulator:
         if tr is not None and tr.enabled:
             tr.complete_tail(phase, dt)
 
-    def timer(self, phase: str):
-        """Context manager: accumulate the block's wall time."""
-        return _PhaseTimer(self, phase)
+    def span(self, phase: str, **ctx) -> "PhaseSpan":
+        """The interval ``phase``, as a context manager, or opened with
+        ``.begin()`` and closed with ``.end()`` where the interval does not
+        sit in one block.  ``ctx`` (batch id, pod count) goes to the
+        profiler's annotation only."""
+        return PhaseSpan(self, phase, ctx)
 
     def snapshot(self) -> Dict[str, float]:
         with self._mu:
@@ -421,19 +438,50 @@ class PhaseAccumulator:
         return out
 
 
-class _PhaseTimer:
-    __slots__ = ("acc", "phase", "_t0")
+class Annotation(TraceAnnotation):
+    """``jax.profiler.TraceAnnotation`` with an explicit begin/end form,
+    for an interval that does not sit in one block.  Begun and ended on
+    one thread."""
 
-    def __init__(self, acc: PhaseAccumulator, phase: str):
+    def begin(self) -> "Annotation":
+        self.__enter__()
+        return self
+
+    def end(self) -> None:
+        self.__exit__(None, None, None)
+
+
+def annotation(name: str, **ctx) -> Annotation:
+    """A profiler annotation ``ktpu.<name>`` with no accumulator behind it
+    (the loop's enclosing batch span, the API server's requests, the
+    ledger's dispatches)."""
+    return Annotation("ktpu." + name, **ctx)
+
+
+class PhaseSpan:
+    __slots__ = ("acc", "phase", "_ann", "_t0")
+
+    def __init__(self, acc: PhaseAccumulator, phase: str, ctx: dict):
         self.acc = acc
         self.phase = phase
+        self._ann = annotation(phase, **ctx)
 
-    def __enter__(self):
+    def begin(self) -> "PhaseSpan":
+        self._ann.begin()
         self._t0 = time.perf_counter()
         return self
 
+    def end(self) -> float:
+        """Close and book the interval; returns its seconds."""
+        dt = time.perf_counter() - self._t0
+        self._ann.end()
+        self.acc.add(self.phase, dt)
+        return dt
+
+    __enter__ = begin
+
     def __exit__(self, *exc):
-        self.acc.add(self.phase, time.perf_counter() - self._t0)
+        self.end()
         return False
 
 

@@ -9,11 +9,16 @@ jit root (the same roster the sanitizer's retrace hook sweeps —
 through ``register_jit_root``) is wrapped with a ``_LedgerRoot`` proxy
 that accounts each dispatch:
 
-  * **execute wall time** — the wall clock of the dispatch call.  On an
-    async backend this is the host-side submit (the same definition the
-    ``device`` phase uses); synchronous work, first-trace time, and any
-    blocking the call performs land here in full, and the device latency
-    the host failed to hide shows up in the per-kernel d2h series below.
+  * **execute wall time** (``execute_s``) — HOST seconds inside the
+    dispatch call.  On an asynchronous backend that is enqueue (submit)
+    time, NOT device time (the same definition the ``device`` phase
+    uses): the tracer track these spans land on is called
+    ``dispatch_submit`` for that reason.  Synchronous work, first-trace
+    time, and any blocking the call performs land here in full; the
+    device latency the host failed to hide shows up in the per-kernel d2h
+    series below, and device time proper is read from a profiler trace
+    (each dispatch holds a ``ktpu.dispatch.<kernel>`` annotation open, so
+    under a profiler session the call sits on the device trace's clock).
   * **first-trace compile time** — a dispatch that grew the root's
     compilation cache (``fn._cache_size()``) is a compile: its wall time
     counts into ``compiles``/``compile_s`` instead of the execute series,
@@ -70,6 +75,8 @@ import weakref
 from typing import Dict, List, Optional, Tuple
 
 import jax
+
+from kubernetes_tpu.metrics import annotation
 
 # Lock-discipline registry (kubernetes_tpu.analysis): the scheduling
 # loop records dispatches, binding workers/HTTP handlers read tables,
@@ -414,8 +421,10 @@ class DispatchLedger:
     wrappers route through the ACTIVE ledger (``activate``).  ``prom``
     is the scheduler's ``SchedulerMetrics`` (optional — standalone
     ledgers in tests run without a registry); ``tracer`` feeds
-    device-track spans when a capture is running; ``slo_getter`` returns
-    the scheduler's SLOEvaluator (or None) at breach time.
+    ``dispatch_submit``-track spans (the host's clock around each dispatch
+    call) when a capture is running; ``slo_getter`` returns the
+    scheduler's SLOEvaluator (or None) at breach time; ``bid_getter``
+    returns the scheduling loop's current batch id for the dispatch span.
     """
 
     def __init__(
@@ -423,6 +432,7 @@ class DispatchLedger:
         prom=None,
         tracer=None,
         slo_getter=None,
+        bid_getter=None,
         clock=time.perf_counter,
         sentinel_factor: float = SENTINEL_FACTOR,
         sentinel_min_samples: int = SENTINEL_MIN_SAMPLES,
@@ -438,6 +448,8 @@ class DispatchLedger:
         self.prom = prom
         self.tracer = tracer
         self.slo_getter = slo_getter
+        # the scheduling loop's current batch id, for the dispatch span
+        self.bid_getter = bid_getter
         self._clock = clock
         self.sentinel_factor = sentinel_factor
         self.sentinel_min_samples = sentinel_min_samples
@@ -549,6 +561,11 @@ class DispatchLedger:
                 spec = _abstract_spec(args, kwargs)
             except Exception:  # noqa: BLE001 — cost analysis is optional
                 spec = None
+        # the dispatch call as a span on the profiler's clock (live only
+        # under a profiler session): a compile inside a traced window
+        # shows as a long named span next to the device's ops
+        bid = self.bid_getter() if self.bid_getter is not None else 0
+        ann = annotation("dispatch." + name, bid=bid).begin()
         t0 = self._clock()
         if stall_s:
             # injected dispatch_hang: the stall rides the execute wall
@@ -557,6 +574,8 @@ class DispatchLedger:
         out = fn(*args, **kwargs)
         dt = self._clock() - t0
         size_after = fn._cache_size()
+        ann.set_metadata(compile=int(size_after > size_before))
+        ann.end()
         breach = None
         with self._mu:
             ks = self._kstats[name]
@@ -596,12 +615,15 @@ class DispatchLedger:
                 prom.kernel_execute.observe(dt, kernel=name)
         tr = self.tracer
         if tr is not None and tr.enabled:
+            # execute_s is the HOST's clock around the dispatch call: on
+            # an asynchronous backend that is enqueue (submit) time, not
+            # device time — the track says so
             tr.complete_track(
-                "device",
+                "dispatch_submit",
                 name,
                 t0,
                 t0 + dt,
-                cat="device",
+                cat="dispatch_submit",
                 compile=bool(compiled),
             )
         # watchdog verdict: an injected hang is a breach BY CONTRACT
@@ -1013,6 +1035,12 @@ class DispatchLedger:
         out["cost_memo_misses"] = st["cost_memo_misses"]
         out["regressions"] = st["regressions"]
         out["breakers"] = self.breaker_rows()
+        out["clocks"] = {
+            "execute_s": "host seconds inside the dispatch call: enqueue "
+            "(submit) time on an asynchronous backend, not device time",
+            "d2h_seconds": "host seconds blocked in the fetch: where the "
+            "host waits for the device",
+        }
         return out
 
 

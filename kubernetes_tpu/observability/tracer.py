@@ -87,7 +87,8 @@ class Tracer:
         self._ring_mode = False
         self._ring_cap = DEFAULT_BLACKBOX_EVENTS
         self._tid_names: Dict[int, str] = {}
-        # synthetic tracks (device-side spans from the dispatch ledger):
+        # synthetic tracks (the dispatch ledger's submit spans, the
+        # control plane's hops):
         # track name → synthetic tid, far above any OS thread ident so
         # Perfetto renders them as their own named rows
         self._track_tids: Dict[str, int] = {}
@@ -170,7 +171,7 @@ class Tracer:
         an unclamped t0 would paint pre-trace time as a fat span at the
         origin.  ``track`` routes the event onto a named SYNTHETIC track
         (a tid above any OS thread ident) instead of the calling thread's
-        — the device-side spans' own row in Perfetto."""
+        — the synthetic spans' own row in Perfetto."""
         t_in = self._clock()
         if track is None:
             tid = threading.get_ident()
@@ -247,11 +248,12 @@ class Tracer:
 
     def complete_track(
         self, track: str, name: str, t0: float, t1: float,
-        cat: str = "device", **args,
+        cat: str = "track", **args,
     ) -> None:
         """Record a complete event spanning [t0, t1) on the named
-        synthetic track (the dispatch ledger's device-side kernel spans,
-        rendered alongside the host thread tracks).  Carries the journal
+        synthetic track (the dispatch ledger's ``dispatch_submit`` spans,
+        the control plane's hops — rendered alongside the host thread
+        tracks).  Carries the journal
         logical time like every other span when one is attached."""
         if not self.enabled:
             return

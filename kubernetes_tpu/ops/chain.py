@@ -225,62 +225,63 @@ def chain_dispatch(
             d_cap=d_cap,
             fit_strategy=fit_strategy,
         )
-    P = db.valid.shape[0]
-    committed = (chosen >= 0) & db.valid
-    upd = dict(
-        requested=tallies["requested"],
-        nonzero_req=tallies["nonzero"],
-        num_pods=tallies["num_pods"],
-        epod_node=_dus(
-            dc.epod_node, jnp.where(committed, chosen, ABSENT), e_cursor
-        ),
-        epod_ns=_dus(dc.epod_ns, db.ns_id, e_cursor),
-        epod_labels=_dus(dc.epod_labels, db.labels, e_cursor),
-        epod_valid=_dus(dc.epod_valid, committed, e_cursor),
-        epod_deleting=_dus(dc.epod_deleting, jnp.zeros((P,), bool), e_cursor),
-    )
-    AT = db.aff_kind.shape[1]
-    if AT and append_terms:
-        real = db.aff_kind != PAD  # [P, AT]
-        pod_idx = e_cursor + jnp.arange(P, dtype=I32)[:, None]
-        term_pod = jnp.where(real, pod_idx, ABSENT).reshape(P * AT)
-        tt = dc.term_table
-        Rc = tt.req_key.shape[2]
-        Vc = tt.req_vals.shape[3]
-        NSc = dc.term_ns_ids.shape[1]
-        bt = db.aff_table
-        rk = _pad_axis(bt.req_key.reshape(P * AT, 1, -1), 2, Rc, PAD)
-        ro = _pad_axis(bt.req_op.reshape(P * AT, 1, -1), 2, Rc, PAD)
-        rr = _pad_axis(bt.req_rhs.reshape(P * AT, 1, -1), 2, Rc, 0)
-        rv = bt.req_vals.reshape(
-            P * AT, 1, bt.req_vals.shape[2], bt.req_vals.shape[3]
+    with jax.named_scope("ktpu/chain/append"):
+        P = db.valid.shape[0]
+        committed = (chosen >= 0) & db.valid
+        upd = dict(
+            requested=tallies["requested"],
+            nonzero_req=tallies["nonzero"],
+            num_pods=tallies["num_pods"],
+            epod_node=_dus(
+                dc.epod_node, jnp.where(committed, chosen, ABSENT), e_cursor
+            ),
+            epod_ns=_dus(dc.epod_ns, db.ns_id, e_cursor),
+            epod_labels=_dus(dc.epod_labels, db.labels, e_cursor),
+            epod_valid=_dus(dc.epod_valid, committed, e_cursor),
+            epod_deleting=_dus(dc.epod_deleting, jnp.zeros((P,), bool), e_cursor),
         )
-        rv = _pad_axis(_pad_axis(rv, 3, Vc, PAD), 2, Rc, PAD)
-        upd.update(
-            term_pod=_dus(dc.term_pod, term_pod, m_cursor),
-            term_kind=_dus(dc.term_kind, db.aff_kind.reshape(P * AT), m_cursor),
-            term_topo=_dus(dc.term_topo, db.aff_topo.reshape(P * AT), m_cursor),
-            term_weight=_dus(
-                dc.term_weight, db.aff_weight.reshape(P * AT), m_cursor
-            ),
-            term_ns_all=_dus(
-                dc.term_ns_all, db.aff_ns_all.reshape(P * AT), m_cursor
-            ),
-            term_ns_ids=_dus(
-                dc.term_ns_ids,
-                _pad_axis(db.aff_ns_ids.reshape(P * AT, -1), 1, NSc, PAD),
-                m_cursor,
-            ),
-            term_table=DTable(
-                req_key=_dus(tt.req_key, rk, m_cursor),
-                req_op=_dus(tt.req_op, ro, m_cursor),
-                req_vals=_dus(tt.req_vals, rv, m_cursor),
-                req_rhs=_dus(tt.req_rhs, rr, m_cursor),
-                term_valid=_dus(
-                    tt.term_valid, bt.term_valid.reshape(P * AT, 1), m_cursor
+        AT = db.aff_kind.shape[1]
+        if AT and append_terms:
+            real = db.aff_kind != PAD  # [P, AT]
+            pod_idx = e_cursor + jnp.arange(P, dtype=I32)[:, None]
+            term_pod = jnp.where(real, pod_idx, ABSENT).reshape(P * AT)
+            tt = dc.term_table
+            Rc = tt.req_key.shape[2]
+            Vc = tt.req_vals.shape[3]
+            NSc = dc.term_ns_ids.shape[1]
+            bt = db.aff_table
+            rk = _pad_axis(bt.req_key.reshape(P * AT, 1, -1), 2, Rc, PAD)
+            ro = _pad_axis(bt.req_op.reshape(P * AT, 1, -1), 2, Rc, PAD)
+            rr = _pad_axis(bt.req_rhs.reshape(P * AT, 1, -1), 2, Rc, 0)
+            rv = bt.req_vals.reshape(
+                P * AT, 1, bt.req_vals.shape[2], bt.req_vals.shape[3]
+            )
+            rv = _pad_axis(_pad_axis(rv, 3, Vc, PAD), 2, Rc, PAD)
+            upd.update(
+                term_pod=_dus(dc.term_pod, term_pod, m_cursor),
+                term_kind=_dus(dc.term_kind, db.aff_kind.reshape(P * AT), m_cursor),
+                term_topo=_dus(dc.term_topo, db.aff_topo.reshape(P * AT), m_cursor),
+                term_weight=_dus(
+                    dc.term_weight, db.aff_weight.reshape(P * AT), m_cursor
                 ),
-            ),
-        )
+                term_ns_all=_dus(
+                    dc.term_ns_all, db.aff_ns_all.reshape(P * AT), m_cursor
+                ),
+                term_ns_ids=_dus(
+                    dc.term_ns_ids,
+                    _pad_axis(db.aff_ns_ids.reshape(P * AT, -1), 1, NSc, PAD),
+                    m_cursor,
+                ),
+                term_table=DTable(
+                    req_key=_dus(tt.req_key, rk, m_cursor),
+                    req_op=_dus(tt.req_op, ro, m_cursor),
+                    req_vals=_dus(tt.req_vals, rv, m_cursor),
+                    req_rhs=_dus(tt.req_rhs, rr, m_cursor),
+                    term_valid=_dus(
+                        tt.term_valid, bt.term_valid.reshape(P * AT, 1), m_cursor
+                    ),
+                ),
+            )
     next_dc = replace(dc, **upd)
     results = jnp.stack([chosen, n_feas])
     if wave:

@@ -533,6 +533,31 @@ def gather_at(cols_t, key):
 # ---------------------------------------------------------------------------
 
 
+# The stage names the scheduling roots put on their device ops
+# (``jax.named_scope`` at the function boundaries that exist: metadata only,
+# the compiled code is the same with and without them).  Flat and stable:
+# ``ktpu/<module>/<stage>``; an op inside two scopes belongs to the inner
+# one.  PERF.md section 3 lists them, tests/test_spans.py holds each root's
+# lowered text to them, benchmarks/readers/scope.py groups device time by
+# them.
+STAGES = (
+    "ktpu/gang/precompute",  # statics of a batch: masks, counts, scores
+    "ktpu/gang/heavy_parts",  # gang scan: batch-peer contractions of a step
+    "ktpu/gang/spread_constraints",  # spread verdict + score counts of a pod
+    "ktpu/gang/interpod_constraints",  # inter-pod verdict + raw score
+    "ktpu/gang/filter",  # pod_step: dynamic filters + failure diagnosis
+    "ktpu/gang/score",  # pod_step: scores and their normalisation
+    "ktpu/gang/select",  # pod_step: argmax / tie-break
+    "ktpu/gang/commit",  # pod_step: the usage carry update
+    "ktpu/wave/speculation",  # wave pass 1: every pod against the snapshot
+    "ktpu/wave/admission",  # wave pass 2: factored deltas, carry, attribution
+    "ktpu/chain/append",  # chain_dispatch: splice of the committed pods
+    "ktpu/resident/round",  # resident_run: one speculation/admission round
+    "ktpu/fastpath/sig_step",  # sig_scan / resident serial tail: one pod
+    "ktpu/fastpath/static_eval",  # static filters + raw scores per signature
+)
+
+
 def usage_carry_update(rows, deltas, nodes, live):
     """THE per-commit node-usage update shared by every serial-recurrence
     replayer: the gang scan / wave admission / workloads admission (via

@@ -61,49 +61,50 @@ def static_eval(dc, db, enabled: frozenset, has_images: bool):
       img         — ImageLocality contribution (already weight-free raw,
                     no normalization pass in the reference)
     """
-    P = db.valid.shape[0]
-    N = dc.node_valid.shape[0]
-    true_pn = jnp.ones((P, N), bool)
-    tolerated = F._tolerated(dc, db)
-    m_nodename = F.mask_node_name(dc, db) if "NodeName" in enabled else true_pn
-    m_unsched = (
-        F.mask_unschedulable(dc, db)
-        if "NodeUnschedulable" in enabled
-        else true_pn
-    )
-    m_taints = (
-        F.mask_taints(dc, db, tolerated)
-        if "TaintToleration" in enabled
-        else true_pn
-    )
-    m_nodeaff = (
-        F.mask_node_affinity(dc, db) if "NodeAffinity" in enabled else true_pn
-    )
-    mask = (
-        dc.node_valid[None, :]
-        & db.valid[:, None]
-        & m_nodename
-        & m_unsched
-        & m_taints
-        & m_nodeaff
-    )
-    taint_raw = S.score_taint_toleration(dc, db)
-    naff_raw = S.score_node_affinity(dc, db)
-    img = (
-        S.score_image_locality(dc, db)
-        if has_images
-        else jnp.zeros((P, N), jnp.int64)
-    )
-    return {
-        "mask": mask,
-        "m_nodename": m_nodename,
-        "m_unsched": m_unsched,
-        "m_taints": m_taints,
-        "m_nodeaff": m_nodeaff,
-        "taint_raw": taint_raw,
-        "naff_raw": naff_raw,
-        "img": img,
-    }
+    with jax.named_scope("ktpu/fastpath/static_eval"):
+        P = db.valid.shape[0]
+        N = dc.node_valid.shape[0]
+        true_pn = jnp.ones((P, N), bool)
+        tolerated = F._tolerated(dc, db)
+        m_nodename = F.mask_node_name(dc, db) if "NodeName" in enabled else true_pn
+        m_unsched = (
+            F.mask_unschedulable(dc, db)
+            if "NodeUnschedulable" in enabled
+            else true_pn
+        )
+        m_taints = (
+            F.mask_taints(dc, db, tolerated)
+            if "TaintToleration" in enabled
+            else true_pn
+        )
+        m_nodeaff = (
+            F.mask_node_affinity(dc, db) if "NodeAffinity" in enabled else true_pn
+        )
+        mask = (
+            dc.node_valid[None, :]
+            & db.valid[:, None]
+            & m_nodename
+            & m_unsched
+            & m_taints
+            & m_nodeaff
+        )
+        taint_raw = S.score_taint_toleration(dc, db)
+        naff_raw = S.score_node_affinity(dc, db)
+        img = (
+            S.score_image_locality(dc, db)
+            if has_images
+            else jnp.zeros((P, N), jnp.int64)
+        )
+        return {
+            "mask": mask,
+            "m_nodename": m_nodename,
+            "m_unsched": m_unsched,
+            "m_taints": m_taints,
+            "m_nodeaff": m_nodeaff,
+            "taint_raw": taint_raw,
+            "naff_raw": naff_raw,
+            "img": img,
+        }
 
 
 # ---------------------------------------------------------------------------
@@ -143,66 +144,67 @@ def make_sig_step(
     ext_lane = jnp.arange(R) >= N_FIXED_LANES  # bool [R]
 
     def step(carry, s):
-        used, nz0, nz1, num_pods = carry
-        active = s >= 0
-        sc = jnp.maximum(s, 0)
-        req = sig_req[sc]  # [R]
-        snz0 = sig_nz[sc, 0]
-        snz1 = sig_nz[sc, 1]
-        ok = sig_ok[sc]  # [N]
+        with jax.named_scope("ktpu/fastpath/sig_step"):
+            used, nz0, nz1, num_pods = carry
+            active = s >= 0
+            sc = jnp.maximum(s, 0)
+            req = sig_req[sc]  # [R]
+            snz0 = sig_nz[sc, 0]
+            snz1 = sig_nz[sc, 1]
+            ok = sig_ok[sc]  # [N]
 
-        # ---- feasibility (fastpath.FastCommitter.feasible_int) ----
-        if check_fit:
-            fits_count = num_pods + 1 <= allowed
-            avail = alloc - used  # [N, R]
-            lane_ok = jnp.where(
-                (ext_lane & (req == 0))[None, :], True, req[None, :] <= avail
-            )
-            fits_lanes = jnp.where(
-                sig_allzero[sc], True, jnp.all(lane_ok, axis=1)
-            )
-            feas = ok & fits_count & fits_lanes
-        else:
-            feas = ok
+            # ---- feasibility (fastpath.FastCommitter.feasible_int) ----
+            if check_fit:
+                fits_count = num_pods + 1 <= allowed
+                avail = alloc - used  # [N, R]
+                lane_ok = jnp.where(
+                    (ext_lane & (req == 0))[None, :], True, req[None, :] <= avail
+                )
+                fits_lanes = jnp.where(
+                    sig_allzero[sc], True, jnp.all(lane_ok, axis=1)
+                )
+                feas = ok & fits_count & fits_lanes
+            else:
+                feas = ok
 
-        # ---- integer score (fastpath.FastCommitter.score_int) ----
-        total = jnp.zeros((N,), I64)
-        if w_fit:
-            c0 = nz0 + snz0
-            c1 = nz1 + snz1
-            f0 = jnp.where(c0 > a0, 0, (a0 - c0) * MAX // jnp.maximum(a0, 1))
-            f1 = jnp.where(c1 > a1, 0, (a1 - c1) * MAX // jnp.maximum(a1, 1))
-            least = jnp.where(
-                fit_w > 0,
-                (jnp.where(h0, f0, 0) + jnp.where(h1, f1, 0))
-                // jnp.maximum(fit_w, 1),
-                0,
-            )
-            total = total + w_fit * least
-        if w_bal:
-            r0 = jnp.minimum(used[:, LANE_CPU] + req[LANE_CPU], a0)
-            r1 = jnp.minimum(used[:, LANE_MEM] + req[LANE_MEM], a1)
-            d = jnp.abs(r0 * a1 - r1 * a0)
-            bal = jnp.where(
-                h0 & h1, MAX - (50 * d + den_bal - 1) // den_bal, MAX
-            )
-            total = total + w_bal * bal
-        if w_img:
-            total = total + w_img * sig_img[sc]
+            # ---- integer score (fastpath.FastCommitter.score_int) ----
+            total = jnp.zeros((N,), I64)
+            if w_fit:
+                c0 = nz0 + snz0
+                c1 = nz1 + snz1
+                f0 = jnp.where(c0 > a0, 0, (a0 - c0) * MAX // jnp.maximum(a0, 1))
+                f1 = jnp.where(c1 > a1, 0, (a1 - c1) * MAX // jnp.maximum(a1, 1))
+                least = jnp.where(
+                    fit_w > 0,
+                    (jnp.where(h0, f0, 0) + jnp.where(h1, f1, 0))
+                    // jnp.maximum(fit_w, 1),
+                    0,
+                )
+                total = total + w_fit * least
+            if w_bal:
+                r0 = jnp.minimum(used[:, LANE_CPU] + req[LANE_CPU], a0)
+                r1 = jnp.minimum(used[:, LANE_MEM] + req[LANE_MEM], a1)
+                d = jnp.abs(r0 * a1 - r1 * a0)
+                bal = jnp.where(
+                    h0 & h1, MAX - (50 * d + den_bal - 1) // den_bal, MAX
+                )
+                total = total + w_bal * bal
+            if w_img:
+                total = total + w_img * sig_img[sc]
 
-        # ---- first-max argmax over feasible nodes + one-hot commit ----
-        ranked = jnp.where(feas, total, -1)
-        choice = jnp.argmax(ranked).astype(I32)
-        any_feas = ranked[choice] >= 0
-        choice = jnp.where(active & any_feas, choice, -1)
-        rows = usage_carry_update(
-            {"used": used, "nz0": nz0, "nz1": nz1, "num_pods": num_pods},
-            {"used": req, "nz0": snz0, "nz1": snz1, "num_pods": 1},
-            choice,
-            choice >= 0,
-        )
-        carry = (rows["used"], rows["nz0"], rows["nz1"], rows["num_pods"])
-        return carry, choice
+            # ---- first-max argmax over feasible nodes + one-hot commit ----
+            ranked = jnp.where(feas, total, -1)
+            choice = jnp.argmax(ranked).astype(I32)
+            any_feas = ranked[choice] >= 0
+            choice = jnp.where(active & any_feas, choice, -1)
+            rows = usage_carry_update(
+                {"used": used, "nz0": nz0, "nz1": nz1, "num_pods": num_pods},
+                {"used": req, "nz0": snz0, "nz1": snz1, "num_pods": 1},
+                choice,
+                choice >= 0,
+            )
+            carry = (rows["used"], rows["nz0"], rows["nz1"], rows["num_pods"])
+            return carry, choice
 
     return step
 

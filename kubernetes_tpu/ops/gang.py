@@ -287,225 +287,226 @@ def precompute(
     driven rather than flag-plumbed).  ``enabled`` reflects the profile's
     Filter plugin set.  sp_keys/sp_cdv_tab/ip_keys come from batch_tables();
     they are required whenever the matching has_* flag is set."""
-    P = db.valid.shape[0]
-    N = dc.node_valid.shape[0]
-    tolerated = F._tolerated(dc, db)
-    node_affinity = F.mask_node_affinity(dc, db)
-    taints = F.mask_taints(dc, db, tolerated)
-    base = dc.node_valid[None, :] & db.valid[:, None]
-    true_pn = jnp.ones((P, N), bool)
-    # host-plugin vetoes (run_host_filters) fold in as a static [P, N]
-    # feasibility contribution
-    d_extra = extra_mask if extra_mask is not None else true_pn
-    d_nodename = F.mask_node_name(dc, db) if "NodeName" in enabled else true_pn
-    d_unsched = (
-        F.mask_unschedulable(dc, db) if "NodeUnschedulable" in enabled else true_pn
-    )
-    d_taints = taints if "TaintToleration" in enabled else true_pn
-    d_nodeaff = node_affinity if "NodeAffinity" in enabled else true_pn
-    d_ports = F.mask_ports(dc, db) if "NodePorts" in enabled else true_pn
-    static_mask = (
-        base & d_extra & d_nodename & d_unsched & d_taints & d_nodeaff & d_ports
-    )
-    has_interpod = has_interpod and "InterPodAffinity" in enabled
-    has_spread = has_spread and "PodTopologySpread" in enabled
+    with jax.named_scope("ktpu/gang/precompute"):
+        P = db.valid.shape[0]
+        N = dc.node_valid.shape[0]
+        tolerated = F._tolerated(dc, db)
+        node_affinity = F.mask_node_affinity(dc, db)
+        taints = F.mask_taints(dc, db, tolerated)
+        base = dc.node_valid[None, :] & db.valid[:, None]
+        true_pn = jnp.ones((P, N), bool)
+        # host-plugin vetoes (run_host_filters) fold in as a static [P, N]
+        # feasibility contribution
+        d_extra = extra_mask if extra_mask is not None else true_pn
+        d_nodename = F.mask_node_name(dc, db) if "NodeName" in enabled else true_pn
+        d_unsched = (
+            F.mask_unschedulable(dc, db) if "NodeUnschedulable" in enabled else true_pn
+        )
+        d_taints = taints if "TaintToleration" in enabled else true_pn
+        d_nodeaff = node_affinity if "NodeAffinity" in enabled else true_pn
+        d_ports = F.mask_ports(dc, db) if "NodePorts" in enabled else true_pn
+        static_mask = (
+            base & d_extra & d_nodename & d_unsched & d_taints & d_nodeaff & d_ports
+        )
+        has_interpod = has_interpod and "InterPodAffinity" in enabled
+        has_spread = has_spread and "PodTopologySpread" in enabled
 
-    # ---- spread ----
-    if has_spread:
-        spre = F.spread_precompute(dc, db, node_affinity, taints)
-        _, C, _ = spre.dv.shape
-        cnt_n = per_node_counts(spre.sel_match.astype(I32), dc.epod_node, N)
-        te = spre.tracked[:, None, :] & spre.eligible
-        dom_tot, dom_pres, _, n_dom = domain_stats(
-            jnp.where(te, cnt_n, 0), te, spre.dv, v_cap
-        )
-        soft = spre.exists & ~db.tsc_hard
-        topo_present = spre.dv >= 0
-        all_keys = jnp.all(~soft[:, :, None] | topo_present, axis=1)  # [P, N]
-        counting = all_keys[:, None, :] & spre.eligible
-        sc_dom, _, _, _ = domain_stats(
-            jnp.where(counting, cnt_n, 0), counting, spre.dv, v_cap
-        )
-        b_sel = eval_table(db.tsc_table, db.labels, dc.val_ints)  # [P, C, J]
-        same_ns = db.ns_id[:, None] == db.ns_id[None, :]
-        sp_bmatch = b_sel & same_ns[:, None, :] & db.valid[None, None, :]
-        if sp_keys is None:
-            # Missing tables would silently zero n_dom for every non-host
-            # soft constraint (wrong topologyNormalizingWeight) — fail loud.
-            raise ValueError(
-                "precompute: sp_keys/sp_cdv_tab (from batch_tables) are "
-                "required when has_spread is set"
+        # ---- spread ----
+        if has_spread:
+            spre = F.spread_precompute(dc, db, node_affinity, taints)
+            _, C, _ = spre.dv.shape
+            cnt_n = per_node_counts(spre.sel_match.astype(I32), dc.epod_node, N)
+            te = spre.tracked[:, None, :] & spre.eligible
+            dom_tot, dom_pres, _, n_dom = domain_stats(
+                jnp.where(te, cnt_n, 0), te, spre.dv, v_cap
             )
-        else:
-            k_eq = (db.tsc_topo[:, :, None] == sp_keys[None, None, :]) & (
-                sp_keys >= 0
-            )[None, None, :]  # [P, C, Kd]
-            any_k = jnp.any(k_eq, axis=-1)
-            ki = jnp.argmax(k_eq, axis=-1)
-            sp_cdv = jnp.where(
-                any_k[:, :, None], sp_cdv_tab[ki], -1
-            )  # [P, C, N]
-        sp = dict(
-            sp_hard=spre.exists & db.tsc_hard,
-            sp_soft=soft,
-            sp_dv=spre.dv,
-            sp_te=te,
-            sp_dom_cnt=jnp.where(dom_pres, dom_tot, 0),
-            sp_dom_pres=dom_pres,
-            sp_ndom=n_dom,
-            sp_self=spre.self_match,
-            sp_bmatch=sp_bmatch,
-            sp_is_host=db.tsc_topo == hostname_key,
-            sp_counting=counting,
-            sp_node_cnt=cnt_n,
-            sp_sc_dom=jnp.where(spre.dv >= 0, sc_dom, 0),
-            sp_all_keys=all_keys,
-            sp_cdv=sp_cdv,
-        )
-    else:
-        z2 = jnp.zeros((P, 0), bool)
-        z3b = jnp.zeros((P, 0, N), bool)
-        z3i = jnp.zeros((P, 0, N), I32)
-        sp = dict(
-            sp_hard=z2,
-            sp_soft=z2,
-            sp_dv=z3i,
-            sp_te=z3b,
-            sp_dom_cnt=z3i,
-            sp_dom_pres=z3b,
-            sp_ndom=jnp.zeros((P, 0), I32),
-            sp_self=z2,
-            sp_bmatch=jnp.zeros((P, 0, P), bool),
-            sp_is_host=z2,
-            sp_counting=z3b,
-            sp_node_cnt=z3i,
-            sp_sc_dom=z3i,
-            sp_all_keys=jnp.ones((P, N), bool),
-            sp_cdv=z3i,
-        )
-
-    # ---- inter-pod ----
-    if has_interpod:
-        ipre = F.interpod_precompute(dc, db)
-        viol_existing = F.interpod_existing_violation(dc, ipre)
-        sym = S.interpod_symmetric_score(dc, ipre, hard_pod_affinity_weight)
-        ip_dom_cnt, _, _, _ = domain_stats(
-            ipre.inc_cnt, jnp.zeros_like(ipre.inc_cnt, bool), ipre.inc_dv, v_cap
-        )
-        ip_dom_cnt = jnp.where(ipre.inc_dv >= 0, ip_dom_cnt, 0)
-        is_aff = db.aff_kind == TERM_REQUIRED_AFFINITY
-        is_anti = db.aff_kind == TERM_REQUIRED_ANTI
-        any_static = jnp.any(is_aff[:, :, None] & ipre.inc_match, axis=(1, 2))
-        self_sel = jax.vmap(
-            lambda tbl, lbl: eval_table(tbl, lbl[None, :], dc.val_ints)[..., 0]
-        )(db.aff_table, db.labels)
-        self_ns = jax.vmap(
-            lambda a, ids, ns: ns_member(a, ids, ns[None])[..., 0]
-        )(db.aff_ns_all, db.aff_ns_ids, db.ns_id)
-        self_all = jnp.all(~is_aff | (self_sel & self_ns), axis=1)
-        b_aff_sel = eval_table(db.aff_table, db.labels, dc.val_ints)
-        b_aff_ns = ns_member(db.aff_ns_all, db.aff_ns_ids, db.ns_id)
-        ip_bmatch = b_aff_sel & b_aff_ns & db.valid[None, None, :]
-        pref_w = jnp.where(
-            db.aff_kind == TERM_PREFERRED_AFFINITY,
-            db.aff_weight,
-            jnp.where(db.aff_kind == TERM_PREFERRED_ANTI, -db.aff_weight, 0),
-        ).astype(I64)
-        sym_w = jnp.where(
-            db.aff_kind == TERM_REQUIRED_AFFINITY,
-            hard_pod_affinity_weight,
-            pref_w.astype(I32),
-        ).astype(I64)
-        AT = is_aff.shape[1]
-        if ip_keys is None:
-            # Without the key table the batch-cross (pod vs already-committed
-            # batch peer) term evaluation has nothing to factor over and
-            # anti-affinity between batch members would silently vanish.
-            raise ValueError(
-                "precompute: ip_keys (from batch_tables) is required when "
-                "has_interpod is set"
+            soft = spre.exists & ~db.tsc_hard
+            topo_present = spre.dv >= 0
+            all_keys = jnp.all(~soft[:, :, None] | topo_present, axis=1)  # [P, N]
+            counting = all_keys[:, None, :] & spre.eligible
+            sc_dom, _, _, _ = domain_stats(
+                jnp.where(counting, cnt_n, 0), counting, spre.dv, v_cap
             )
-        else:
-            k_eq = (db.aff_topo[:, :, None] == ip_keys[None, None, :]) & (
-                ip_keys >= 0
-            )[None, None, :]
-            any_k = jnp.any(k_eq, axis=-1)
-            ip_key_idx = jnp.where(
-                any_k, jnp.argmax(k_eq, axis=-1).astype(I32), -1
-            )
-            ip_key_cols = gather_at(dc.node_labels.T, ip_keys)  # [Kd2, N]
-        ip = dict(
-            ip_dv=ipre.inc_dv,
-            ip_dom_cnt=ip_dom_cnt,
-            ip_viol_existing=viol_existing,
-            ip_sym=sym,
-            ip_any_static=any_static,
-            ip_self_all=self_all,
-            ip_bmatch=ip_bmatch,
-            ip_is_aff=is_aff,
-            ip_is_anti=is_anti,
-            ip_pref_w=pref_w,
-            ip_sym_w=sym_w,
-            ip_key_idx=ip_key_idx,
-            ip_key_cols=ip_key_cols,
-        )
-    else:
-        ip = dict(
-            ip_dv=jnp.zeros((P, 0, N), I32),
-            ip_dom_cnt=jnp.zeros((P, 0, N), I32),
-            ip_viol_existing=jnp.zeros((P, N), bool),
-            ip_sym=jnp.zeros((P, N), I64),
-            ip_any_static=jnp.zeros((P,), bool),
-            ip_self_all=jnp.ones((P,), bool),
-            ip_bmatch=jnp.zeros((P, 0, P), bool),
-            ip_is_aff=jnp.zeros((P, 0), bool),
-            ip_is_anti=jnp.zeros((P, 0), bool),
-            ip_pref_w=jnp.zeros((P, 0), I64),
-            ip_sym_w=jnp.zeros((P, 0), I64),
-            ip_key_idx=jnp.zeros((P, 0), I32),
-            ip_key_cols=jnp.full((1, N), ABSENT, I32),
-        )
-
-    # ---- batch port conflicts (node_ports.go semantics, pod×pod) ----
-    if has_ports:
-        W = db.want_ppk.shape[1]
-        port_b = jnp.zeros((P, P), bool)
-        for w in range(W):
-            wk = db.want_ppk[:, w][:, None]
-            wi = db.want_ip[:, w][:, None]
-            ww = db.want_wild[:, w][:, None]
-            wv = wk != PAD
-            for u in range(W):
-                uk = db.want_ppk[:, u][None, :]
-                ui = db.want_ip[:, u][None, :]
-                uw = db.want_wild[:, u][None, :]
-                uv = uk != PAD
-                port_b = port_b | (
-                    wv & uv & (wk == uk) & ((wi == ui) | ww | uw)
+            b_sel = eval_table(db.tsc_table, db.labels, dc.val_ints)  # [P, C, J]
+            same_ns = db.ns_id[:, None] == db.ns_id[None, :]
+            sp_bmatch = b_sel & same_ns[:, None, :] & db.valid[None, None, :]
+            if sp_keys is None:
+                # Missing tables would silently zero n_dom for every non-host
+                # soft constraint (wrong topologyNormalizingWeight) — fail loud.
+                raise ValueError(
+                    "precompute: sp_keys/sp_cdv_tab (from batch_tables) are "
+                    "required when has_spread is set"
                 )
-    else:
-        port_b = jnp.zeros((P, 0), bool)
+            else:
+                k_eq = (db.tsc_topo[:, :, None] == sp_keys[None, None, :]) & (
+                    sp_keys >= 0
+                )[None, None, :]  # [P, C, Kd]
+                any_k = jnp.any(k_eq, axis=-1)
+                ki = jnp.argmax(k_eq, axis=-1)
+                sp_cdv = jnp.where(
+                    any_k[:, :, None], sp_cdv_tab[ki], -1
+                )  # [P, C, N]
+            sp = dict(
+                sp_hard=spre.exists & db.tsc_hard,
+                sp_soft=soft,
+                sp_dv=spre.dv,
+                sp_te=te,
+                sp_dom_cnt=jnp.where(dom_pres, dom_tot, 0),
+                sp_dom_pres=dom_pres,
+                sp_ndom=n_dom,
+                sp_self=spre.self_match,
+                sp_bmatch=sp_bmatch,
+                sp_is_host=db.tsc_topo == hostname_key,
+                sp_counting=counting,
+                sp_node_cnt=cnt_n,
+                sp_sc_dom=jnp.where(spre.dv >= 0, sc_dom, 0),
+                sp_all_keys=all_keys,
+                sp_cdv=sp_cdv,
+            )
+        else:
+            z2 = jnp.zeros((P, 0), bool)
+            z3b = jnp.zeros((P, 0, N), bool)
+            z3i = jnp.zeros((P, 0, N), I32)
+            sp = dict(
+                sp_hard=z2,
+                sp_soft=z2,
+                sp_dv=z3i,
+                sp_te=z3b,
+                sp_dom_cnt=z3i,
+                sp_dom_pres=z3b,
+                sp_ndom=jnp.zeros((P, 0), I32),
+                sp_self=z2,
+                sp_bmatch=jnp.zeros((P, 0, P), bool),
+                sp_is_host=z2,
+                sp_counting=z3b,
+                sp_node_cnt=z3i,
+                sp_sc_dom=z3i,
+                sp_all_keys=jnp.ones((P, N), bool),
+                sp_cdv=z3i,
+            )
 
-    if has_images:
-        sc_image = S.score_image_locality(dc, db)
-    else:
-        sc_image = jnp.zeros((P, N), I64)
+        # ---- inter-pod ----
+        if has_interpod:
+            ipre = F.interpod_precompute(dc, db)
+            viol_existing = F.interpod_existing_violation(dc, ipre)
+            sym = S.interpod_symmetric_score(dc, ipre, hard_pod_affinity_weight)
+            ip_dom_cnt, _, _, _ = domain_stats(
+                ipre.inc_cnt, jnp.zeros_like(ipre.inc_cnt, bool), ipre.inc_dv, v_cap
+            )
+            ip_dom_cnt = jnp.where(ipre.inc_dv >= 0, ip_dom_cnt, 0)
+            is_aff = db.aff_kind == TERM_REQUIRED_AFFINITY
+            is_anti = db.aff_kind == TERM_REQUIRED_ANTI
+            any_static = jnp.any(is_aff[:, :, None] & ipre.inc_match, axis=(1, 2))
+            self_sel = jax.vmap(
+                lambda tbl, lbl: eval_table(tbl, lbl[None, :], dc.val_ints)[..., 0]
+            )(db.aff_table, db.labels)
+            self_ns = jax.vmap(
+                lambda a, ids, ns: ns_member(a, ids, ns[None])[..., 0]
+            )(db.aff_ns_all, db.aff_ns_ids, db.ns_id)
+            self_all = jnp.all(~is_aff | (self_sel & self_ns), axis=1)
+            b_aff_sel = eval_table(db.aff_table, db.labels, dc.val_ints)
+            b_aff_ns = ns_member(db.aff_ns_all, db.aff_ns_ids, db.ns_id)
+            ip_bmatch = b_aff_sel & b_aff_ns & db.valid[None, None, :]
+            pref_w = jnp.where(
+                db.aff_kind == TERM_PREFERRED_AFFINITY,
+                db.aff_weight,
+                jnp.where(db.aff_kind == TERM_PREFERRED_ANTI, -db.aff_weight, 0),
+            ).astype(I64)
+            sym_w = jnp.where(
+                db.aff_kind == TERM_REQUIRED_AFFINITY,
+                hard_pod_affinity_weight,
+                pref_w.astype(I32),
+            ).astype(I64)
+            AT = is_aff.shape[1]
+            if ip_keys is None:
+                # Without the key table the batch-cross (pod vs already-committed
+                # batch peer) term evaluation has nothing to factor over and
+                # anti-affinity between batch members would silently vanish.
+                raise ValueError(
+                    "precompute: ip_keys (from batch_tables) is required when "
+                    "has_interpod is set"
+                )
+            else:
+                k_eq = (db.aff_topo[:, :, None] == ip_keys[None, None, :]) & (
+                    ip_keys >= 0
+                )[None, None, :]
+                any_k = jnp.any(k_eq, axis=-1)
+                ip_key_idx = jnp.where(
+                    any_k, jnp.argmax(k_eq, axis=-1).astype(I32), -1
+                )
+                ip_key_cols = gather_at(dc.node_labels.T, ip_keys)  # [Kd2, N]
+            ip = dict(
+                ip_dv=ipre.inc_dv,
+                ip_dom_cnt=ip_dom_cnt,
+                ip_viol_existing=viol_existing,
+                ip_sym=sym,
+                ip_any_static=any_static,
+                ip_self_all=self_all,
+                ip_bmatch=ip_bmatch,
+                ip_is_aff=is_aff,
+                ip_is_anti=is_anti,
+                ip_pref_w=pref_w,
+                ip_sym_w=sym_w,
+                ip_key_idx=ip_key_idx,
+                ip_key_cols=ip_key_cols,
+            )
+        else:
+            ip = dict(
+                ip_dv=jnp.zeros((P, 0, N), I32),
+                ip_dom_cnt=jnp.zeros((P, 0, N), I32),
+                ip_viol_existing=jnp.zeros((P, N), bool),
+                ip_sym=jnp.zeros((P, N), I64),
+                ip_any_static=jnp.zeros((P,), bool),
+                ip_self_all=jnp.ones((P,), bool),
+                ip_bmatch=jnp.zeros((P, 0, P), bool),
+                ip_is_aff=jnp.zeros((P, 0), bool),
+                ip_is_anti=jnp.zeros((P, 0), bool),
+                ip_pref_w=jnp.zeros((P, 0), I64),
+                ip_sym_w=jnp.zeros((P, 0), I64),
+                ip_key_idx=jnp.zeros((P, 0), I32),
+                ip_key_cols=jnp.full((1, N), ABSENT, I32),
+            )
 
-    return GangStatics(
-        static_mask=static_mask,
-        **sp,
-        **ip,
-        sc_taint=S.score_taint_toleration(dc, db),
-        sc_nodeaff=S.score_node_affinity(dc, db),
-        sc_image=sc_image,
-        port_b=port_b,
-        d_nodename=d_nodename,
-        d_unsched=d_unsched,
-        d_taints=d_taints,
-        d_nodeaff=d_nodeaff,
-        d_ports=d_ports,
-        d_extra=d_extra,
-    )
+        # ---- batch port conflicts (node_ports.go semantics, pod×pod) ----
+        if has_ports:
+            W = db.want_ppk.shape[1]
+            port_b = jnp.zeros((P, P), bool)
+            for w in range(W):
+                wk = db.want_ppk[:, w][:, None]
+                wi = db.want_ip[:, w][:, None]
+                ww = db.want_wild[:, w][:, None]
+                wv = wk != PAD
+                for u in range(W):
+                    uk = db.want_ppk[:, u][None, :]
+                    ui = db.want_ip[:, u][None, :]
+                    uw = db.want_wild[:, u][None, :]
+                    uv = uk != PAD
+                    port_b = port_b | (
+                        wv & uv & (wk == uk) & ((wi == ui) | ww | uw)
+                    )
+        else:
+            port_b = jnp.zeros((P, 0), bool)
+
+        if has_images:
+            sc_image = S.score_image_locality(dc, db)
+        else:
+            sc_image = jnp.zeros((P, N), I64)
+
+        return GangStatics(
+            static_mask=static_mask,
+            **sp,
+            **ip,
+            sc_taint=S.score_taint_toleration(dc, db),
+            sc_nodeaff=S.score_node_affinity(dc, db),
+            sc_image=sc_image,
+            port_b=port_b,
+            d_nodename=d_nodename,
+            d_unsched=d_unsched,
+            d_taints=d_taints,
+            d_nodeaff=d_nodeaff,
+            d_ports=d_ports,
+            d_extra=d_extra,
+        )
 
 
 # ---------------------------------------------------------------------------
@@ -627,25 +628,26 @@ def spread_constraints(db: DeviceBatch, g: "GangStatics", p, sd: SpreadDyn):
     the batch-peer count contributions (filtering.go:236-362 semantics on
     static existing counts + ``sd``).  Returns (m_spread [N], sp_cnt [C,N],
     c_ok [C,N]) — c_ok per constraint for failure attribution."""
-    total = g.sp_dom_cnt[p] + sd.dyn_f  # [C, N]
-    big32 = jnp.iinfo(jnp.int32).max
-    min_match = jnp.min(jnp.where(g.sp_te[p], total, big32), axis=1)
-    min_match = jnp.where(
-        (db.tsc_min_domains[p] > 0) & (g.sp_ndom[p] < db.tsc_min_domains[p]),
-        0,
-        min_match,
-    )
-    skew = total + g.sp_self[p].astype(I32)[:, None] - min_match[:, None]
-    c_ok = (g.sp_dv[p] >= 0) & (
-        ~g.sp_dom_pres[p] | (skew <= db.tsc_max_skew[p][:, None])
-    )
-    m_spread = jnp.all(~g.sp_hard[p][:, None] | c_ok, axis=0)
-    sp_cnt = jnp.where(
-        g.sp_is_host[p][:, None],
-        g.sp_node_cnt[p] + sd.dyn_host,
-        g.sp_sc_dom[p] + sd.dyn_dom,
-    )  # [C, N]
-    return m_spread, sp_cnt, c_ok
+    with jax.named_scope("ktpu/gang/spread_constraints"):
+        total = g.sp_dom_cnt[p] + sd.dyn_f  # [C, N]
+        big32 = jnp.iinfo(jnp.int32).max
+        min_match = jnp.min(jnp.where(g.sp_te[p], total, big32), axis=1)
+        min_match = jnp.where(
+            (db.tsc_min_domains[p] > 0) & (g.sp_ndom[p] < db.tsc_min_domains[p]),
+            0,
+            min_match,
+        )
+        skew = total + g.sp_self[p].astype(I32)[:, None] - min_match[:, None]
+        c_ok = (g.sp_dv[p] >= 0) & (
+            ~g.sp_dom_pres[p] | (skew <= db.tsc_max_skew[p][:, None])
+        )
+        m_spread = jnp.all(~g.sp_hard[p][:, None] | c_ok, axis=0)
+        sp_cnt = jnp.where(
+            g.sp_is_host[p][:, None],
+            g.sp_node_cnt[p] + sd.dyn_host,
+            g.sp_sc_dom[p] + sd.dyn_dom,
+        )  # [C, N]
+        return m_spread, sp_cnt, c_ok
 
 
 def interpod_constraints(g: "GangStatics", p, idyn: InterpodDyn):
@@ -653,28 +655,29 @@ def interpod_constraints(g: "GangStatics", p, idyn: InterpodDyn):
     batch-peer contributions (interpodaffinity filtering/scoring over
     static existing counts + ``idyn``).  Returns (m_interpod [N],
     ip_raw [N], anti_viol [AT, N]) — anti_viol per term for attribution."""
-    ip_total = g.ip_dom_cnt[p] + idyn.ip_dyn  # [AT, N]
-    topo_present = g.ip_dv[p] >= 0
-    anti_viol = g.ip_is_anti[p][:, None] & topo_present & (ip_total > 0)
-    viol2 = jnp.any(anti_viol, axis=0)
-    aff_ok = jnp.all(
-        ~g.ip_is_aff[p][:, None] | (topo_present & (ip_total > 0)), axis=0
-    )
-    any_match = g.ip_any_static[p] | idyn.any_dyn
-    topo_all = jnp.all(~g.ip_is_aff[p][:, None] | topo_present, axis=0)
-    escape = jnp.any(g.ip_is_aff[p]) & ~any_match & g.ip_self_all[p]
-    ok3 = aff_ok | (escape & topo_all)
-    m_interpod = ~g.ip_viol_existing[p] & ~viol2 & ok3 & ~idyn.viol_b
-    pref = jnp.sum(
-        jnp.where(
-            topo_present,
-            ip_total.astype(I64) * g.ip_pref_w[p][:, None],
-            0,
-        ),
-        axis=0,
-    )
-    ip_raw = g.ip_sym[p] + pref + idyn.sym_b.astype(I64)
-    return m_interpod, ip_raw, anti_viol
+    with jax.named_scope("ktpu/gang/interpod_constraints"):
+        ip_total = g.ip_dom_cnt[p] + idyn.ip_dyn  # [AT, N]
+        topo_present = g.ip_dv[p] >= 0
+        anti_viol = g.ip_is_anti[p][:, None] & topo_present & (ip_total > 0)
+        viol2 = jnp.any(anti_viol, axis=0)
+        aff_ok = jnp.all(
+            ~g.ip_is_aff[p][:, None] | (topo_present & (ip_total > 0)), axis=0
+        )
+        any_match = g.ip_any_static[p] | idyn.any_dyn
+        topo_all = jnp.all(~g.ip_is_aff[p][:, None] | topo_present, axis=0)
+        escape = jnp.any(g.ip_is_aff[p]) & ~any_match & g.ip_self_all[p]
+        ok3 = aff_ok | (escape & topo_all)
+        m_interpod = ~g.ip_viol_existing[p] & ~viol2 & ok3 & ~idyn.viol_b
+        pref = jnp.sum(
+            jnp.where(
+                topo_present,
+                ip_total.astype(I64) * g.ip_pref_w[p][:, None],
+                0,
+            ),
+            axis=0,
+        )
+        ip_raw = g.ip_sym[p] + pref + idyn.sym_b.astype(I64)
+        return m_interpod, ip_raw, anti_viol
 
 
 def pod_step(
@@ -715,251 +718,255 @@ def pod_step(
     C = g.sp_dv.shape[1]
     true_n = jnp.ones((N,), bool)
 
-    # ---------------- dynamic filters ----------------
-    req = db.requests[p]  # [Rp]
-    mask = g.static_mask[p] & hv["m_portb"]
-    m_fit = true_n
-    if check_fit:
-        nom_cnt = 0
-        nom_delta = 0
-        if nom_oh is not None:
-            gate = (nom_prio >= db.priority[p]).astype(I32)  # [G]
-            nom_cnt = jnp.einsum("g,gn->n", gate, nom_oh)
-            nom_delta = jnp.einsum(
-                "gr,gn->nr", nom_req * gate[:, None], nom_oh
-            )  # [N, Rn]
-        fits = state["num_pods"] + nom_cnt + 1 <= dc.allowed_pods
-        all_zero = jnp.all(req == 0)
-        avail = dc.allocatable - state["requested"] - nom_delta  # [N, Rn]
-        if Rp > Rn:
-            avail = jnp.concatenate(
-                [avail, jnp.zeros((N, Rp - Rn), I32)], axis=1
+    with jax.named_scope("ktpu/gang/filter"):
+        # ---------------- dynamic filters ----------------
+        req = db.requests[p]  # [Rp]
+        mask = g.static_mask[p] & hv["m_portb"]
+        m_fit = true_n
+        if check_fit:
+            nom_cnt = 0
+            nom_delta = 0
+            if nom_oh is not None:
+                gate = (nom_prio >= db.priority[p]).astype(I32)  # [G]
+                nom_cnt = jnp.einsum("g,gn->n", gate, nom_oh)
+                nom_delta = jnp.einsum(
+                    "gr,gn->nr", nom_req * gate[:, None], nom_oh
+                )  # [N, Rn]
+            fits = state["num_pods"] + nom_cnt + 1 <= dc.allowed_pods
+            all_zero = jnp.all(req == 0)
+            avail = dc.allocatable - state["requested"] - nom_delta  # [N, Rn]
+            if Rp > Rn:
+                avail = jnp.concatenate(
+                    [avail, jnp.zeros((N, Rp - Rn), I32)], axis=1
+                )
+            conflict = req[None, :] > avail  # [N, Rp]
+            # extended-resource lanes only count when actually requested
+            scalar_lane = jnp.arange(Rp) >= N_FIXED_LANES
+            conflict = conflict & (~scalar_lane | (req > 0))[None, :]
+            lane_ok = ~jnp.any(conflict, axis=1)
+            m_fit = fits & (all_zero | lane_ok)
+            mask = mask & m_fit
+
+        m_portb = hv["m_portb"]
+        m_spread = hv["m_spread"]
+        m_interpod = hv["m_interpod"]
+        mask = mask & m_spread & m_interpod
+        feas = mask
+        if sample_k is not None:
+            # adaptive-sampling cut: keep the first sample_k feasible nodes
+            # in ZONE-ROUND-ROBIN rotation order from the carried start
+            # index — dc.visit_rank is the nodeTree order
+            # (node_tree.go:119-143) that the reference's sampling,
+            # rotation, and tie-breaks all ride
+            nv = jnp.maximum(dc.n_valid_nodes, 1)
+            start = state["sample_start"]
+            vr = dc.visit_rank
+            valid_vr = vr >= 0
+            rank = jnp.where(valid_vr, (vr - start) % nv, N)
+            rot = (
+                jnp.zeros((N + 1,), bool)
+                .at[rank]
+                .set(feas & valid_vr, mode="drop")[:N]
             )
-        conflict = req[None, :] > avail  # [N, Rp]
-        # extended-resource lanes only count when actually requested
-        scalar_lane = jnp.arange(Rp) >= N_FIXED_LANES
-        conflict = conflict & (~scalar_lane | (req > 0))[None, :]
-        lane_ok = ~jnp.any(conflict, axis=1)
-        m_fit = fits & (all_zero | lane_ok)
-        mask = mask & m_fit
+            cum = jnp.cumsum(rot.astype(I32))
+            keep_rot = rot & (cum <= sample_k)
+            feas = (
+                jnp.concatenate([keep_rot, jnp.zeros((1,), bool)])[rank]
+                & feas
+            )
+            total_feas = cum[N - 1]
+            processed = jnp.where(
+                total_feas >= sample_k,
+                jnp.sum((cum < sample_k).astype(I32)) + 1,
+                nv,
+            )
+        n_feas = jnp.sum(feas.astype(I32))
 
-    m_portb = hv["m_portb"]
-    m_spread = hv["m_spread"]
-    m_interpod = hv["m_interpod"]
-    mask = mask & m_spread & m_interpod
-    feas = mask
-    if sample_k is not None:
-        # adaptive-sampling cut: keep the first sample_k feasible nodes
-        # in ZONE-ROUND-ROBIN rotation order from the carried start
-        # index — dc.visit_rank is the nodeTree order
-        # (node_tree.go:119-143) that the reference's sampling,
-        # rotation, and tie-breaks all ride
-        nv = jnp.maximum(dc.n_valid_nodes, 1)
-        start = state["sample_start"]
-        vr = dc.visit_rank
-        valid_vr = vr >= 0
-        rank = jnp.where(valid_vr, (vr - start) % nv, N)
-        rot = (
-            jnp.zeros((N + 1,), bool)
-            .at[rank]
-            .set(feas & valid_vr, mode="drop")[:N]
-        )
-        cum = jnp.cumsum(rot.astype(I32))
-        keep_rot = rot & (cum <= sample_k)
-        feas = (
-            jnp.concatenate([keep_rot, jnp.zeros((1,), bool)])[rank]
-            & feas
-        )
-        total_feas = cum[N - 1]
-        processed = jnp.where(
-            total_feas >= sample_k,
-            jnp.sum((cum < sample_k).astype(I32)) + 1,
-            nv,
-        )
-    n_feas = jnp.sum(feas.astype(I32))
+        # ---------------- failure diagnosis ----------------
+        # Per-kernel rejected-node counts with first-failure attribution in
+        # the reference's filter chain order (findNodesThatPassFilters
+        # early-exits per node; FitError aggregates counts per reason).
+        remaining = dc.node_valid & db.valid[p]
+        reason_counts = []
+        for comp in (
+            g.d_unsched[p],
+            g.d_nodename[p],
+            g.d_taints[p],
+            g.d_nodeaff[p],
+            g.d_ports[p] & m_portb,
+            g.d_extra[p],
+            m_fit,
+            m_spread,
+            m_interpod,
+        ):
+            rejected = remaining & ~comp
+            reason_counts.append(jnp.sum(rejected.astype(I32)))
+            remaining = remaining & comp
+        reason_counts = jnp.stack(reason_counts)  # [N_DIAG]
 
-    # ---------------- failure diagnosis ----------------
-    # Per-kernel rejected-node counts with first-failure attribution in
-    # the reference's filter chain order (findNodesThatPassFilters
-    # early-exits per node; FitError aggregates counts per reason).
-    remaining = dc.node_valid & db.valid[p]
-    reason_counts = []
-    for comp in (
-        g.d_unsched[p],
-        g.d_nodename[p],
-        g.d_taints[p],
-        g.d_nodeaff[p],
-        g.d_ports[p] & m_portb,
-        g.d_extra[p],
-        m_fit,
-        m_spread,
-        m_interpod,
-    ):
-        rejected = remaining & ~comp
-        reason_counts.append(jnp.sum(rejected.astype(I32)))
-        remaining = remaining & comp
-    reason_counts = jnp.stack(reason_counts)  # [N_DIAG]
+    with jax.named_scope("ktpu/gang/score"):
+        # ---------------- scores ----------------
+        # NodeResourcesFit scoring strategy on non-zero-defaulted requests
+        # (resource_allocation.go:37-115): LeastAllocated (default),
+        # MostAllocated, or RequestedToCapacityRatio over cpu/memory.
+        strat_id, fit_shape, fit_w = fit_strategy
+        nz = (
+            state["nonzero"].astype(I64)
+            + db.nonzero_req[p][None, :].astype(I64)
+        )  # [N, 2]
+        alloc2 = jnp.stack(
+            [dc.allocatable[:, LANE_CPU], dc.allocatable[:, LANE_MEM]], axis=1
+        ).astype(I64)
+        lane_has = alloc2 > 0
+        if strat_id == 1:  # MostAllocated (most_allocated.go)
+            frac = jnp.where(
+                nz > alloc2, 0, nz * MAX // jnp.maximum(alloc2, 1)
+            )
+        elif strat_id == 2:  # RequestedToCapacityRatio
+            util = jnp.where(
+                ~lane_has | (nz > alloc2),
+                MAX,
+                nz * MAX // jnp.maximum(alloc2, 1),
+            )
+            frac = _broken_linear_dev(fit_shape, util)
+        else:  # LeastAllocated (least_allocated.go:29-60)
+            frac = jnp.where(
+                nz > alloc2, 0, (alloc2 - nz) * MAX // jnp.maximum(alloc2, 1)
+            )
+        w2 = jnp.asarray(fit_w, I64)[None, :]
+        # RTCR only counts resources whose score is positive
+        # (requested_to_capacity_ratio.go:46-52)
+        use = lane_has & (frac > 0) if strat_id == 2 else lane_has
+        wsum = jnp.sum(jnp.where(use, w2, 0), axis=1)
+        total_fit = jnp.sum(jnp.where(use, frac * w2, 0), axis=1)
+        if strat_id == 2:  # math.Round of the weighted mean
+            least = jnp.where(
+                wsum > 0,
+                (2 * total_fit + wsum) // jnp.maximum(2 * wsum, 1),
+                0,
+            )
+        else:
+            least = jnp.where(
+                wsum > 0, total_fit // jnp.maximum(wsum, 1), 0
+            )
 
-    # ---------------- scores ----------------
-    # NodeResourcesFit scoring strategy on non-zero-defaulted requests
-    # (resource_allocation.go:37-115): LeastAllocated (default),
-    # MostAllocated, or RequestedToCapacityRatio over cpu/memory.
-    strat_id, fit_shape, fit_w = fit_strategy
-    nz = (
-        state["nonzero"].astype(I64)
-        + db.nonzero_req[p][None, :].astype(I64)
-    )  # [N, 2]
-    alloc2 = jnp.stack(
-        [dc.allocatable[:, LANE_CPU], dc.allocatable[:, LANE_MEM]], axis=1
-    ).astype(I64)
-    lane_has = alloc2 > 0
-    if strat_id == 1:  # MostAllocated (most_allocated.go)
-        frac = jnp.where(
-            nz > alloc2, 0, nz * MAX // jnp.maximum(alloc2, 1)
+        # BalancedAllocation on real requests
+        a0 = dc.allocatable[:, LANE_CPU].astype(I64)
+        a1 = dc.allocatable[:, LANE_MEM].astype(I64)
+        r0 = jnp.minimum(
+            state["requested"][:, LANE_CPU].astype(I64)
+            + db.requests[p, LANE_CPU].astype(I64),
+            a0,
         )
-    elif strat_id == 2:  # RequestedToCapacityRatio
-        util = jnp.where(
-            ~lane_has | (nz > alloc2),
-            MAX,
-            nz * MAX // jnp.maximum(alloc2, 1),
+        r1 = jnp.minimum(
+            state["requested"][:, LANE_MEM].astype(I64)
+            + db.requests[p, LANE_MEM].astype(I64),
+            a1,
         )
-        frac = _broken_linear_dev(fit_shape, util)
-    else:  # LeastAllocated (least_allocated.go:29-60)
-        frac = jnp.where(
-            nz > alloc2, 0, (alloc2 - nz) * MAX // jnp.maximum(alloc2, 1)
-        )
-    w2 = jnp.asarray(fit_w, I64)[None, :]
-    # RTCR only counts resources whose score is positive
-    # (requested_to_capacity_ratio.go:46-52)
-    use = lane_has & (frac > 0) if strat_id == 2 else lane_has
-    wsum = jnp.sum(jnp.where(use, w2, 0), axis=1)
-    total_fit = jnp.sum(jnp.where(use, frac * w2, 0), axis=1)
-    if strat_id == 2:  # math.Round of the weighted mean
-        least = jnp.where(
-            wsum > 0,
-            (2 * total_fit + wsum) // jnp.maximum(2 * wsum, 1),
-            0,
-        )
-    else:
-        least = jnp.where(
-            wsum > 0, total_fit // jnp.maximum(wsum, 1), 0
+        d = jnp.abs(r0 * a1 - r1 * a0)
+        den = jnp.maximum(a0 * a1, 1)
+        balanced = jnp.where(
+            (a0 > 0) & (a1 > 0), MAX - (50 * d + den - 1) // den, MAX
         )
 
-    # BalancedAllocation on real requests
-    a0 = dc.allocatable[:, LANE_CPU].astype(I64)
-    a1 = dc.allocatable[:, LANE_MEM].astype(I64)
-    r0 = jnp.minimum(
-        state["requested"][:, LANE_CPU].astype(I64)
-        + db.requests[p, LANE_CPU].astype(I64),
-        a0,
-    )
-    r1 = jnp.minimum(
-        state["requested"][:, LANE_MEM].astype(I64)
-        + db.requests[p, LANE_MEM].astype(I64),
-        a1,
-    )
-    d = jnp.abs(r0 * a1 - r1 * a0)
-    den = jnp.maximum(a0 * a1, 1)
-    balanced = jnp.where(
-        (a0 > 0) & (a1 > 0), MAX - (50 * d + den - 1) // den, MAX
-    )
+        # InterPodAffinity: static symmetric + incoming preferred (with batch
+        # contributions) + symmetric from batch-assigned pods' terms —
+        # carried in hv.
+        ip_raw = hv["ip_raw"]
 
-    # InterPodAffinity: static symmetric + incoming preferred (with batch
-    # contributions) + symmetric from batch-assigned pods' terms —
-    # carried in hv.
-    ip_raw = hv["ip_raw"]
+        # PodTopologySpread score: the count rows come from hv; the
+        # log-weight normalization depends on the LIVE feasible set, so it
+        # runs here per pod.
+        if C:
+            sp_raw, sp_valid = _spread_raw(
+                dc, db, g, p, feas, hv["sp_cnt"], d_cap
+            )
+        else:
+            sp_raw = jnp.zeros((N,), I64)
+            sp_valid = feas
 
-    # PodTopologySpread score: the count rows come from hv; the
-    # log-weight normalization depends on the LIVE feasible set, so it
-    # runs here per pod.
-    if C:
-        sp_raw, sp_valid = _spread_raw(
-            dc, db, g, p, feas, hv["sp_cnt"], d_cap
-        )
-    else:
-        sp_raw = jnp.zeros((N,), I64)
-        sp_valid = feas
+        w_taint, w_naff, w_spread, w_ip, w_fit, w_bal, w_img = weights
+        total_score = jnp.zeros((N,), I64)
+        if w_taint:
+            total_score += w_taint * _norm_default(
+                g.sc_taint[p], feas, reverse=True
+            )
+        if w_naff:
+            total_score += w_naff * _norm_default(g.sc_nodeaff[p], feas)
+        if w_spread:
+            total_score += w_spread * _norm_spread(sp_raw, sp_valid, feas)
+        if w_ip:
+            total_score += w_ip * _norm_minmax(ip_raw, feas)
+        if w_fit:
+            total_score += w_fit * least
+        if w_bal:
+            total_score += w_bal * balanced
+        if w_img:
+            total_score += w_img * g.sc_image[p]
+        if extra_score is not None:
+            total_score += extra_score[p]
 
-    w_taint, w_naff, w_spread, w_ip, w_fit, w_bal, w_img = weights
-    total_score = jnp.zeros((N,), I64)
-    if w_taint:
-        total_score += w_taint * _norm_default(
-            g.sc_taint[p], feas, reverse=True
-        )
-    if w_naff:
-        total_score += w_naff * _norm_default(g.sc_nodeaff[p], feas)
-    if w_spread:
-        total_score += w_spread * _norm_spread(sp_raw, sp_valid, feas)
-    if w_ip:
-        total_score += w_ip * _norm_minmax(ip_raw, feas)
-    if w_fit:
-        total_score += w_fit * least
-    if w_bal:
-        total_score += w_bal * balanced
-    if w_img:
-        total_score += w_img * g.sc_image[p]
-    if extra_score is not None:
-        total_score += extra_score[p]
-
-    neg = jnp.iinfo(jnp.int64).min
-    if tie_key is not None:
-        # seeded uniform tie-break: lexicographic (score, hash) argmax
-        # — every max-score node equally likely, deterministic per
-        # (seed, attempt) (selectHost reservoir analogue)
-        k_p = jax.random.fold_in(tie_key, attempt_base + p)
-        h = jax.random.bits(k_p, (N,), dtype=jnp.uint32).astype(I64)
-        ranked = jnp.where(feas, total_score * (1 << 33) + h, neg)
-        choice = jnp.argmax(ranked).astype(I32)
-    elif sample_k is not None:
-        # compat first-max: among max-score nodes, pick the first in
-        # the zone-round-robin VISIT order (the reference appends
-        # feasible nodes in nodeTree walk order, so "first max" means
-        # first visited, not lowest packed slot)
-        ranked = jnp.where(feas, total_score, neg)
-        best = jnp.max(ranked)
-        tie_rank = jnp.where(feas & (ranked == best), rank, N + 1)
-        choice = jnp.argmin(tie_rank).astype(I32)
-    else:
-        ranked = jnp.where(feas, total_score, neg)
-        choice = jnp.argmax(ranked).astype(I32)
-    choice = jnp.where((n_feas > 0) & active, choice, ABSENT)
-    n_feas = jnp.where(active, n_feas, 0)
+    with jax.named_scope("ktpu/gang/select"):
+        neg = jnp.iinfo(jnp.int64).min
+        if tie_key is not None:
+            # seeded uniform tie-break: lexicographic (score, hash) argmax
+            # — every max-score node equally likely, deterministic per
+            # (seed, attempt) (selectHost reservoir analogue)
+            k_p = jax.random.fold_in(tie_key, attempt_base + p)
+            h = jax.random.bits(k_p, (N,), dtype=jnp.uint32).astype(I64)
+            ranked = jnp.where(feas, total_score * (1 << 33) + h, neg)
+            choice = jnp.argmax(ranked).astype(I32)
+        elif sample_k is not None:
+            # compat first-max: among max-score nodes, pick the first in
+            # the zone-round-robin VISIT order (the reference appends
+            # feasible nodes in nodeTree walk order, so "first max" means
+            # first visited, not lowest packed slot)
+            ranked = jnp.where(feas, total_score, neg)
+            best = jnp.max(ranked)
+            tie_rank = jnp.where(feas & (ranked == best), rank, N + 1)
+            choice = jnp.argmin(tie_rank).astype(I32)
+        else:
+            ranked = jnp.where(feas, total_score, neg)
+            choice = jnp.argmax(ranked).astype(I32)
+        choice = jnp.where((n_feas > 0) & active, choice, ABSENT)
+        n_feas = jnp.where(active, n_feas, 0)
 
     if not commit:
         return state, (choice, n_feas, reason_counts)
 
-    # ---------------- commit ----------------
-    committed = choice >= 0
-    new_state = dict(
-        state,
-        **usage_carry_update(
-            {k: state[k] for k in ("requested", "nonzero", "num_pods")},
-            {
-                "requested": db.requests[p][:Rn],
-                "nonzero": db.nonzero_req[p],
-                "num_pods": 1,
-            },
-            choice,
-            committed,
-        ),
-        # inactive (pad) slots must not clobber row p's assignment.
-        # p is the scan/vmap index over the batch axis — in range by
-        # construction; mode="drop" (the default, spelled out) documents
-        # the out-of-bounds semantics for the slice-clamp rule
-        assigned=state["assigned"]
-        .at[p]
-        .set(jnp.where(active, choice, state["assigned"][p]), mode="drop"),
-    )
-    if sample_k is not None:
-        # nextStartNodeIndex advances by nodes visited, per attempt
-        # (schedule_one.go:625), padded batch rows included like the
-        # reference's no-op cycles would be skipped: only real pods
-        # advance the rotation
-        new_state["sample_start"] = jnp.where(
-            db.valid[p],
-            (state["sample_start"] + processed) % nv,
-            state["sample_start"],
-        ).astype(I32)
+    with jax.named_scope("ktpu/gang/commit"):
+        # ---------------- commit ----------------
+        committed = choice >= 0
+        new_state = dict(
+            state,
+            **usage_carry_update(
+                {k: state[k] for k in ("requested", "nonzero", "num_pods")},
+                {
+                    "requested": db.requests[p][:Rn],
+                    "nonzero": db.nonzero_req[p],
+                    "num_pods": 1,
+                },
+                choice,
+                committed,
+            ),
+            # inactive (pad) slots must not clobber row p's assignment.
+            # p is the scan/vmap index over the batch axis — in range by
+            # construction; mode="drop" (the default, spelled out) documents
+            # the out-of-bounds semantics for the slice-clamp rule
+            assigned=state["assigned"]
+            .at[p]
+            .set(jnp.where(active, choice, state["assigned"][p]), mode="drop"),
+        )
+        if sample_k is not None:
+            # nextStartNodeIndex advances by nodes visited, per attempt
+            # (schedule_one.go:625), padded batch rows included like the
+            # reference's no-op cycles would be skipped: only real pods
+            # advance the rotation
+            new_state["sample_start"] = jnp.where(
+                db.valid[p],
+                (state["sample_start"] + processed) % nv,
+                state["sample_start"],
+            ).astype(I32)
     return new_state, (choice, n_feas, reason_counts)
 
 
@@ -1063,109 +1070,110 @@ def gang_schedule(
         """State-dependent tensors whose value cannot change while no
         INTERACTING peer commits: spread/inter-pod masks, count rows, and
         port conflicts.  The per-pod scan calls this every step."""
-        av = assigned_valid[None, :]
-        m_portb = true_n
-        if g.port_b.shape[1]:
-            port_conf = jnp.any(g.port_b[p][:, None] & eqJ, axis=0)
-            m_portb = ~port_conf
+        with jax.named_scope("ktpu/gang/heavy_parts"):
+            av = assigned_valid[None, :]
+            m_portb = true_n
+            if g.port_b.shape[1]:
+                port_conf = jnp.any(g.port_b[p][:, None] & eqJ, axis=0)
+                m_portb = ~port_conf
 
-        if C:
-            dv = g.sp_dv[p]  # [C, N]
-            # value-at-assigned-node via one-hot matmul instead of a gather
-            # (TPU gathers serialize; einsum rides the MXU).  Invalid peers
-            # produce 0 rows — every consumer is gated on av/bm.
-            eqJ_i = eqJ.astype(I32)
-            dv_at = jnp.einsum("cn,jn->cj", dv, eqJ_i)  # [C, J]
-            te_at = jnp.einsum("cn,jn->cj", g.sp_te[p].astype(I32), eqJ_i) > 0
-            bm = g.sp_bmatch[p] & av  # [C, J]
-            # Same-domain indicator of each node vs each assigned peer's
-            # node, as a fused dense compare (dv space): [C, N, J].
-            eq_dom = (
-                (dv[:, :, None] >= 0)
-                & (dv_at[:, None, :] >= 0)
-                & (dv[:, :, None] == dv_at[:, None, :])
-            )
-            dyn_f = jnp.sum(
-                (eq_dom & (bm & te_at)[:, None, :]).astype(I32), axis=2
-            )  # [C, N]
-            # score-side counts: _spread_cnt
-            dyn_host = jnp.einsum("cj,jn->cn", bm.astype(I32), eqJ_i)
-            cg_at = (
-                jnp.einsum(
-                    "cn,jn->cj", g.sp_counting[p].astype(I32), eqJ_i
+            if C:
+                dv = g.sp_dv[p]  # [C, N]
+                # value-at-assigned-node via one-hot matmul instead of a gather
+                # (TPU gathers serialize; einsum rides the MXU).  Invalid peers
+                # produce 0 rows — every consumer is gated on av/bm.
+                eqJ_i = eqJ.astype(I32)
+                dv_at = jnp.einsum("cn,jn->cj", dv, eqJ_i)  # [C, J]
+                te_at = jnp.einsum("cn,jn->cj", g.sp_te[p].astype(I32), eqJ_i) > 0
+                bm = g.sp_bmatch[p] & av  # [C, J]
+                # Same-domain indicator of each node vs each assigned peer's
+                # node, as a fused dense compare (dv space): [C, N, J].
+                eq_dom = (
+                    (dv[:, :, None] >= 0)
+                    & (dv_at[:, None, :] >= 0)
+                    & (dv[:, :, None] == dv_at[:, None, :])
                 )
-                > 0
-            )
-            dyn_dom = jnp.sum(
-                (eq_dom & (bm & cg_at)[:, None, :]).astype(I32), axis=2
-            )
-            m_spread, sp_cnt, _ = spread_constraints(
-                db, g, p, SpreadDyn(dyn_f, dyn_host, dyn_dom)
-            )
-        else:
-            m_spread = true_n
-            sp_cnt = jnp.zeros((C, N), I32)
+                dyn_f = jnp.sum(
+                    (eq_dom & (bm & te_at)[:, None, :]).astype(I32), axis=2
+                )  # [C, N]
+                # score-side counts: _spread_cnt
+                dyn_host = jnp.einsum("cj,jn->cn", bm.astype(I32), eqJ_i)
+                cg_at = (
+                    jnp.einsum(
+                        "cn,jn->cj", g.sp_counting[p].astype(I32), eqJ_i
+                    )
+                    > 0
+                )
+                dyn_dom = jnp.sum(
+                    (eq_dom & (bm & cg_at)[:, None, :]).astype(I32), axis=2
+                )
+                m_spread, sp_cnt, _ = spread_constraints(
+                    db, g, p, SpreadDyn(dyn_f, dyn_host, dyn_dom)
+                )
+            else:
+                m_spread = true_n
+                sp_cnt = jnp.zeros((C, N), I32)
 
-        if AT:
-            ip_dv = g.ip_dv[p]  # [AT, N]
-            ip_dv_at = jnp.einsum("tn,jn->tj", ip_dv, eqJ.astype(I32))
-            ip_eq = (
-                (ip_dv[:, :, None] >= 0)
-                & (ip_dv_at[:, None, :] >= 0)
-                & (ip_dv[:, :, None] == ip_dv_at[:, None, :])
-            )  # [AT, N, J]
-            ip_bm = g.ip_bmatch[p] & av  # [AT, J]
-            ip_dyn = jnp.sum((ip_eq & ip_bm[:, None, :]).astype(I32), axis=2)
-            any_dyn = jnp.any(g.ip_is_aff[p][:, None] & ip_bm)
+            if AT:
+                ip_dv = g.ip_dv[p]  # [AT, N]
+                ip_dv_at = jnp.einsum("tn,jn->tj", ip_dv, eqJ.astype(I32))
+                ip_eq = (
+                    (ip_dv[:, :, None] >= 0)
+                    & (ip_dv_at[:, None, :] >= 0)
+                    & (ip_dv[:, :, None] == ip_dv_at[:, None, :])
+                )  # [AT, N, J]
+                ip_bm = g.ip_bmatch[p] & av  # [AT, J]
+                ip_dyn = jnp.sum((ip_eq & ip_bm[:, None, :]).astype(I32), axis=2)
+                any_dyn = jnp.any(g.ip_is_aff[p][:, None] & ip_bm)
 
-            # Batch-assigned peers' terms vs p, factored by distinct topology
-            # key so the contraction reads [Kd2, N] columns instead of the
-            # full [P, AT, N] domain tensor each step.  dv_ju[j, u] = the
-            # topology value at j's assigned node for j's term u.
-            m_jp = g.ip_bmatch[:, :, p] & assigned_valid[:, None]  # [J, AT]
-            cols_at_a = jnp.einsum(
-                "kn,jn->kj", g.ip_key_cols, eqJ.astype(I32)
-            )  # [Kd2, J]
-            ki = g.ip_key_idx  # [J, AT]
-            ki_clip = jnp.clip(ki, 0, Kd2 - 1)
-            ki_oh = (
-                ki_clip[:, :, None] == jnp.arange(Kd2, dtype=I32)[None, None, :]
-            ).astype(I32)  # [J, AT, Kd2]
-            dv_ju = jnp.einsum("jk,juk->ju", cols_at_a.T, ki_oh)  # [J, AT]
-            term_live = m_jp & (ki >= 0) & (dv_ju >= 0)
-            g_anti = (term_live & g.ip_is_anti).reshape(-1)  # [J·AT]
-            w_sym = jnp.where(term_live, g.ip_sym_w, 0).astype(I32).reshape(-1)
-            ki_f = ki_clip.reshape(-1)
-            live_f = (ki >= 0).reshape(-1)
-            dvf = dv_ju.reshape(-1)
-            viol_b = jnp.zeros((N,), bool)
-            sym_b = jnp.zeros((N,), I32)
-            for k in range(Kd2):
-                in_k = live_f & (ki_f == k)
-                eqk = (dvf[:, None] == g.ip_key_cols[k][None, :]) & (
-                    g.ip_key_cols[k] >= 0
-                )[None, :]  # [J·AT, N]
-                viol_b = viol_b | jnp.any(
-                    (g_anti & in_k)[:, None] & eqk, axis=0
+                # Batch-assigned peers' terms vs p, factored by distinct topology
+                # key so the contraction reads [Kd2, N] columns instead of the
+                # full [P, AT, N] domain tensor each step.  dv_ju[j, u] = the
+                # topology value at j's assigned node for j's term u.
+                m_jp = g.ip_bmatch[:, :, p] & assigned_valid[:, None]  # [J, AT]
+                cols_at_a = jnp.einsum(
+                    "kn,jn->kj", g.ip_key_cols, eqJ.astype(I32)
+                )  # [Kd2, J]
+                ki = g.ip_key_idx  # [J, AT]
+                ki_clip = jnp.clip(ki, 0, Kd2 - 1)
+                ki_oh = (
+                    ki_clip[:, :, None] == jnp.arange(Kd2, dtype=I32)[None, None, :]
+                ).astype(I32)  # [J, AT, Kd2]
+                dv_ju = jnp.einsum("jk,juk->ju", cols_at_a.T, ki_oh)  # [J, AT]
+                term_live = m_jp & (ki >= 0) & (dv_ju >= 0)
+                g_anti = (term_live & g.ip_is_anti).reshape(-1)  # [J·AT]
+                w_sym = jnp.where(term_live, g.ip_sym_w, 0).astype(I32).reshape(-1)
+                ki_f = ki_clip.reshape(-1)
+                live_f = (ki >= 0).reshape(-1)
+                dvf = dv_ju.reshape(-1)
+                viol_b = jnp.zeros((N,), bool)
+                sym_b = jnp.zeros((N,), I32)
+                for k in range(Kd2):
+                    in_k = live_f & (ki_f == k)
+                    eqk = (dvf[:, None] == g.ip_key_cols[k][None, :]) & (
+                        g.ip_key_cols[k] >= 0
+                    )[None, :]  # [J·AT, N]
+                    viol_b = viol_b | jnp.any(
+                        (g_anti & in_k)[:, None] & eqk, axis=0
+                    )
+                    sym_b = sym_b + jnp.einsum(
+                        "t,tn->n",
+                        jnp.where(in_k, w_sym, 0),
+                        eqk.astype(I32),
+                    )
+                m_interpod, ip_raw, _ = interpod_constraints(
+                    g, p, InterpodDyn(ip_dyn, viol_b, sym_b.astype(I64), any_dyn)
                 )
-                sym_b = sym_b + jnp.einsum(
-                    "t,tn->n",
-                    jnp.where(in_k, w_sym, 0),
-                    eqk.astype(I32),
-                )
-            m_interpod, ip_raw, _ = interpod_constraints(
-                g, p, InterpodDyn(ip_dyn, viol_b, sym_b.astype(I64), any_dyn)
+            else:
+                m_interpod = true_n
+                ip_raw = g.ip_sym[p]
+            return dict(
+                m_portb=m_portb,
+                m_spread=m_spread,
+                sp_cnt=sp_cnt,
+                m_interpod=m_interpod,
+                ip_raw=ip_raw,
             )
-        else:
-            m_interpod = true_n
-            ip_raw = g.ip_sym[p]
-        return dict(
-            m_portb=m_portb,
-            m_spread=m_spread,
-            sp_cnt=sp_cnt,
-            m_interpod=m_interpod,
-            ip_raw=ip_raw,
-        )
 
     def step(state, p):
         assigned_valid, eqJ = peer_view(state["assigned"])

@@ -319,96 +319,99 @@ def resident_run(
 
     def round_body(carry):
         q, used, nz0, nz1, num_pods, choices, rounds, q_ckpt, stop = carry
-        keys = _sig_node_keys(
-            sig_req, sig_nz, sig_allzero, sig_ok, sig_img,
-            alloc, allowed, used, nz0, nz1, num_pods, **score_kw
-        )  # [S, N]
-        win = jax.lax.dynamic_slice(ids_pad, (q,), (W,))  # [W]
-        live = win >= 0
-        sig_w = jnp.maximum(win, 0)
-        # shared consumption walk: nodes in the window head's preference
-        # order (keys are unique, so argsort is deterministic)
-        order = jnp.argsort(-keys[sig_w[0]]).astype(I32)  # [N]
-        skey = keys[:, order]  # [S, N] every sig's keys along the walk
-        sufmax = jnp.flip(
-            jax.lax.cummax(jnp.flip(skey, axis=1), axis=1), axis=1
-        )  # [S, N] best untouched key at-or-after each position
-        dead = sufmax[:, 0] < 0  # [S] no feasible node at all this round
-        dead_w = dead[sig_w] & live
-        sched_spec = live & ~dead_w  # speculated to consume a position
-        si = sched_spec.astype(I32)
-        pos = jnp.minimum(jnp.cumsum(si) - si, N - 1)  # exclusive count
-        ckey = skey[sig_w, pos]  # [W] speculated placement's key
-        csuf = sufmax[sig_w, pos]  # [W] its sig's true untouched max
-        cnode = order[pos]  # [W]
-        u = _upd_keys(
-            cnode, sig_w, sig_req, sig_nz, sig_allzero, sig_ok, sig_img,
-            alloc, allowed, used, nz0, nz1, num_pods, **score_kw
-        )  # [W, S] post-commit keys of each slot's node
-        u = jnp.where(sched_spec[:, None], u, NEG)
-        # exclusive running max over predecessors' committed nodes
-        thr = jax.lax.cummax(u, axis=0)
-        thr = jnp.concatenate([jnp.full((1, u.shape[1]), NEG, I64), thr[:-1]])
-        thr_i = thr[iota_w, sig_w]  # [W]
-        ok_sched = sched_spec & (ckey >= 0) & (ckey == csuf) & (ckey > thr_i)
-        agree = ok_sched | dead_w
-        disagree = ~agree
-        any_dis = jnp.any(disagree)
-        first = jnp.argmax(disagree).astype(I32)
-        A = jnp.where(any_dis, first, W)  # admitted prefix length (>= 1)
-        adm = iota_w < A
-        commit = adm & ok_sched
-        # windowed form of THE shared usage commit (ops/common.py): each
-        # walk position commits at most once per round, so the scatter-add
-        # equals replaying the scalar rank-1 form per admitted slot
-        rows = usage_carry_update(
-            {"used": used, "nz0": nz0, "nz1": nz1, "num_pods": num_pods},
-            {
-                "used": sig_req[sig_w],
-                "nz0": sig_nz[sig_w, 0],
-                "nz1": sig_nz[sig_w, 1],
-                "num_pods": 1,
-            },
-            cnode,
-            commit,
-        )
-        used, nz0, nz1, num_pods = (
-            rows["used"], rows["nz0"], rows["nz1"], rows["num_pods"]
-        )
-        cvals = jnp.where(commit, cnode, -1)  # admitted dead pods: -1
-        # choices is padded by W so this window write NEVER reaches the
-        # array end — XLA CLAMPS out-of-range dynamic_update_slice starts,
-        # which would silently shift the write onto earlier results
-        old = jax.lax.dynamic_slice(choices, (q,), (W,))
-        choices = jax.lax.dynamic_update_slice(
-            choices, jnp.where(adm & live, cvals, old), (q,)
-        )
-        q = q + A
-        rounds = rounds + 1
-        # adaptive stop: every STOP_GRACE rounds the loop must have
-        # yielded STOP_GRACE*MIN_YIELD admissions since the checkpoint —
-        # workloads whose agreement prefixes collapse (adversarial sig
-        # interleavings) hand over to the tail instead of burning rounds
-        at_ckpt = rounds % STOP_GRACE == 0
-        stop = at_ckpt & (q - q_ckpt < STOP_GRACE * min_yield)
-        q_ckpt = jnp.where(at_ckpt, q, q_ckpt)
-        return (q, used, nz0, nz1, num_pods, choices, rounds, q_ckpt, stop)
+        with jax.named_scope("ktpu/resident/round"):
+            keys = _sig_node_keys(
+                sig_req, sig_nz, sig_allzero, sig_ok, sig_img,
+                alloc, allowed, used, nz0, nz1, num_pods, **score_kw
+            )  # [S, N]
+            win = jax.lax.dynamic_slice(ids_pad, (q,), (W,))  # [W]
+            live = win >= 0
+            sig_w = jnp.maximum(win, 0)
+            # shared consumption walk: nodes in the window head's preference
+            # order (keys are unique, so argsort is deterministic)
+            order = jnp.argsort(-keys[sig_w[0]]).astype(I32)  # [N]
+            skey = keys[:, order]  # [S, N] every sig's keys along the walk
+            sufmax = jnp.flip(
+                jax.lax.cummax(jnp.flip(skey, axis=1), axis=1), axis=1
+            )  # [S, N] best untouched key at-or-after each position
+            dead = sufmax[:, 0] < 0  # [S] no feasible node at all this round
+            dead_w = dead[sig_w] & live
+            sched_spec = live & ~dead_w  # speculated to consume a position
+            si = sched_spec.astype(I32)
+            pos = jnp.minimum(jnp.cumsum(si) - si, N - 1)  # exclusive count
+            ckey = skey[sig_w, pos]  # [W] speculated placement's key
+            csuf = sufmax[sig_w, pos]  # [W] its sig's true untouched max
+            cnode = order[pos]  # [W]
+            u = _upd_keys(
+                cnode, sig_w, sig_req, sig_nz, sig_allzero, sig_ok, sig_img,
+                alloc, allowed, used, nz0, nz1, num_pods, **score_kw
+            )  # [W, S] post-commit keys of each slot's node
+            u = jnp.where(sched_spec[:, None], u, NEG)
+            # exclusive running max over predecessors' committed nodes
+            thr = jax.lax.cummax(u, axis=0)
+            thr = jnp.concatenate([jnp.full((1, u.shape[1]), NEG, I64), thr[:-1]])
+            thr_i = thr[iota_w, sig_w]  # [W]
+            ok_sched = sched_spec & (ckey >= 0) & (ckey == csuf) & (ckey > thr_i)
+            agree = ok_sched | dead_w
+            disagree = ~agree
+            any_dis = jnp.any(disagree)
+            first = jnp.argmax(disagree).astype(I32)
+            A = jnp.where(any_dis, first, W)  # admitted prefix length (>= 1)
+            adm = iota_w < A
+            commit = adm & ok_sched
+            # windowed form of THE shared usage commit (ops/common.py): each
+            # walk position commits at most once per round, so the scatter-add
+            # equals replaying the scalar rank-1 form per admitted slot
+            rows = usage_carry_update(
+                {"used": used, "nz0": nz0, "nz1": nz1, "num_pods": num_pods},
+                {
+                    "used": sig_req[sig_w],
+                    "nz0": sig_nz[sig_w, 0],
+                    "nz1": sig_nz[sig_w, 1],
+                    "num_pods": 1,
+                },
+                cnode,
+                commit,
+            )
+            used, nz0, nz1, num_pods = (
+                rows["used"], rows["nz0"], rows["nz1"], rows["num_pods"]
+            )
+            cvals = jnp.where(commit, cnode, -1)  # admitted dead pods: -1
+            # choices is padded by W so this window write NEVER reaches the
+            # array end — XLA CLAMPS out-of-range dynamic_update_slice starts,
+            # which would silently shift the write onto earlier results
+            old = jax.lax.dynamic_slice(choices, (q,), (W,))
+            choices = jax.lax.dynamic_update_slice(
+                choices, jnp.where(adm & live, cvals, old), (q,)
+            )
+            q = q + A
+            rounds = rounds + 1
+            # adaptive stop: every STOP_GRACE rounds the loop must have
+            # yielded STOP_GRACE*MIN_YIELD admissions since the checkpoint —
+            # workloads whose agreement prefixes collapse (adversarial sig
+            # interleavings) hand over to the tail instead of burning rounds
+            at_ckpt = rounds % STOP_GRACE == 0
+            stop = at_ckpt & (q - q_ckpt < STOP_GRACE * min_yield)
+            q_ckpt = jnp.where(at_ckpt, q, q_ckpt)
+            return (q, used, nz0, nz1, num_pods, choices, rounds, q_ckpt, stop)
 
     def round_cond(carry):
         q, _, _, _, _, _, rounds, _, stop = carry
         return (q < p_live) & (rounds < r_cap) & ~stop
 
     choices0 = jnp.full((P + W,), UNRESOLVED, I32)
-    (
-        q, used, nz0, nz1, num_pods, choices, rounds, _, _
-    ) = jax.lax.while_loop(
-        round_cond,
-        round_body,
+    # the loop's own machinery (condition, carry) belongs to the rounds too
+    with jax.named_scope("ktpu/resident/round"):
         (
-            jnp.zeros((), I32), used, nz0, nz1, num_pods, choices0,
-            jnp.zeros((), I64), jnp.zeros((), I32), jnp.zeros((), bool),
-        ),
-    )
+            q, used, nz0, nz1, num_pods, choices, rounds, _, _
+        ) = jax.lax.while_loop(
+            round_cond,
+            round_body,
+            (
+                jnp.zeros((), I32), used, nz0, nz1, num_pods, choices0,
+                jnp.zeros((), I64), jnp.zeros((), I32), jnp.zeros((), bool),
+            ),
+        )
     choices = choices[:P]
     tail_left = q < p_live
 

@@ -796,11 +796,12 @@ def wave_schedule(
     # cursor — the admission pass alone carries the advancing cursor, and
     # speculation feeds only the stats/attribution outputs)
     def spec_one(p):
-        hv, _, _ = build_hv(p, zero_sdyn(), zero_idyn(), true_n)
-        _, (choice, _, _) = gang.pod_step(
-            dc, db, g, p, base, hv, jnp.asarray(True), commit=False, **step_kw
-        )
-        return choice
+        with jax.named_scope("ktpu/wave/speculation"):
+            hv, _, _ = build_hv(p, zero_sdyn(), zero_idyn(), true_n)
+            _, (choice, _, _) = gang.pod_step(
+                dc, db, g, p, base, hv, jnp.asarray(True), commit=False, **step_kw
+            )
+            return choice
 
     c0 = jax.vmap(spec_one)(jnp.arange(P, dtype=I32))
 
@@ -825,142 +826,145 @@ def wave_schedule(
     carry_keys = FACTORED_CARRY_KEYS[:3] + (("occ_pt",) if Tpt else ())
 
     def step(state, p):
-        if C:
-            sdyn = factored_spread_dyn(g, p, tid_sp, state["cnt_sp"], d_cap)
-        else:
-            sdyn = zero_sdyn()
+        with jax.named_scope("ktpu/wave/admission"):
+            if C:
+                sdyn = factored_spread_dyn(g, p, tid_sp, state["cnt_sp"], d_cap)
+            else:
+                sdyn = zero_sdyn()
 
-        if AT:
-            idyn, ip_aux = factored_interpod_dyn(
-                g,
-                db,
-                p,
-                tid_ip,
-                ip_cdv_tab,
-                d2_cap,
-                hostname_key,
-                state["cnt_ip"],
-                state["rev_cnt"],
-                m_ip_all,
-                t_anti,
-                t_w,
-            )
-        else:
-            idyn = zero_idyn()
-            ip_aux = None
-
-        if has_ports:
-            m_portb, pt_cnt = factored_port_mask(
-                tid_pt, port_conf, state["occ_pt"], p
-            )
-        else:
-            m_portb, pt_cnt = true_n, None
-
-        hv, c_ok, anti_viol = build_hv(p, sdyn, idyn, m_portb)
-        new_state, (choice, n_feas, reason_counts) = gang.pod_step(
-            dc, db, g, p, state, hv, jnp.asarray(True), **step_kw
-        )
-
-        # carry updates: dense rank-1 outer products, no scatters
-        new_state.update(
-            factored_carry_update(
-                {k: state[k] for k in carry_keys},
-                p,
-                choice,
-                m_sp_all,
-                m_ip_all,
-                ip_aux,
-                pt_cnt=pt_cnt,
-            )
-        )
-
-        # demotion attribution vs the speculative candidate: evaluated at
-        # the pod's own step, where the carries are exactly the serial
-        # prefix — "why this speculation failed in the serial order"
-        spec = c0[p]
-        spec_live = spec >= 0
-        at = jnp.clip(spec, 0, N - 1)
-        pt_bad = spec_live & ~m_portb[at]
-        sp_bad = spec_live & ~hv["m_spread"][at]
-        ip_bad = spec_live & ~hv["m_interpod"][at]
-        # resource-contention demotion: earlier wave commits consumed the
-        # speculative node (the dominant cause on tight clusters) —
-        # checked against the PRE-commit state this pod's verdict saw.
-        # Nominated-pod charges are not replayed here (attribution only;
-        # a nomination-induced fit failure reports as "score").
-        if check_fit:
-            Rn = dc.requested.shape[1]
-            Rp = db.requests.shape[1]
-            req = db.requests[p]
-            avail = dc.allocatable[at] - state["requested"][at]  # [Rn]
-            if Rp > Rn:
-                avail = jnp.concatenate(
-                    [avail, jnp.zeros((Rp - Rn,), I32)]
+            if AT:
+                idyn, ip_aux = factored_interpod_dyn(
+                    g,
+                    db,
+                    p,
+                    tid_ip,
+                    ip_cdv_tab,
+                    d2_cap,
+                    hostname_key,
+                    state["cnt_ip"],
+                    state["rev_cnt"],
+                    m_ip_all,
+                    t_anti,
+                    t_w,
                 )
-            scalar_lane = jnp.arange(Rp) >= N_FIXED_LANES
-            conflict = (req > avail) & (~scalar_lane | (req > 0))
-            lane_bad = jnp.any(conflict) & ~jnp.all(req == 0)
-            pods_bad = state["num_pods"][at] + 1 > dc.allowed_pods[at]
-            fit_bad = spec_live & (lane_bad | pods_bad)
-        else:
-            fit_bad = jnp.asarray(False)
-        demoted = choice != spec
-        kind = jnp.where(
-            ~demoted,
-            DEMOTE_NONE,
-            jnp.where(
-                ~spec_live,
-                DEMOTE_UPGRADE,
+            else:
+                idyn = zero_idyn()
+                ip_aux = None
+
+            if has_ports:
+                m_portb, pt_cnt = factored_port_mask(
+                    tid_pt, port_conf, state["occ_pt"], p
+                )
+            else:
+                m_portb, pt_cnt = true_n, None
+
+            hv, c_ok, anti_viol = build_hv(p, sdyn, idyn, m_portb)
+            new_state, (choice, n_feas, reason_counts) = gang.pod_step(
+                dc, db, g, p, state, hv, jnp.asarray(True), **step_kw
+            )
+
+            # carry updates: dense rank-1 outer products, no scatters
+            new_state.update(
+                factored_carry_update(
+                    {k: state[k] for k in carry_keys},
+                    p,
+                    choice,
+                    m_sp_all,
+                    m_ip_all,
+                    ip_aux,
+                    pt_cnt=pt_cnt,
+                )
+            )
+
+            # demotion attribution vs the speculative candidate: evaluated at
+            # the pod's own step, where the carries are exactly the serial
+            # prefix — "why this speculation failed in the serial order"
+            spec = c0[p]
+            spec_live = spec >= 0
+            at = jnp.clip(spec, 0, N - 1)
+            pt_bad = spec_live & ~m_portb[at]
+            sp_bad = spec_live & ~hv["m_spread"][at]
+            ip_bad = spec_live & ~hv["m_interpod"][at]
+            # resource-contention demotion: earlier wave commits consumed the
+            # speculative node (the dominant cause on tight clusters) —
+            # checked against the PRE-commit state this pod's verdict saw.
+            # Nominated-pod charges are not replayed here (attribution only;
+            # a nomination-induced fit failure reports as "score").
+            if check_fit:
+                Rn = dc.requested.shape[1]
+                Rp = db.requests.shape[1]
+                req = db.requests[p]
+                avail = dc.allocatable[at] - state["requested"][at]  # [Rn]
+                if Rp > Rn:
+                    avail = jnp.concatenate(
+                        [avail, jnp.zeros((Rp - Rn,), I32)]
+                    )
+                scalar_lane = jnp.arange(Rp) >= N_FIXED_LANES
+                conflict = (req > avail) & (~scalar_lane | (req > 0))
+                lane_bad = jnp.any(conflict) & ~jnp.all(req == 0)
+                pods_bad = state["num_pods"][at] + 1 > dc.allowed_pods[at]
+                fit_bad = spec_live & (lane_bad | pods_bad)
+            else:
+                fit_bad = jnp.asarray(False)
+            demoted = choice != spec
+            kind = jnp.where(
+                ~demoted,
+                DEMOTE_NONE,
                 jnp.where(
-                    pt_bad,
-                    DEMOTE_PORTS,
+                    ~spec_live,
+                    DEMOTE_UPGRADE,
                     jnp.where(
-                        sp_bad,
-                        DEMOTE_SPREAD,
+                        pt_bad,
+                        DEMOTE_PORTS,
                         jnp.where(
-                            ip_bad,
-                            DEMOTE_AFFINITY,
-                            jnp.where(fit_bad, DEMOTE_FIT, DEMOTE_SCORE),
+                            sp_bad,
+                            DEMOTE_SPREAD,
+                            jnp.where(
+                                ip_bad,
+                                DEMOTE_AFFINITY,
+                                jnp.where(fit_bad, DEMOTE_FIT, DEMOTE_SCORE),
+                            ),
                         ),
                     ),
                 ),
-            ),
-        ).astype(I32)
-        if C:
-            sp_viol = g.sp_hard[p] & ~c_ok[:, at]  # [C]
-            sp_term = jnp.argmax(sp_viol).astype(I32)
-            sp_term = jnp.where(jnp.any(sp_viol), sp_term, -1)
-        else:
-            sp_term = jnp.asarray(-1, I32)
-        if AT:
-            ip_viol = anti_viol[:, at]  # [AT]
-            ip_term = jnp.argmax(ip_viol).astype(I32)
-            ip_term = jnp.where(jnp.any(ip_viol), ip_term, -1)
-        else:
-            ip_term = jnp.asarray(-1, I32)
-        cterm = jnp.where(
-            kind == DEMOTE_SPREAD,
-            sp_term,
-            jnp.where(kind == DEMOTE_AFFINITY, ip_term, -1),
-        )
-        # p is the scan index over the batch axis — in range by
-        # construction; mode="drop" spells it for the slice-clamp rule
-        new_state["out_choice"] = (
-            state["out_choice"].at[p].set(choice, mode="drop")
-        )
-        new_state["out_nfeas"] = (
-            state["out_nfeas"].at[p].set(n_feas, mode="drop")
-        )
-        new_state["out_rc"] = (
-            state["out_rc"].at[p].set(reason_counts, mode="drop")
-        )
-        new_state["out_kind"] = state["out_kind"].at[p].set(kind, mode="drop")
-        new_state["out_cterm"] = (
-            state["out_cterm"].at[p].set(cterm, mode="drop")
-        )
-        return new_state, None
+            ).astype(I32)
+            if C:
+                sp_viol = g.sp_hard[p] & ~c_ok[:, at]  # [C]
+                sp_term = jnp.argmax(sp_viol).astype(I32)
+                sp_term = jnp.where(jnp.any(sp_viol), sp_term, -1)
+            else:
+                sp_term = jnp.asarray(-1, I32)
+            if AT:
+                ip_viol = anti_viol[:, at]  # [AT]
+                ip_term = jnp.argmax(ip_viol).astype(I32)
+                ip_term = jnp.where(jnp.any(ip_viol), ip_term, -1)
+            else:
+                ip_term = jnp.asarray(-1, I32)
+            cterm = jnp.where(
+                kind == DEMOTE_SPREAD,
+                sp_term,
+                jnp.where(kind == DEMOTE_AFFINITY, ip_term, -1),
+            )
+            # p is the scan index over the batch axis — in range by
+            # construction; mode="drop" spells it for the slice-clamp rule
+            new_state["out_choice"] = (
+                state["out_choice"].at[p].set(choice, mode="drop")
+            )
+            new_state["out_nfeas"] = (
+                state["out_nfeas"].at[p].set(n_feas, mode="drop")
+            )
+            new_state["out_rc"] = (
+                state["out_rc"].at[p].set(reason_counts, mode="drop")
+            )
+            new_state["out_kind"] = state["out_kind"].at[p].set(kind, mode="drop")
+            new_state["out_cterm"] = (
+                state["out_cterm"].at[p].set(cterm, mode="drop")
+            )
+            return new_state, None
 
-    state, _ = jax.lax.scan(step, init, jnp.arange(P, dtype=I32))
+    # the scan's own loop machinery belongs to the admission pass too
+    with jax.named_scope("ktpu/wave/admission"):
+        state, _ = jax.lax.scan(step, init, jnp.arange(P, dtype=I32))
     chosen = state["out_choice"]
     n_feas = state["out_nfeas"]
     reason_counts = state["out_rc"]

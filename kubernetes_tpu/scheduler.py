@@ -40,6 +40,7 @@ from kubernetes_tpu.framework.interface import (
 )
 from kubernetes_tpu.framework.registry import Registry, default_registry
 from kubernetes_tpu.framework.runtime import Framework
+from kubernetes_tpu.metrics import annotation
 from kubernetes_tpu.oracle.scores import HOSTNAME_LABEL
 from kubernetes_tpu.oracle.state import NodeState, OracleState
 from kubernetes_tpu.ops import gang
@@ -111,6 +112,8 @@ class _BindTask:
     binder_override: object
     outcome: "ScheduleOutcome"
     lean: bool = False
+    bid: int = 0  # the batch that produced it
+    t_submit: float = 0.0  # perf_counter when _flush_binds handed it over
 
     def lean_eligible(self) -> bool:
         return self.lean and not self.waited and self.binder_override is None
@@ -127,6 +130,8 @@ class _BulkBindTask:
     fwk: object
     state: object
     items: list  # [(qp, node_name, outcome)]
+    bid: int = 0  # the batch that produced it
+    t_submit: float = 0.0  # perf_counter when _flush_binds handed it over
 
 
 @dataclass
@@ -521,8 +526,15 @@ class Scheduler:
             s = self_ref()
             return s.slo if s is not None else None
 
+        def _bid_of():
+            s = self_ref()
+            return s._bid if s is not None else 0
+
         self.kernels = kernels_mod.DispatchLedger(
-            prom=self.prom, tracer=self.tracer, slo_getter=_slo_of
+            prom=self.prom,
+            tracer=self.tracer,
+            slo_getter=_slo_of,
+            bid_getter=_bid_of,
         )
         if getattr(self.config, "kernel_ledger", True):
             kernels_mod.install()
@@ -530,6 +542,10 @@ class Scheduler:
         else:
             self.kernels.enabled = False
         self._batch_seq = 0  # trace batch ids (scheduling-loop thread only)
+        # the batch id the loop thread's spans carry: the id the NEXT
+        # _trace_dispatch will stamp while a batch is prepared, the
+        # record's own while it is harvested (scheduling-loop thread only)
+        self._bid = 1
         # jax.profiler trace hook (SURVEY §5; the --profiling/pprof analog,
         # apis/config/types.go:60): when set, schedule_pending wraps each
         # drain in jax.profiler.trace(profile_dir).
@@ -941,14 +957,22 @@ class Scheduler:
         def flush(keep: int = 0) -> None:
             while len(pending) > keep:
                 rec = pending.popleft()
+                self._bid = rec.get("bid", self._bid)
                 if rec.get("kind") == "fast":
                     outcomes.extend(self._finish_fast(rec))
                 else:
                     outcomes.extend(self._finish_chained(rec))
+            self._bid = self._batch_seq + 1
 
         while True:
-            t_pop = time.perf_counter()
+            self._bid = self._batch_seq + 1
+            # the iteration's enclosing span: annotation only — booked as a
+            # phase it would cover every other phase of the loop
+            ann_batch = annotation("batch", bid=self._bid).begin()
+            sp_pop = self._span("queue_pop").begin()
+            sp_lock = self._span("queue_pop.lock_wait").begin()
             with self._mu:
+                sp_lock.end()
                 batch = self.queue.pop_batch(self.config.batch_size)
                 if batch and self.config.gang_dispatch:
                     # gang sibling-pull: a gang split across pop batches
@@ -956,8 +980,9 @@ class Scheduler:
                     # ready members into THIS batch so quorum is judged
                     # once (PR 10 remainder; cheap for gang-free batches)
                     batch.extend(self._pull_gang_siblings(batch))
-            self.phases.add("queue_pop", time.perf_counter() - t_pop)
+            sp_pop.end()
             if not batch:
+                ann_batch.end()
                 break
             # Segregate by profile (schedule_one.go:376-382): each group
             # runs ONE gang dispatch under its own framework's plugin set.
@@ -970,14 +995,19 @@ class Scheduler:
                 )
                 rec = None
                 if self._chain_quickcheck(fwk, group):
-                    rec = self._try_dispatch_chained(
-                        fwk, group, outcomes, can_restart=not pending
-                    )
+                    # the host's side of one chained dispatch (prep under
+                    # the lock, tables, the dispatch call): its own phase —
+                    # the chained path books no pack/h2d/device
+                    with self._span("chain_dispatch"):
+                        rec = self._try_dispatch_chained(
+                            fwk, group, outcomes, can_restart=not pending
+                        )
                     if rec == "flush":
                         flush(0)
-                        rec = self._try_dispatch_chained(
-                            fwk, group, outcomes, can_restart=True
-                        )
+                        with self._span("chain_dispatch"):
+                            rec = self._try_dispatch_chained(
+                                fwk, group, outcomes, can_restart=True
+                            )
                 if isinstance(rec, tuple) and rec and rec[0] == "serial":
                     # breaker fallback for an abandoned chained dispatch:
                     # settle the pipeline (its commits must land first),
@@ -1058,6 +1088,7 @@ class Scheduler:
             # hand this batch's buffered binds to the workers — they overlap
             # the next batch's device dispatch (the async binding pipeline)
             self._flush_binds()
+            ann_batch.end()
             batches += 1
             if max_batches is not None and batches >= max_batches:
                 break
@@ -1066,8 +1097,13 @@ class Scheduler:
         # be in flight (they overlapped the later dispatches); callers read
         # final outcomes, so settle them here.  Failed binds have been
         # requeued with backoff by now — they surface on a later drain,
-        # exactly like the reference's retry flow.
-        self.wait_for_bindings()
+        # exactly like the reference's retry flow.  The queue is empty
+        # here, so the wait is the loop thread's idle time: nothing to
+        # decide, only binds in flight.
+        self._flush_binds()
+        if self._inflight_binds:
+            with self.phases.span("loop.idle"):
+                self.wait_for_bindings()
         if self._sanitize:
             # KTPU_SANITIZE drift probe: every usage row the mirror claims
             # current must match a fresh recomputation from the cache
@@ -1098,6 +1134,10 @@ class Scheduler:
             fwk.has_reserve_or_permit()
             and not fwk.reserve_permit_covered_by_host_filters()
         )
+
+    def _span(self, phase: str):
+        """A loop-thread phase span carrying the current batch id."""
+        return self.phases.span(phase, bid=self._bid)
 
     def _trace_dispatch(self, kind: str, t0: float, batch, rec=None) -> int:
         """Stamp a monotonically-increasing batch id and — when tracing —
@@ -1662,11 +1702,12 @@ class Scheduler:
             # scan path: bring the full mirror (usage tensors included) up
             # to date — its kernels read requested/num_pods per node.
             t_pack = time.perf_counter()
-            self._repack_mirror()
-            self.prom.recorder.observe(
-                self.prom.snapshot_pack_duration, time.perf_counter() - t_pack
-            )
-            self.phases.add("pack", time.perf_counter() - t_pack)
+            with self._span("pack"):
+                self._repack_mirror()
+                self.prom.recorder.observe(
+                    self.prom.snapshot_pack_duration,
+                    time.perf_counter() - t_pack,
+                )
             trace.step("Snapshot mirror updated")
 
             self._p_cap_max = max(self._p_cap_max, self._p_bucket(len(pods)))
@@ -1679,6 +1720,7 @@ class Scheduler:
                 namespace_labels=self.namespace_labels,
             )
             t_sync = time.perf_counter()
+            sp_h2d = self._span("h2d").begin()
             from kubernetes_tpu.observability import kernels as kernels_mod
 
             try:
@@ -1694,7 +1736,7 @@ class Scheduler:
                 time.perf_counter() - t_sync,
                 phase="device_sync",
             )
-            self.phases.add("h2d", time.perf_counter() - t_sync)
+            sp_h2d.end()
             v_cap = bucket_cap(len(vocab.label_vals))
             hostname_key = self._hostname_dev(vocab)
             tables = self._gang_tables(pb, vocab)
@@ -1777,6 +1819,7 @@ class Scheduler:
             else None
         )
         t_gang = time.perf_counter()
+        sp_dev = self._span("device").begin()
         wstats_dev = None
         # kwargs shared VERBATIM by both dispatch kernels — one dict so a
         # future knob cannot reach one path and silently miss the other
@@ -1850,14 +1893,13 @@ class Scheduler:
                     attempt_base=attempt_base,
                     **shared_kw,
                 )
-            t_d2h = time.perf_counter()
-            self.phases.add("device", t_d2h - t_gang)
-            both = self._d2h_guarded(
-                jnp.stack([chosen, n_feas]),
-                kernel=kroot,
-                validate=_validate_direct,
-            )
-            self.phases.add("d2h", time.perf_counter() - t_d2h)
+            sp_dev.end()
+            with self._span("d2h"):
+                both = self._d2h_guarded(
+                    jnp.stack([chosen, n_feas]),
+                    kernel=kroot,
+                    validate=_validate_direct,
+                )
         except kernels_mod.DispatchFailed as e:
             # abandoned dispatch (or unrecoverable readback): nothing was
             # committed — the batch drains on the serial host-oracle path,
@@ -1927,7 +1969,7 @@ class Scheduler:
         interaction-group ids from the wave partitioner) routes successes
         through the bulk-commit path instead, one bulk run per group, so
         non-interacting groups' bindings flow concurrently."""
-        t_commit = time.perf_counter()
+        sp_commit = self._span("commit").begin()
         node_names = self.mirror.nodes.names
         n_nodes = len(self.cache.real_nodes())
         counts = None  # fetched lazily — only failures read it
@@ -2011,7 +2053,7 @@ class Scheduler:
                 n_feas=n_feas,
                 nonfast=True,
             )
-        self.phases.add("commit", time.perf_counter() - t_commit)
+        sp_commit.end()
 
     # ----- the chained (pipelined) dispatch path ---------------------------
     #
@@ -2605,7 +2647,7 @@ class Scheduler:
         outcomes: List[ScheduleOutcome] = []
         tr = self.tracer
         t_h = tr.now() if tr.enabled else None
-        t_d2h = time.perf_counter()
+        sp_d2h = self._span("d2h").begin()
         from kubernetes_tpu.observability import kernels as kernels_mod
 
         n_bound = len(self.mirror.nodes.names)
@@ -2637,7 +2679,7 @@ class Scheduler:
             )
             self._flush_binds()
             return outcomes
-        self.phases.add("d2h", time.perf_counter() - t_d2h)
+        sp_d2h.end()
         wstats = rec.get("wave_stats")
         self.prom.recorder.observe(
             self.prom.gang_dispatch_duration,
@@ -3144,9 +3186,8 @@ class Scheduler:
             weights = tuple(
                 fwk.score_weights.get(n, 0) for n in gang.WEIGHT_ORDER
             )
-            t_pack = time.perf_counter()
-            self._repack_mirror()
-            self.phases.add("pack", time.perf_counter() - t_pack)
+            with self._span("pack"):
+                self._repack_mirror()
             self._p_cap_max = max(self._p_cap_max, self._p_bucket(len(pods)))
             p_cap = self._p_cap_max
             pb = pack_pod_batch(
@@ -3156,7 +3197,7 @@ class Scheduler:
                 p_cap=p_cap,
                 namespace_labels=self.namespace_labels,
             )
-            t_sync = time.perf_counter()
+            sp_h2d = self._span("h2d").begin()
             from kubernetes_tpu.observability import kernels as kernels_mod
 
             try:
@@ -3172,7 +3213,7 @@ class Scheduler:
                     ordered, try_workloads=False
                 )
             db = self._place_db(DeviceBatch.from_host(pb))
-            self.phases.add("h2d", time.perf_counter() - t_sync)
+            sp_h2d.end()
             v_cap = bucket_cap(len(vocab.label_vals))
             hostname_key = self._hostname_dev(vocab)
             tables = self._gang_tables(pb, vocab)
@@ -3242,6 +3283,7 @@ class Scheduler:
         from kubernetes_tpu.observability import kernels as kernels_mod
 
         t_gang = time.perf_counter()
+        sp_dev = self._span("device").begin()
         try:
             chosen_dev, n_feas_dev, reason_counts, tallies, wl_dev = (
                 cos_ops.workloads_run(
@@ -3276,8 +3318,8 @@ class Scheduler:
                     **tables,
                 )
             )
-            t_d2h = time.perf_counter()
-            self.phases.add("device", t_d2h - t_gang)
+            sp_dev.end()
+            sp_d2h = self._span("d2h").begin()
             n_bound = len(self.mirror.nodes.names)
 
             def _validate_wl(fetched):
@@ -3311,7 +3353,7 @@ class Scheduler:
         chosen, n_feas, raw, spec, gang_admit, gang_landed, claim_node = (
             fetched
         )
-        self.phases.add("d2h", time.perf_counter() - t_d2h)
+        sp_d2h.end()
         self.prom.recorder.observe(
             self.prom.gang_dispatch_duration,
             time.perf_counter() - t_gang,
@@ -3390,14 +3432,16 @@ class Scheduler:
         host-replay commit."""
         import numpy as np
 
-        t_commit = time.perf_counter()
+        sp_commit = self._span("commit").begin()
         node_names = self.mirror.nodes.names
         n_nodes = len(self.cache.real_nodes())
         counts = None
         fr = self.flight
         chosen_n = np.asarray(chosen)[: len(ordered)]
         spec_n = np.asarray(spec)[: len(ordered)]
+        sp_lock = self._span("commit.lock_wait").begin()
         with self._mu:
+            sp_lock.end()
             self.metrics["schedule_attempts"] += len(ordered)
             # speculation stats: pods whose admitted placement survived
             # the serial admission pass unchanged (the wave's admitted-as-
@@ -3533,7 +3577,7 @@ class Scheduler:
                         },
                     )
             outcomes.append(outcome)
-        self.phases.add("commit", time.perf_counter() - t_commit)
+        sp_commit.end()
 
     def _wave_resolve(self, fwk, batch, chosen, wstats_dev, kernel=None):
         """Harvest one wave's speculation stats: admitted/demoted counters,
@@ -3546,7 +3590,7 @@ class Scheduler:
 
         from kubernetes_tpu.ops import wave as wave_ops
 
-        t0 = time.perf_counter()
+        sp_resolve = self._span("wave_resolve").begin()
         stats = np.asarray(self._d2h(wstats_dev, kernel=kernel))
         n = len(batch)
         spec, kinds, cterms = stats[0][:n], stats[1][:n], stats[2][:n]
@@ -3616,7 +3660,7 @@ class Scheduler:
                 self.metrics["wave_groups"] = (
                     self.metrics.get("wave_groups", 0) + n_groups
                 )
-        self.phases.add("wave_resolve", time.perf_counter() - t0)
+        sp_resolve.end()
         return groups
 
     def _static_device_cluster(self) -> DeviceCluster:
@@ -3879,9 +3923,8 @@ class Scheduler:
                 holder["heaps_dirty"] = False
             # the host greedy IS the selection step here — attribute it to
             # the device phase it replaces
-            t_dev = time.perf_counter()
-            choices = holder["fc"].run(pod_sigs)
-            self.phases.add("device", time.perf_counter() - t_dev)
+            with self._span("device"):
+                choices = holder["fc"].run(pod_sigs)
             holder["dev"] = None  # device copy (if any) is now stale
             with self._mu:  # metrics is a registered lock-guarded field
                 self.metrics["fast_batches"] += 1
@@ -3909,7 +3952,7 @@ class Scheduler:
         # signature ids with the node-usage state resident in HBM
         # (ops/fastpath.sig_scan) — one dispatch per batch, no [P, N]
         # tensors, bit-identical to the host FastCommitter
-        t_h2d = time.perf_counter()
+        sp_h2d = self._span("h2d").begin()
         if holder["stack"] is None:
             holder["stack"] = self._stack_signatures(holder)
         st = holder["stack"]
@@ -3965,8 +4008,8 @@ class Scheduler:
                     + int(npods_np.sum())
                 )
             used, nz0, nz1, num_pods = holder["dev"]
-            t_dev = time.perf_counter()
-            self.phases.add("h2d", t_dev - t_h2d)
+            sp_h2d.end()
+            sp_dev = self._span("device").begin()
             rstats_dev = None
             if res_on:
                 # resident drain loop (ops/resident.py): the whole run is
@@ -4039,7 +4082,7 @@ class Scheduler:
             if rstats_dev is not None:
                 rstats_dev.copy_to_host_async()
             holder["dev_inflight"] += 1
-            self.phases.add("device", time.perf_counter() - t_dev)
+            sp_dev.end()
         except Exception as e:
             # a dispatch died mid-round: the donated usage buffers are in
             # an unknown state — but the HOST committer is still the
@@ -4067,9 +4110,8 @@ class Scheduler:
             if holder["heaps_dirty"]:
                 holder["fc"].invalidate_heaps()
                 holder["heaps_dirty"] = False
-            t_dev = time.perf_counter()
-            choices = holder["fc"].run(pod_sigs)
-            self.phases.add("device", time.perf_counter() - t_dev)
+            with self._span("device"):
+                choices = holder["fc"].run(pod_sigs)
             with self._mu:  # metrics is a registered lock-guarded field
                 self.metrics["fast_batches"] += 1
             rec = {
@@ -4162,7 +4204,7 @@ class Scheduler:
                     return "choice index out of node range"
                 return None
 
-            t_d2h = time.perf_counter()
+            sp_d2h = self._span("d2h").begin()
             try:
                 fetched = self._d2h_guarded(
                     (rec["choices_dev"], rstats_dev, csum_dev),
@@ -4180,7 +4222,7 @@ class Scheduler:
                 )
                 csum = int(fetched[2]) if csum_dev is not None else None
                 choices = choices_np.tolist()
-            self.phases.add("d2h", time.perf_counter() - t_d2h)
+            sp_d2h.end()
         if torn is not None:
             # epoch-guarded resync: nothing from the dead round reaches
             # the cache or the committer — the host committer (still the
@@ -4192,13 +4234,18 @@ class Scheduler:
             if holder["heaps_dirty"]:
                 holder["fc"].invalidate_heaps()
                 holder["heaps_dirty"] = False
-            t_res = time.perf_counter()
-            choices = holder["fc"].run(pod_sigs)
-            self.phases.add("resident_rounds", time.perf_counter() - t_res)
+            with self._span("resident_rounds"):
+                choices = holder["fc"].run(pod_sigs)
             rec["rstats_dev"] = None  # the path label below reads it
         elif rec["choices_host"] is None:
             holder["dev_inflight"] -= 1
-            t_res = time.perf_counter()
+            # the host replay of a resident run's rounds; booked only for
+            # resident records (a sig_scan harvest's replay stays unbooked)
+            sp_res = (
+                self._span("resident_rounds").begin()
+                if rstats is not None
+                else None
+            )
             if rstats is not None:
                 rounds = int(rstats[0])
                 # resident_pods counts what the fixed point RESOLVED; the
@@ -4306,10 +4353,8 @@ class Scheduler:
                 holder["heaps_dirty"] = False
                 holder["dev"] = None
                 holder["dev_sum"] = None
-            if rstats is not None:
-                self.phases.add(
-                    "resident_rounds", time.perf_counter() - t_res
-                )
+            if sp_res is not None:
+                sp_res.end()
             shadow = holder.get("shadow")
             if shadow is not None:
                 host_choices = shadow.run(pod_sigs)
@@ -4352,7 +4397,7 @@ class Scheduler:
         n = len(batch)
         with self._mu:  # metrics is a registered lock-guarded field
             self.metrics["schedule_attempts"] += n
-        t_commit = time.perf_counter()
+        sp_commit = self._span("commit").begin()
         i = 0
         while i < n:
             if choices[i] >= 0:
@@ -4367,7 +4412,9 @@ class Scheduler:
                         fwk, state, batch, choices, i, j, node_names, outcomes
                     )
                 else:
+                    sp_lock = self._span("commit.lock_wait").begin()
                     with self._mu:
+                        sp_lock.end()
                         for k_ in range(i, j):
                             outcomes.append(
                                 self._commit_under_lock(
@@ -4398,7 +4445,7 @@ class Scheduler:
                     fwk, state, qp, status, 0, diag, set(diag)
                 )
             )
-        self.phases.add("commit", time.perf_counter() - t_commit)
+        sp_commit.end()
         if rec["record_metrics"]:
             self._record_batch_metrics(
                 fwk.profile_name,
@@ -4487,7 +4534,7 @@ class Scheduler:
             ):
                 return None
 
-        t_pack = time.perf_counter()
+        sp_pack = self._span("pack").begin()
         with self._mu:
             vocab = self.mirror.vocab
             for qp in batch:
@@ -4503,7 +4550,7 @@ class Scheduler:
         # while the seed group is the only thing popped — extension pods
         # would be lost to the direct-path fallback otherwise.
         rows = self._fast_sig_rows(fwk, batch, keys, enabled, weights)
-        self.phases.add("pack", time.perf_counter() - t_pack)
+        sp_pack.end()
         if rows is None:
             return None
 
@@ -4526,10 +4573,11 @@ class Scheduler:
             elig = self._fast_pod_predicate(
                 fwk, batch[0].pod.scheduler_name, known_rows=rows
             )
-            t_pop = time.perf_counter()
-            with self._mu:
-                extra = self.queue.pop_batch_while(ext, elig)
-            self.phases.add("queue_pop", time.perf_counter() - t_pop)
+            with self._span("queue_pop"):
+                sp_lock = self._span("queue_pop.lock_wait").begin()
+                with self._mu:
+                    sp_lock.end()
+                    extra = self.queue.pop_batch_while(ext, elig)
             if extra:
                 with self._mu:
                     for qp in extra:
@@ -4541,7 +4589,7 @@ class Scheduler:
 
         state = CycleState()
         pods_all = [qp.pod for qp in batch]
-        t_pack = time.perf_counter()
+        sp_pack = self._span("pack").begin()
         # ---- point of commitment: PreFilter mutates outcomes/queue state,
         # so every bail-out above happened first (the direct path must not
         # replay it, and extension pods are already part of this batch);
@@ -4562,10 +4610,10 @@ class Scheduler:
                     )
                 batch = live
                 if not batch:
-                    self.phases.add("pack", time.perf_counter() - t_pack)
+                    sp_pack.end()
                     return "handled"
                 keys = self._batch_signature_keys(batch)
-        self.phases.add("pack", time.perf_counter() - t_pack)
+        sp_pack.end()
         # fast commits happen outside the chain's device state — drop it
         # (it restarts from the repacked mirror once the pipeline settles)
         self._chain = None
@@ -5591,7 +5639,8 @@ class Scheduler:
             first_enqueue_mono=qp.mono_timestamp or None,
         )
         task = _BindTask(
-            fwk, state, qp, node_name, waited, binder_override, outcome, lean
+            fwk, state, qp, node_name, waited, binder_override, outcome, lean,
+            bid=self._bid,
         )
         if waited:
             # A Wait-ed pod's cycle can block on permit for its timeout —
@@ -5675,7 +5724,9 @@ class Scheduler:
         # as immutable everywhere (failure paths REPLACE outcome.status)
         success = STATUS_SUCCESS
         items = []
+        sp_lock = self._span("commit.lock_wait").begin()
         with self._mu:
+            sp_lock.end()
             if self._sanitize:
                 sanitizer.assert_owned(self._mu, "_commit_fast_bulk")
             if nonfast:
@@ -5720,7 +5771,9 @@ class Scheduler:
         if fr_events:
             fr.record_many(fr_events)
         if items:
-            self._bulk_bind_buffer.append(_BulkBindTask(fwk, state, items))
+            self._bulk_bind_buffer.append(
+                _BulkBindTask(fwk, state, items, bid=self._bid)
+            )
 
     def _ensure_bind_pool(self) -> None:
         if self._bind_pool is None:
@@ -5737,6 +5790,12 @@ class Scheduler:
         workers — one future per ~64 pods is only the ceiling.  Bulk tasks
         (fast-path runs) split into per-worker slices the same way, but
         keep their one-sink-write/one-lock-tail discipline per slice."""
+        if not self._bulk_bind_buffer and not self._bind_buffer:
+            return
+        with self._span("flush_binds"):
+            self._submit_binds(chunk)
+
+    def _submit_binds(self, chunk: int) -> None:
         bulk = self._bulk_bind_buffer
         if bulk:
             self._bulk_bind_buffer = []
@@ -5756,7 +5815,10 @@ class Scheduler:
                     # across the pool so latencies overlap
                     per = min(64, max(1, -(-n // workers)))
                 for lo in range(0, n, per):
-                    part = _BulkBindTask(t.fwk, t.state, t.items[lo : lo + per])
+                    part = _BulkBindTask(
+                        t.fwk, t.state, t.items[lo : lo + per],
+                        bid=t.bid, t_submit=time.perf_counter(),
+                    )
                     self._inflight_binds.append(
                         self._bind_pool.submit(self._binding_bulk, part)
                     )
@@ -5768,6 +5830,7 @@ class Scheduler:
         self._ensure_bind_pool()
         for i in range(0, len(buf), chunk):
             part = buf[i : i + chunk]
+            part[0].t_submit = time.perf_counter()
             self._inflight_binds.append(
                 self._bind_pool.submit(self._binding_chunk, part)
             )
@@ -5784,7 +5847,10 @@ class Scheduler:
         unwind per pod through the standard _bind_fail path."""
         from kubernetes_tpu import events as ev
 
-        t0 = time.perf_counter()
+        phases = self.phases
+        sp_bind = phases.span("bind", bid=t.bid, pods=len(t.items)).begin()
+        if t.t_submit:
+            phases.add("bind.queue_wait", time.perf_counter() - t.t_submit)
         fwk, state, items = t.fwk, t.state, t.items
         fr = self.flight
         if fr.enabled:
@@ -5797,7 +5863,8 @@ class Scheduler:
         sink_many = self.binding_sink_many
         if sink_many is not None and len(items) > 1:
             try:
-                errs = sink_many([(qp.pod, nn) for qp, nn, _ in items])
+                with phases.span("bind.sink", bid=t.bid):
+                    errs = sink_many([(qp.pod, nn) for qp, nn, _ in items])
             except Exception as e:  # noqa: BLE001 — whole-slice failure
                 errs = [str(e)] * len(items)
             if not isinstance(errs, (list, tuple)) or len(errs) != len(items):
@@ -5814,18 +5881,25 @@ class Scheduler:
                     self._bind_fail(fwk, state, qp, nn, outcome, Status.error(err))
         else:
             sink = self.binding_sink
-            for qp, nn, outcome in items:
-                try:
-                    sink(qp.pod, nn)
-                except Exception as e:  # noqa: BLE001 — surfaced as Status
-                    self._bind_fail(
-                        fwk, state, qp, nn, outcome,
-                        Status.error(f"binding cycle panicked: {e}"),
-                    )
-                    continue
-                ok_items.append((qp, nn, outcome))
+            # one span for the slice's sink calls (never one per pod); a
+            # failed pod's unwind inside it is the rare path
+            with phases.span("bind.sink", bid=t.bid):
+                for qp, nn, outcome in items:
+                    try:
+                        sink(qp.pod, nn)
+                    except Exception as e:  # noqa: BLE001 — surfaced as Status
+                        self._bind_fail(
+                            fwk, state, qp, nn, outcome,
+                            Status.error(f"binding cycle panicked: {e}"),
+                        )
+                        continue
+                    ok_items.append((qp, nn, outcome))
+        sp_tail = None
         if ok_items:
+            sp_lock = phases.span("bind.lock_wait", bid=t.bid).begin()
             with self._mu:
+                sp_lock.end()
+                sp_tail = phases.span("bind.tail", bid=t.bid).begin()
                 queue_done = self.queue.done
                 finish = self.cache.finish_binding
                 nom = self.nominator if len(self.nominator) else None
@@ -5856,11 +5930,12 @@ class Scheduler:
                         "Binding",
                         f"Successfully assigned {pod.key} to {nn}",
                     )
-        dt = time.perf_counter() - t0
+        if sp_tail is not None:
+            sp_tail.end()
+        dt = sp_bind.end()
         if items:
             # amortized binding latency: the slice shares one wall clock
             self.prom.binding_duration.observe_n(dt / len(items), len(items))
-        self.phases.add("bind", dt)
 
     def _binding_chunk(self, part: List["_BindTask"]) -> None:
         """One worker's buffered binding cycles.  Lean cycles (fast batches
@@ -5871,7 +5946,13 @@ class Scheduler:
         changing what any concurrent reader can observe mid-chunk."""
         from kubernetes_tpu import events as ev
 
-        t_bind = time.perf_counter()
+        phases = self.phases
+        bid = part[0].bid
+        sp_bind = phases.span("bind", bid=bid, pods=len(part)).begin()
+        if part[0].t_submit:
+            phases.add(
+                "bind.queue_wait", time.perf_counter() - part[0].t_submit
+            )
         lean_ok = []
         lean_tasks = [t for t in part if t.lean_eligible()]
         fr = self.flight
@@ -5887,7 +5968,10 @@ class Scheduler:
             # chunk's bindings ride one write; per-item errors unwind
             # exactly the pods that failed
             try:
-                errs = sink_many([(t.qp.pod, t.node_name) for t in lean_tasks])
+                with phases.span("bind.sink", bid=bid):
+                    errs = sink_many(
+                        [(t.qp.pod, t.node_name) for t in lean_tasks]
+                    )
             except Exception as e:  # noqa: BLE001 — whole-batch failure
                 errs = [str(e)] * len(lean_tasks)
             if not isinstance(errs, (list, tuple)) or len(errs) != len(
@@ -5909,24 +5993,36 @@ class Scheduler:
             lean_handled = set(map(id, lean_tasks))
         else:
             lean_handled = set()
+        direct = [
+            t for t in part if id(t) not in lean_handled and t.lean_eligible()
+        ]
+        if direct:
+            # one span for the chunk's direct sink calls (never one per
+            # pod); a failed pod's unwind inside it is the rare path
+            with phases.span("bind.sink", bid=bid):
+                for t in direct:
+                    try:
+                        s = t.fwk.run_bind_direct(
+                            t.state, t.qp.pod, t.node_name
+                        )
+                    except Exception as e:  # noqa: BLE001 — surfaced as Status
+                        s = Status.error(f"binding cycle panicked: {e}")
+                    if s.ok:
+                        lean_ok.append(t)
+                    else:
+                        self._bind_fail(
+                            t.fwk, t.state, t.qp, t.node_name, t.outcome, s
+                        )
         for t in part:
-            if id(t) in lean_handled:
-                continue
-            if t.lean_eligible():
-                try:
-                    s = t.fwk.run_bind_direct(t.state, t.qp.pod, t.node_name)
-                except Exception as e:  # noqa: BLE001 — surfaced as Status
-                    s = Status.error(f"binding cycle panicked: {e}")
-                if s.ok:
-                    lean_ok.append(t)
-                else:
-                    self._bind_fail(t.fwk, t.state, t.qp, t.node_name, t.outcome, s)
-            else:
+            if not t.lean_eligible():
                 self._binding_cycle(t)
         if not lean_ok:
-            self.phases.add("bind", time.perf_counter() - t_bind)
+            sp_bind.end()
             return
+        sp_lock = phases.span("bind.lock_wait", bid=bid).begin()
         with self._mu:
+            sp_lock.end()
+            sp_tail = phases.span("bind.tail", bid=bid).begin()
             for t in lean_ok:
                 pod = t.qp.pod
                 self.queue.done(pod.uid)
@@ -5951,7 +6047,8 @@ class Scheduler:
                     "Binding",
                     f"Successfully assigned {pod.key} to {t.node_name}",
                 )
-        self.phases.add("bind", time.perf_counter() - t_bind)
+        sp_tail.end()
+        sp_bind.end()
 
     def _bind_fail(self, fwk, state, qp, node_name, outcome, s) -> None:
         """Bind-failure unwind: Unreserve + ForgetPod + requeue under the

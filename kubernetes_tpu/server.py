@@ -622,7 +622,9 @@ class SchedulerServer:
                     self.sched.metrics["errors"] += 1
                 except Exception:  # noqa: BLE001
                     pass
-            self._stop.wait(self.poll_interval_s)
+            # the sleep on an empty queue, named on the loop's own thread
+            with self.sched.phases.span("loop.idle"):
+                self._stop.wait(self.poll_interval_s)
 
     def stop(self) -> None:
         self._stop.set()

@@ -15,7 +15,7 @@ Covers the acceptance surface:
   * the regression sentinel: a synthetically slowed kernel breaches
     after the sustained threshold and the SLO tier's black-box
     freeze→dump fires with the kernel NAMED in the breach record;
-  * device-track spans ride the PR-4 tracer export;
+  * dispatch_submit-track spans ride the PR-4 tracer export;
   * /debug/kernels + the /debug/ JSON index round-trip over the real
     HTTP server, and the plain-text help block is generated from the
     same table (no drift possible);
@@ -456,11 +456,12 @@ def test_sentinel_baseline_ignores_outliers_and_compiles():
 
 
 # ---------------------------------------------------------------------------
-# tracer device track
+# tracer dispatch_submit track (the host's clock around the dispatch call:
+# submit time on an asynchronous backend, never device time)
 # ---------------------------------------------------------------------------
 
 
-def test_device_track_spans_ride_the_tracer():
+def test_dispatch_submit_track_spans_ride_the_tracer():
     sched = Scheduler()
     led = sched.kernels
     sched.tracer.start()
@@ -471,8 +472,14 @@ def test_device_track_spans_ride_the_tracer():
     spans = [
         e for e in trace["traceEvents"] if e.get("name") == "fake.traced"
     ]
-    assert spans and spans[0]["ph"] == "X" and spans[0]["cat"] == "device"
+    assert spans and spans[0]["ph"] == "X"
+    assert spans[0]["cat"] == "dispatch_submit"
     track_meta = [
+        e
+        for e in trace["traceEvents"]
+        if e.get("ph") == "M" and e["args"].get("name") == "dispatch_submit"
+    ]
+    assert not [
         e
         for e in trace["traceEvents"]
         if e.get("ph") == "M" and e["args"].get("name") == "device"

@@ -1,0 +1,270 @@
+"""The one span primitive (``PhaseAccumulator.span``), the sub-phases it
+splits the served path into, the ``ktpu.*`` events a profiler session
+holds, and the stage names the scheduling roots put on their device ops.
+
+Everything runs on the CPU backend: a profiler session there has the host
+plane (where the program's spans land) and no device plane.
+"""
+
+import glob
+import os
+import re
+import time
+
+import pytest
+
+from kubernetes_tpu.metrics import Histogram, PhaseAccumulator
+from kubernetes_tpu.observability.tracer import Tracer
+
+
+class _TailTap:
+    """Stands where ``PhaseAccumulator.tracer`` expects a Tracer."""
+
+    def __init__(self, enabled=True):
+        self.enabled = enabled
+        self.calls = []
+
+    def complete_tail(self, name, dur_s, *a, **kw):
+        self.calls.append((name, dur_s))
+
+
+def _hist():
+    return Histogram("t_phase_seconds", "test", ("phase",))
+
+
+# ---------------------------------------------------------------------------
+# the primitive
+# ---------------------------------------------------------------------------
+
+
+def test_span_books_what_add_booked():
+    """Same total, same histogram observation, same complete_tail call as
+    ``add`` with the span's own duration."""
+    a, b = PhaseAccumulator(hist=_hist()), PhaseAccumulator(hist=_hist())
+    a.tracer, b.tracer = _TailTap(), _TailTap()
+    with a.span("commit", bid=7):
+        time.sleep(0.002)
+    (name, dt), = a.tracer.calls
+    assert name == "commit" and dt >= 0.002
+    b.add("commit", dt)
+    assert a.snapshot() == b.snapshot() == {"commit": dt}
+    assert a.tracer.calls == b.tracer.calls
+    assert a.hist.count(phase="commit") == b.hist.count(phase="commit") == 1
+
+
+def test_begin_end_form_is_the_same_span():
+    acc = PhaseAccumulator()
+    sp = acc.span("device", bid=1).begin()
+    time.sleep(0.001)
+    dt = sp.end()
+    assert acc.snapshot() == {"device": dt} and dt >= 0.001
+
+
+def test_spans_nest():
+    acc = PhaseAccumulator()
+    acc.tracer = _TailTap()
+    with acc.span("bind", bid=3, pods=4):
+        with acc.span("bind.sink", bid=3):
+            time.sleep(0.001)
+        with acc.span("bind.tail", bid=3):
+            pass
+    snap = acc.snapshot()
+    assert set(snap) == {"bind", "bind.sink", "bind.tail"}
+    assert snap["bind.sink"] + snap["bind.tail"] <= snap["bind"]
+    # inner spans close (and reach the tracer) before the outer one
+    assert [n for n, _ in acc.tracer.calls] == ["bind.sink", "bind.tail", "bind"]
+
+
+def test_span_books_its_interval_when_the_block_raises():
+    acc = PhaseAccumulator()
+    with pytest.raises(KeyError):
+        with acc.span("commit"):
+            time.sleep(0.001)
+            raise KeyError("boom")
+    assert acc.snapshot()["commit"] >= 0.001
+
+
+def test_disabled_tracer_and_no_session_are_not_touched():
+    class _Forbidden(_TailTap):
+        def complete_tail(self, *a, **kw):  # pragma: no cover — must not run
+            raise AssertionError("a disabled tracer was handed a span")
+
+    acc = PhaseAccumulator()
+    acc.tracer = _Forbidden(enabled=False)
+    with acc.span("pack", bid=2):
+        pass
+    real = Tracer()  # never started: disabled
+    acc.tracer = real
+    with acc.span("pack", bid=2):
+        pass
+    assert real.stats()["events"] == 0
+    assert set(acc.snapshot()) == {"pack"}
+    # no profiler session is live: the annotation recorded nowhere
+    from jax.profiler import TraceAnnotation
+
+    assert not TraceAnnotation.is_enabled()
+
+
+# ---------------------------------------------------------------------------
+# a small served drain under a CPU profiler session
+# ---------------------------------------------------------------------------
+
+SUB_PHASES = (
+    "bind.queue_wait", "bind.sink", "bind.lock_wait", "bind.tail",
+    "queue_pop.lock_wait", "commit.lock_wait", "flush_binds", "loop.idle",
+)
+
+
+@pytest.fixture(scope="module")
+def served_drain(tmp_path_factory):
+    """API server over HTTP, reflectors, scheduling loop, binding workers;
+    1,200 one-shape pods on 32 nodes, drained under ``jax.profiler``.
+    Returns (phase totals, {event name: [stats dict, ...]} of the host
+    planes)."""
+    import jax
+
+    from kubernetes_tpu.api.types import Container, Node, Pod
+    from kubernetes_tpu.api.resource import Resource
+    from kubernetes_tpu.client import ApiClient, ApiServer, RemoteClusterSource
+    from kubernetes_tpu.scheduler import Scheduler
+    from kubernetes_tpu.server import SchedulerServer
+    from kubernetes_tpu.testing.fake_cluster import FakeCluster
+
+    api = FakeCluster(pv_controller=False)
+    apiserver = ApiServer(api).start()
+    endpoint = f"http://127.0.0.1:{apiserver.port}"
+    sched = Scheduler()
+    source = RemoteClusterSource(endpoint)
+    source.connect(sched)
+    source.start()
+    assert source.wait_for_sync(timeout=60.0)
+    driver = ApiClient(endpoint)
+    for i in range(32):
+        driver.create_node(Node(
+            name=f"n{i}",
+            allocatable=Resource.from_map({"cpu": "64", "memory": "256Gi", "pods": "110"}),
+        ))
+    n_pods = 1200
+    for i in range(n_pods):
+        driver.create_pod(Pod(
+            name=f"p{i}", uid=f"default/p{i}",
+            containers=[Container(name="c", requests={"cpu": "100m", "memory": "64Mi"})],
+        ))
+    deadline = time.monotonic() + 60.0
+    while len(sched.queue) < n_pods and time.monotonic() < deadline:
+        time.sleep(0.01)
+    assert len(sched.queue) == n_pods
+    trace_dir = str(tmp_path_factory.mktemp("xplane"))
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(trace_dir, profiler_options=opts)
+    server = SchedulerServer(sched, poll_interval_s=0.005)
+    try:
+        server.start()
+        while time.monotonic() < deadline:
+            with sched._mu:
+                if sched.metrics["scheduled"] >= n_pods:
+                    break
+            time.sleep(0.01)
+        time.sleep(0.05)  # a poll or two on the empty queue
+    finally:
+        server.stop()
+        sched.wait_for_bindings()
+        jax.profiler.stop_trace()
+        source.stop()
+        apiserver.stop()
+    assert sched.metrics["scheduled"] == n_pods
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb"))
+    events = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("ktpu."):
+                    events.setdefault(e.name, []).append(dict(e.stats))
+    return sched.phases.snapshot(), events
+
+
+def test_every_sub_phase_appears_and_the_parts_of_bind_fit_in_bind(served_drain):
+    phases, _ = served_drain
+    for name in SUB_PHASES:
+        assert name in phases, (name, sorted(phases))
+    parts = phases["bind.sink"] + phases["bind.lock_wait"] + phases["bind.tail"]
+    assert 0 < parts <= phases["bind"]
+    assert phases["queue_pop.lock_wait"] <= phases["queue_pop"]
+    assert phases["commit.lock_wait"] <= phases["commit"]
+
+
+def test_profiler_session_holds_the_programs_spans(served_drain):
+    _, events = served_drain
+    for name in ("ktpu.batch", "ktpu.commit", "ktpu.bind",
+                 "ktpu.apiserver.POST.bindings", "ktpu.apiserver.lock_wait",
+                 "ktpu.bind.sink", "ktpu.loop.idle", "ktpu.flush_binds"):
+        assert name in events, (name, sorted(events))
+    # a bind slice names the batch that produced it, and its pod count
+    bids = {st["bid"] for st in events["ktpu.commit"]}
+    for st in events["ktpu.bind"]:
+        assert st["bid"] in bids and st["pods"] >= 1
+    assert sum(st["pods"] for st in events["ktpu.bind"]) == 1200
+    assert all("bid" in st for st in events["ktpu.batch"])
+    # the ledger's dispatch call is a named span too
+    assert any(n.startswith("ktpu.dispatch.") for n in events)
+
+
+# ---------------------------------------------------------------------------
+# stage names on the device ops (jax.named_scope: metadata only)
+# ---------------------------------------------------------------------------
+
+_POD_STEP = ("ktpu/gang/filter", "ktpu/gang/score", "ktpu/gang/select", "ktpu/gang/commit")
+_CONSTRAINTS = ("ktpu/gang/spread_constraints", "ktpu/gang/interpod_constraints")
+_WAVE = ("ktpu/gang/precompute", "ktpu/wave/speculation", "ktpu/wave/admission") + _POD_STEP + _CONSTRAINTS
+ROOT_STAGES = {
+    "chain.chain_dispatch": _WAVE + ("ktpu/chain/append",),
+    "gang.gang_run": ("ktpu/gang/precompute", "ktpu/gang/heavy_parts") + _POD_STEP + _CONSTRAINTS,
+    "wave.wave_run": _WAVE,
+    "resident.resident_run": ("ktpu/resident/round",),
+}
+
+
+@pytest.fixture(scope="module")
+def dispatched_specs():
+    """{root: (args, kwargs) of ShapeDtypeStructs + statics} from tiny CPU
+    drains, through the ledger's retained buckets."""
+    from kubernetes_tpu.tools import paritycheck as pc
+
+    specs = {}
+
+    def drain(nodes, pods, **cfg):
+        _, s = pc._drain(nodes, pods, return_sched=True, mesh_dispatch=False, **cfg)
+        for name, ks in s.kernels._kstats.items():
+            for b in ks.buckets.values():
+                if b["spec"] is not None:
+                    specs.setdefault(name, b["spec"])
+
+    drain(pc._basic_nodes(64), pc._basic_pods(2048))  # resident_run
+    drain(pc._basic_nodes(16, zones=4), pc._cross_pod_pods(16))  # wave_run
+    drain(pc._basic_nodes(16, zones=4), pc._cross_pod_pods(16), wave_dispatch=False)  # gang_run
+    drain(pc._basic_nodes(32, zones=4), pc._cross_pod_pods(96), batch_size=16)  # chain_dispatch
+    return specs
+
+
+@pytest.mark.parametrize("root", sorted(ROOT_STAGES))
+def test_lowered_root_carries_its_stage_names(root, dispatched_specs):
+    from kubernetes_tpu.observability import kernels
+    from kubernetes_tpu.ops.common import STAGES
+
+    assert root in dispatched_specs, sorted(dispatched_specs)
+    args, kwargs = dispatched_specs[root]
+    text = kernels._wrapped_fn(root).lower(*args, **kwargs).as_text(debug_info=True)
+    found = set(re.findall(r"ktpu/[a-z_]+/[a-z_]+", text))
+    assert set(ROOT_STAGES[root]) <= found, sorted(set(ROOT_STAGES[root]) - found)
+    assert found <= set(STAGES), sorted(found - set(STAGES))
+
+
+def test_the_four_roots_cover_every_listed_stage_but_the_fastpath_ones():
+    from kubernetes_tpu.ops.common import STAGES
+
+    covered = {s for names in ROOT_STAGES.values() for s in names}
+    assert set(STAGES) - covered == {"ktpu/fastpath/sig_step", "ktpu/fastpath/static_eval"}
