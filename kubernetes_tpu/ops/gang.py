@@ -19,7 +19,11 @@ dense equality-contraction over small axes ([C,N,J]-shaped compare+reduce
 against the assigned-node domain values), so the per-step cost is a handful
 of VPU/MXU passes over row slices instead of serialized scatter ops.  The
 only per-step dynamic indexing is row slices of the per-pod statics and
-[C,J]-sized gathers of the assigned nodes' domain values.
+[C,J]-sized gathers of the assigned nodes' domain values.  Scan-step
+arithmetic keeps the NODE axis minor and resource lanes as separate [N]
+vectors: an int64 [N, 2] operand fills the register tiles of [N, 128], and
+the fit score's emulated divisions cost 227 us a step on a v5e stacked as
+[N, 2] and about 11 us as two [N] lanes, N = 5,120 (PERF.md, PR 28).
 
 The scan step mirrors, piece by piece, what the serial oracle recomputes
 between pods, so gang results are identical to scheduling the pods one by
@@ -595,6 +599,42 @@ def _broken_linear_dev(points: tuple, x):
 DEFAULT_FIT_STRATEGY = (0, (), (1, 1))
 
 
+def fit_score(fit_strategy: tuple, a0, a1, c0, c1):
+    """NodeResourcesFit score (resource_allocation.go:37-115) from the cpu
+    and memory lanes as SEPARATE same-shaped i64 arrays: ``a0/a1``
+    allocatable, ``c0/c1`` non-zero-defaulted requests (node + pod).
+    Never stacked into a trailing axis of 2 (module docstring): the two
+    lanes meet only in the final two-term sums."""
+    strat_id, fit_shape, (w0, w1) = fit_strategy
+
+    def lane(a, c):
+        has = a > 0
+        if strat_id == 1:  # MostAllocated (most_allocated.go)
+            f = jnp.where(c > a, 0, c * MAX // jnp.maximum(a, 1))
+        elif strat_id == 2:  # RequestedToCapacityRatio
+            util = jnp.where(
+                ~has | (c > a), MAX, c * MAX // jnp.maximum(a, 1)
+            )
+            f = _broken_linear_dev(fit_shape, util)
+            # RTCR only counts resources whose score is positive
+            # (requested_to_capacity_ratio.go:46-52)
+            has = has & (f > 0)
+        else:  # LeastAllocated (least_allocated.go:29-60)
+            f = jnp.where(c > a, 0, (a - c) * MAX // jnp.maximum(a, 1))
+        return f, has
+
+    f0, u0 = lane(a0, c0)
+    f1, u1 = lane(a1, c1)
+    zero = jnp.zeros_like(f0)
+    wsum = jnp.where(u0, w0, zero) + jnp.where(u1, w1, zero)
+    total = jnp.where(u0, f0 * w0, zero) + jnp.where(u1, f1 * w1, zero)
+    if strat_id == 2:  # math.Round of the weighted mean
+        q = (2 * total + wsum) // jnp.maximum(2 * wsum, 1)
+    else:
+        q = total // jnp.maximum(wsum, 1)
+    return jnp.where(wsum > 0, q, 0)
+
+
 # ---------------------------------------------------------------------------
 # Shared count→constraint algebra (one definition for every dispatch path)
 #
@@ -809,50 +849,20 @@ def pod_step(
         # NodeResourcesFit scoring strategy on non-zero-defaulted requests
         # (resource_allocation.go:37-115): LeastAllocated (default),
         # MostAllocated, or RequestedToCapacityRatio over cpu/memory.
-        strat_id, fit_shape, fit_w = fit_strategy
-        nz = (
-            state["nonzero"].astype(I64)
-            + db.nonzero_req[p][None, :].astype(I64)
-        )  # [N, 2]
-        alloc2 = jnp.stack(
-            [dc.allocatable[:, LANE_CPU], dc.allocatable[:, LANE_MEM]], axis=1
-        ).astype(I64)
-        lane_has = alloc2 > 0
-        if strat_id == 1:  # MostAllocated (most_allocated.go)
-            frac = jnp.where(
-                nz > alloc2, 0, nz * MAX // jnp.maximum(alloc2, 1)
-            )
-        elif strat_id == 2:  # RequestedToCapacityRatio
-            util = jnp.where(
-                ~lane_has | (nz > alloc2),
-                MAX,
-                nz * MAX // jnp.maximum(alloc2, 1),
-            )
-            frac = _broken_linear_dev(fit_shape, util)
-        else:  # LeastAllocated (least_allocated.go:29-60)
-            frac = jnp.where(
-                nz > alloc2, 0, (alloc2 - nz) * MAX // jnp.maximum(alloc2, 1)
-            )
-        w2 = jnp.asarray(fit_w, I64)[None, :]
-        # RTCR only counts resources whose score is positive
-        # (requested_to_capacity_ratio.go:46-52)
-        use = lane_has & (frac > 0) if strat_id == 2 else lane_has
-        wsum = jnp.sum(jnp.where(use, w2, 0), axis=1)
-        total_fit = jnp.sum(jnp.where(use, frac * w2, 0), axis=1)
-        if strat_id == 2:  # math.Round of the weighted mean
-            least = jnp.where(
-                wsum > 0,
-                (2 * total_fit + wsum) // jnp.maximum(2 * wsum, 1),
-                0,
-            )
-        else:
-            least = jnp.where(
-                wsum > 0, total_fit // jnp.maximum(wsum, 1), 0
-            )
-
-        # BalancedAllocation on real requests
+        # Per resource lane on [N] vectors (module docstring); a0/a1 also
+        # feed BalancedAllocation below.
         a0 = dc.allocatable[:, LANE_CPU].astype(I64)
         a1 = dc.allocatable[:, LANE_MEM].astype(I64)
+        nz_req = db.nonzero_req[p].astype(I64)
+        least = fit_score(
+            fit_strategy,
+            a0,
+            a1,
+            state["nonzero"][:, 0].astype(I64) + nz_req[0],
+            state["nonzero"][:, 1].astype(I64) + nz_req[1],
+        )
+
+        # BalancedAllocation on real requests
         r0 = jnp.minimum(
             state["requested"][:, LANE_CPU].astype(I64)
             + db.requests[p, LANE_CPU].astype(I64),

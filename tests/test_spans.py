@@ -236,17 +236,22 @@ def dispatched_specs():
 
     specs = {}
 
-    def drain(nodes, pods, **cfg):
-        _, s = pc._drain(nodes, pods, return_sched=True, mesh_dispatch=False, **cfg)
+    def harvest(s):
         for name, ks in s.kernels._kstats.items():
             for b in ks.buckets.values():
                 if b["spec"] is not None:
                     specs.setdefault(name, b["spec"])
 
+    def drain(nodes, pods, **cfg):
+        _, s = pc._drain(nodes, pods, return_sched=True, mesh_dispatch=False, **cfg)
+        harvest(s)
+
     drain(pc._basic_nodes(64), pc._basic_pods(2048))  # resident_run
     drain(pc._basic_nodes(16, zones=4), pc._cross_pod_pods(16))  # wave_run
     drain(pc._basic_nodes(16, zones=4), pc._cross_pod_pods(16), wave_dispatch=False)  # gang_run
     drain(pc._basic_nodes(32, zones=4), pc._cross_pod_pods(96), batch_size=16)  # chain_dispatch
+    _, s = pc._drain_workloads(*pc._gang_workload(8, 4))  # workloads_run
+    harvest(s)
     return specs
 
 
@@ -261,6 +266,31 @@ def test_lowered_root_carries_its_stage_names(root, dispatched_specs):
     found = set(re.findall(r"ktpu/[a-z_]+/[a-z_]+", text))
     assert set(ROOT_STAGES[root]) <= found, sorted(set(ROOT_STAGES[root]) - found)
     assert found <= set(STAGES), sorted(found - set(STAGES))
+
+
+# every root whose program holds gang.pod_step (scan, speculation, admission)
+POD_STEP_ROOTS = (
+    "chain.chain_dispatch",
+    "coscheduling.workloads_run",
+    "gang.gang_run",
+    "wave.wave_run",
+)
+
+
+@pytest.mark.parametrize("root", POD_STEP_ROOTS)
+def test_pod_step_root_divides_no_int64_with_a_minor_axis_of_two(root, dispatched_specs):
+    """The scan step keeps the node axis minor (ops/gang.py docstring): an
+    emulated int64 division over [..., N, 2] pays for the tiles of
+    [..., N, 128] on the chip (PERF.md, PR 28)."""
+    from kubernetes_tpu.observability import kernels
+
+    assert root in dispatched_specs, sorted(dispatched_specs)
+    args, kwargs = dispatched_specs[root]
+    text = kernels._wrapped_fn(root).lower(*args, **kwargs).as_text()
+    divides = re.findall(r"stablehlo\.divide[^\n]*", text)
+    assert any(d.rstrip().endswith("xi64>") for d in divides), "no int64 division found at all"
+    two_lane = [d for d in divides if re.search(r"x2xi64>\s*$", d)]
+    assert not two_lane, two_lane
 
 
 def test_the_four_roots_cover_every_listed_stage_but_the_fastpath_ones():
