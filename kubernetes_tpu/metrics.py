@@ -417,6 +417,13 @@ class PhaseAccumulator:
         if tr is not None and tr.enabled:
             tr.complete_tail(phase, dt)
 
+    def count(self, name: str, n: float) -> None:
+        """Book a count (pods, rows) beside the phases: a total that
+        ``snapshot`` and ``diff`` carry like a phase's seconds, with no
+        histogram observation and no span (it is not an interval)."""
+        with self._mu:
+            self._totals[name] = self._totals.get(name, 0.0) + n
+
     def span(self, phase: str, **ctx) -> "PhaseSpan":
         """The interval ``phase``, as a context manager, or opened with
         ``.begin()`` and closed with ``.end()`` where the interval does not

@@ -1930,7 +1930,7 @@ class Scheduler:
         wave_groups = None
         if wstats_dev is not None:
             wave_groups = self._wave_resolve(
-                fwk, batch, chosen, wstats_dev, kernel=kroot
+                fwk, batch, chosen, wstats_dev, self.mirror.e_used, kernel=kroot
             )
         self._process_results(
             fwk,
@@ -2636,6 +2636,7 @@ class Scheduler:
                 "results": results,
                 "reasons": reasons,
                 "wave_stats": wstats,
+                "e_rows": ch["e"],
                 "t0": t0,
             }
             self._trace_dispatch("wave" if wt is not None else "chain", t0, batch, rec)
@@ -2693,6 +2694,7 @@ class Scheduler:
                 rec["batch"],
                 both[0],
                 wstats,
+                rec["e_rows"],
                 kernel="chain.chain_dispatch",
             )
         self._process_results(
@@ -3579,8 +3581,10 @@ class Scheduler:
             outcomes.append(outcome)
         sp_commit.end()
 
-    def _wave_resolve(self, fwk, batch, chosen, wstats_dev, kernel=None):
-        """Harvest one wave's speculation stats: admitted/demoted counters,
+    def _wave_resolve(self, fwk, batch, chosen, wstats_dev, e_rows, kernel=None):
+        """Harvest one wave's speculation stats: admitted/demoted counters
+        (``wave.demoted`` and, with ``e_rows`` — the existing-pod rows live
+        at the dispatch — ``wave.epod_rows`` go to the phase accumulator),
         a ``wave_demoted`` flight-recorder event (with the conflicting
         term) per corrected pod, and — when the framework permits lean
         binds — the interaction-group split the bulk commit path uses.
@@ -3630,6 +3634,8 @@ class Scheduler:
             self.metrics["wave_pods"] += n
             self.metrics["wave_admitted"] += admitted
         self.prom.wave_admitted.inc(admitted)
+        self.phases.count("wave.demoted", len(demoted))
+        self.phases.count("wave.epod_rows", e_rows)
         for kind, cnt in conflicts.items():
             self.prom.wave_conflicts.inc(cnt, kind=kind)
         # Bulk-commit eligibility: lean_bind_ok()'s and the Reserve/Permit

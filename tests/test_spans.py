@@ -52,6 +52,18 @@ def test_span_books_what_add_booked():
     assert a.hist.count(phase="commit") == b.hist.count(phase="commit") == 1
 
 
+def test_count_books_a_total_and_nothing_else():
+    """A count (pods, rows) rides in the snapshot beside the phases' seconds
+    and is no interval: no histogram observation, no span."""
+    acc = PhaseAccumulator(hist=_hist())
+    acc.tracer = _TailTap()
+    acc.count("wave.demoted", 3)
+    acc.count("wave.demoted", 4)
+    assert acc.snapshot() == {"wave.demoted": 7}
+    assert acc.tracer.calls == [] and acc.hist.count(phase="wave.demoted") == 0
+    assert PhaseAccumulator.diff(acc.snapshot(), {"wave.demoted": 3}) == {"wave.demoted": 4}
+
+
 def test_begin_end_form_is_the_same_span():
     acc = PhaseAccumulator()
     sp = acc.span("device", bid=1).begin()
