@@ -9,6 +9,7 @@ instead of per-object interpreter loops.
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, fields
 from typing import Any, Optional
 
@@ -377,21 +378,41 @@ def eval_table(table: DTable, label_vals, val_ints):
 
     Requirement semantics mirror labels.Requirement.Matches (selector.go):
     NotIn also matches absent keys; Gt/Lt need integer-parsing both sides.
-    The static R/V loops keep peak memory at one ``lead+(N,)`` buffer per op.
+
+    The integer of a label (``val_ints[id]``, an element gather: 8.6 ns an
+    element on a v5e) belongs to the (label row, key) pair, not to the
+    (term, row) pair.  So it is parsed once a call over the ``[K, N]`` label
+    columns, and a requirement selects its parsed column by key as it selects
+    its value column: K·N gathered elements, not ``prod(lead)``·R·N.  The two
+    orders read ``val_ints`` at the same clipped id or yield INT_INVALID
+    (``present`` ⇔ key known ∧ ``cols[key] >= 0``), and the parse is done on
+    the side with fewer elements, read off the static shapes: after the
+    select only where ``prod(lead) * R <= K`` (one pod's few terms against a
+    wide label vocabulary).
+
+    The static R/V loops keep the working set at ``lead+(N,)`` buffers (a
+    slot's values and their integers, a bool per op), never
+    ``lead+(R, V, N)``; the parsed columns add K·N·4 bytes.
     """
     R = table.req_key.shape[-1]
     V = table.req_vals.shape[-1]
     N, K = label_vals.shape
     cols = label_vals.T  # [K, N]
 
+    def parse(ids):
+        # label-value ids → their integers; absent (negative) → INT_INVALID
+        safe = jnp.clip(ids, 0, val_ints.shape[0] - 1)
+        return jnp.where(ids >= 0, val_ints[safe], INT_INVALID)
+
+    parse_cols = math.prod(table.req_key.shape[:-1]) * R > K
+    int_cols = parse(cols) if parse_cols else None  # [K, N]
+
     ok = None
     for r in range(R):
         key = table.req_key[..., r]  # lead
         op = table.req_op[..., r]
         rhs = table.req_rhs[..., r]
-        key_known = (key >= 0) & (key < K)
-        safe_key = jnp.clip(key, 0, K - 1)
-        val = jnp.where(key_known[..., None], cols[safe_key], ABSENT)  # lead+(N,)
+        val = gather_at(cols, key)  # lead+(N,)
         present = val >= 0
 
         in_any = jnp.zeros_like(present)
@@ -399,11 +420,7 @@ def eval_table(table: DTable, label_vals, val_ints):
             rv = table.req_vals[..., r, v]
             in_any = in_any | (present & (val == rv[..., None]) & (rv >= 0)[..., None])
 
-        iv = jnp.where(
-            present,
-            val_ints[jnp.clip(val, 0, val_ints.shape[0] - 1)],
-            INT_INVALID,
-        )
+        iv = gather_at(int_cols, key, INT_INVALID) if parse_cols else parse(val)
         int_ok = (iv != INT_INVALID) & (rhs != INT_INVALID)[..., None]
 
         opb = op[..., None]
@@ -519,13 +536,13 @@ def gather_rows(matrix, idx):
     return jnp.where((idx >= 0)[..., None], out, ABSENT)
 
 
-def gather_at(cols_t, key):
-    """cols_t: [K, N]; key: lead → lead+(N,) of label values (ABSENT when the
-    key id is out of range/padding)."""
+def gather_at(cols_t, key, fill=ABSENT):
+    """cols_t: [K, N]; key: lead → lead+(N,) of label values (``fill`` when
+    the key id is out of range/padding)."""
     K = cols_t.shape[0]
     known = (key >= 0) & (key < K)
     safe = jnp.clip(key, 0, K - 1)
-    return jnp.where(known[..., None], cols_t[safe], ABSENT)
+    return jnp.where(known[..., None], cols_t[safe], fill)
 
 
 # ---------------------------------------------------------------------------
