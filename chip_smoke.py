@@ -3,12 +3,18 @@
 correctly, on the chip?
 
 Drives the main path once through the entry points a user calls
-(``Scheduler`` + informer handlers + ``schedule_pending``; the HTTP serving
-loop ``ApiServer`` ← ``RemoteClusterSource`` ← ``SchedulerServer``), at the
-upstream scheduler_perf widths (BASELINE.md), and checks the decisions
-against the plain reference (the serial oracle, via ``tools/paritycheck``).
-ONE process, the only one that touches JAX; the served phase runs its API
-server, reflector, scheduler loop and binding workers as threads of it.
+(``Scheduler`` + informer handlers + ``schedule_pending``), at the upstream
+scheduler_perf widths (BASELINE.md), and checks the decisions against the
+plain reference (the serial oracle, via ``tools/paritycheck``).  ONE
+process, the only one that touches JAX.  Phases: device, drain,
+constraints, identity; with ``--chips 4``: device, mesh.
+
+The served path (``ApiServer`` ← ``RemoteClusterSource`` ←
+``SchedulerServer``, binds read back through LIST) is proven elsewhere: on
+the chip by ``python3 benchmarks/run.py`` (every cell of BENCHMARK.json), on
+the CPU by tests/test_bench_interpod_cell.py and
+tests/test_bench_template_format.py, which drive ``benchmarks/harness.py``
+at tiny sizes.
 
     python chip_smoke.py                 # one chip, full widths
     python chip_smoke.py --chips 4       # four chips: the mesh drain and its
@@ -37,13 +43,11 @@ import argparse
 import json
 import logging
 import sys
-import threading
 import time
-from concurrent.futures import ThreadPoolExecutor
 from typing import Dict, List, Optional
 
 # scheduler_perf widths (BASELINE.md → performance-config.yaml line refs)
-FULL_NODES = 5000  # SchedulingBasic :51, TopologySpreading :512, kubemark
+FULL_NODES = 5000  # SchedulingBasic :51, TopologySpreading :512
 FULL_PODS = 10000  # SchedulingBasic backlog; the other phases derive theirs
 # Backlog cuts the cold run's 1200 s force at the full node width (node and
 # pod SHAPES are never cut).  Every (root, statics, bucket) variant of the
@@ -83,9 +87,8 @@ def say(msg: str) -> None:
     print(f"[smoke] {msg}", flush=True)
 
 
-# ---- workloads (fixed seeds; shapes are the repo's scheduler_perf cells:
-# bench.py's SchedulingBasic pods on paritycheck's basic nodes, bench.py's
-# TopologySpreading pods) -----------------------------------------------------
+# ---- workloads (fixed seeds; SchedulingBasic-shaped pods on paritycheck's
+# basic nodes, TopologySpreading-shaped pods) ---------------------------------
 
 
 def basic_pods(n: int, prefix: str, seed: int = 0) -> list:
@@ -428,164 +431,6 @@ class Smoke:
                 self.fail("constraints", "no device dispatch of a cross-pod root")
             self.hbm("constraints")
 
-    def served(self, timeout_s: float = 300.0) -> None:
-        """The HTTP path at kubemark width (tools/kubemark.run_scale_sim's
-        shape): hollow nodes register over HTTP, pods arrive over the
-        binary codec, the SchedulerServer loop schedules, binding workers
-        POST the bindings; every acknowledged bind is read back from the
-        API server's store and must equal the scheduler's decision.  The
-        scheduling loop starts once the informer has delivered the whole
-        backlog (a scheduler starting against a pending queue), so the
-        batch the device sees does not depend on arrival timing."""
-        from kubernetes_tpu.api.codec import decode
-        from kubernetes_tpu.api.resource import Resource
-        from kubernetes_tpu.api.types import Node
-        from kubernetes_tpu.client import (
-            ApiClient,
-            ApiServer,
-            RemoteClusterSource,
-        )
-        from kubernetes_tpu.events import EventBroadcaster
-        from kubernetes_tpu.kubemark import HollowFleet
-        from kubernetes_tpu.scheduler import Scheduler
-        from kubernetes_tpu.server import SchedulerServer
-        from kubernetes_tpu.testing.fake_cluster import FakeCluster
-
-        n_nodes, n_pods = self.nodes, self.pods // 2
-        with self.phase("served"):
-            api = FakeCluster(pv_controller=False)
-            apiserver = ApiServer(api).start()
-            endpoint = f"http://127.0.0.1:{apiserver.port}"
-            sched = Scheduler(event_broadcaster=EventBroadcaster())
-            sched.event_broadcaster.start_recording_to_sink(api.record_event)
-            sched.mirror.e_cap_hint = n_pods + sched.config.batch_size + 128
-            source = RemoteClusterSource(endpoint)  # binary codec default
-            source.connect(sched)
-            # the scheduler's decisions, recorded where it hands them to the
-            # wire: uid → node for every bind the API server ACKNOWLEDGED
-            decided: Dict[str, str] = {}
-            mu = threading.Lock()
-            bind_one, bind_many = sched.binding_sink, sched.binding_sink_many
-
-            def sink(pod, node):
-                bind_one(pod, node)
-                with mu:
-                    decided[pod.uid] = node
-
-            def sink_many(items):
-                items = list(items)
-                errs = bind_many(items)
-                with mu:
-                    for (pod, node), err in zip(items, errs):
-                        if err is None:
-                            decided[pod.uid] = node
-                return errs
-
-            sched.binding_sink, sched.binding_sink_many = sink, sink_many
-            source.start()
-            server = SchedulerServer(sched, poll_interval_s=0.005)
-            sched.install_controlplane(api_server=apiserver, source=source)
-            fleet = None
-            try:
-                t0 = time.perf_counter()
-                client = ApiClient(endpoint)  # binary; thread-local conns
-
-                def register(i: int) -> None:
-                    client.create_node(
-                        Node(
-                            name=f"hollow-{i}",
-                            labels={
-                                "topology.kubernetes.io/zone": f"zone-{i % 3}",
-                                "kubernetes.io/hostname": f"hollow-{i}",
-                            },
-                            capacity=Resource.from_map(
-                                {"cpu": "8", "memory": "32Gi", "pods": 110}
-                            ),
-                        )
-                    )
-
-                with ThreadPoolExecutor(16) as ex:
-                    list(ex.map(register, range(n_nodes)))
-                if not source.wait_for_sync(timeout=60.0):
-                    self.fail("served", "informers never synced")
-                t_reg = time.perf_counter()
-                fleet = HollowFleet(endpoint, heartbeat_interval_s=15.0)
-                fleet.adopt([Node(name=f"hollow-{i}") for i in range(n_nodes)])
-                fleet.start()
-
-                # the drain's pod shapes (same signature bucket → the
-                # resident kernel compiled there is reused here)
-                with ThreadPoolExecutor(16) as ex:
-                    list(
-                        ex.map(
-                            client.create_pod,
-                            basic_pods(n_pods, "load", self.seed),
-                        )
-                    )
-                deadline = time.monotonic() + timeout_s
-                while time.monotonic() < deadline and len(sched.queue) < n_pods:
-                    time.sleep(0.02)
-                t_q = time.perf_counter()
-                server.start()
-                while (
-                    time.monotonic() < deadline
-                    and len(api.bindings) < n_pods
-                ):
-                    time.sleep(0.02)
-                t_end = time.perf_counter()
-                # read every bind back THROUGH the served path
-                store = {}
-                for env in ApiClient(endpoint).list("pods")["items"]:
-                    pod = decode(env)
-                    store[pod.uid] = pod.node_name
-                with mu:
-                    acked = dict(decided)
-                wrong = [
-                    (uid, node, store.get(uid))
-                    for uid, node in acked.items()
-                    if store.get(uid) != node
-                ]
-                with apiserver._wire_mu:
-                    wire = dict(apiserver.wire_bytes)
-                say(
-                    f"served: {n_nodes} hollow nodes registered in "
-                    f"{t_reg - t0:.2f}s; {n_pods} pods created and "
-                    f"delivered in {t_q - t_reg:.2f}s; {len(acked)} of "
-                    f"{n_pods} bound+acknowledged {t_end - t_q:.2f}s after "
-                    f"the loop started, {len(wrong)} read-back mismatches, "
-                    f"{server.cycles} loop cycles [smoke timings]; "
-                    f"wire bytes { {'/'.join(k): v for k, v in wire.items()} }"
-                )
-                if len(acked) != n_pods or len(store) != n_pods:
-                    self.fail(
-                        "served",
-                        f"{len(acked)} acknowledged binds, {len(store)} "
-                        f"pods in the store, want {n_pods}",
-                    )
-                if wrong:
-                    self.fail(
-                        "served",
-                        f"{len(wrong)} acknowledged binds read back "
-                        f"differently, first {wrong[:3]}",
-                    )
-                if not wire.get(("binary", "tx"), 0) > 0:
-                    self.fail("served", "no binary bytes left the API server")
-            finally:
-                if fleet is not None:
-                    fleet.stop()
-                if server._loop_thread is not None:  # started
-                    server.stop()
-                else:
-                    server.http.server_close()
-                source.stop()
-                apiserver.stop()
-            self.loud(
-                "served",
-                sched,
-                want_kernels=("resident.resident_run",),
-                want_metrics=("resident_batches",),
-            )
-
     def identity(self) -> None:
         """Decisions against the plain reference at the drain's node width
         (backlogs cut to what the per-pod Python oracle can replay inside
@@ -679,7 +524,6 @@ class Smoke:
         else:
             self.drain()
             self.constraints()
-            self.served()
             self.identity()
         say(
             f"total: smoke timing wall "

@@ -175,21 +175,15 @@ RETRACE_MODULES = JIT_MODULES + (
     "fastpath.py",
     os.path.join("observability", "explain.py"),
 )
-# the repo-root bench driver fetches through the Scheduler's public API —
-# checked when running from a source tree
-_BENCH = os.path.join(_REPO_ROOT, "bench.py")
 DONATION_CONTRACT_DOC = os.path.join(_REPO_ROOT, "RESIDENT.md")
 
 
 def default_targets() -> Dict[str, List[str]]:
-    d2h = [os.path.join(_PKG_ROOT, p) for p in D2H_MODULES]
-    if os.path.exists(_BENCH):
-        d2h.append(_BENCH)
     return {
         "locks": [os.path.join(_PKG_ROOT, p) for p in LOCK_MODULES],
         "purity": [os.path.join(_PKG_ROOT, p) for p in PURITY_MODULES],
         "jit": [os.path.join(_PKG_ROOT, p) for p in JIT_MODULES],
-        "d2h": d2h,
+        "d2h": [os.path.join(_PKG_ROOT, p) for p in D2H_MODULES],
         "donation": [os.path.join(_PKG_ROOT, p) for p in DONATION_MODULES],
         "clamp": [os.path.join(_PKG_ROOT, p) for p in CLAMP_MODULES],
         "retrace": [os.path.join(_PKG_ROOT, p) for p in RETRACE_MODULES],

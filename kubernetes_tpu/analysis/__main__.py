@@ -12,7 +12,7 @@ fixture-driven mode the tier-1 test uses (a fixture file declares its own
 only the relevant checker fires on it).
 
 ``--json`` prints a machine-readable report (findings + per-rule counts
-and wall times) for the bench tooling instead of the line-per-finding
+and wall times) for tooling instead of the line-per-finding
 text form.
 
 Baselines let a BRANCH gate on *new* findings while main stays strict on

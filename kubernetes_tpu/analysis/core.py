@@ -132,10 +132,10 @@ class SourceModule:
 
 # Process-level parse cache: every rule family reads the same shipped-tree
 # files, and the tier-1 gate runs the whole suite dozens of times per
-# session (tree gate + every fixture case + the CLI tests + bench
-# preflight).  One parse per (path, content digest) serves all of them;
-# a touched file (fixtures written to tmp dirs, editor saves between
-# runs) misses on content and reparses.  Suppression hit-tracking is the
+# session (tree gate + every fixture case + the CLI tests).  One parse
+# per (path, content digest) serves all of them; a touched file
+# (fixtures written to tmp dirs, editor saves between runs) misses on
+# content and reparses.  Suppression hit-tracking is the
 # only mutable state on a SourceModule and is monotonic, so sharing
 # instances across rule families and runs is safe.
 _SOURCE_CACHE: Dict[str, tuple] = {}

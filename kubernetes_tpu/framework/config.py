@@ -179,15 +179,6 @@ class SchedulerConfiguration:
     # and the host committer finishes them (right when host heaps beat
     # serial device steps — CPU backends).
     resident_serial_tail: bool = False
-    # TPU extension: epoch-guarded crash consistency for the resident/
-    # carry HBM state (ISSUE 15) — every device-path fast batch rides a
-    # tiny usage_checksum dispatch, validated against the host-tracked
-    # exact sum BEFORE the round's commits touch the committer; a
-    # mismatch (dispatch died mid-round, clobbered donation) resyncs the
-    # lineage from the host committer instead of committing torn usage
-    # rows.  Off = no checksum dispatch (the epoch counter alone still
-    # guards cross-dispatch staleness).
-    resident_epoch_guard: bool = True
     # TPU extension: the workloads tier (ops/coscheduling.py) — gang/
     # coscheduling all-or-nothing admission + batched DRA claim allocation
     # + volume-topology kernel masks ride one fused dispatch with
@@ -264,6 +255,11 @@ class SchedulerConfiguration:
             raise ValueError("batchSize must be positive")
         if self.mesh_pods_axis is not None and self.mesh_pods_axis <= 0:
             raise ValueError("meshPodsAxis must be positive")
+        for key in (
+            "fastBatchMax", "fastDeviceMin", "residentRunMax", "residentWindow"
+        ):
+            if getattr(self, _SCALAR_KEYS[key]) <= 0:
+                raise ValueError(f"{key} must be positive")
         for p in self.profiles:
             if not p.scheduler_name:
                 raise ValueError("profile schedulerName must be non-empty")
@@ -457,6 +453,31 @@ def _plugins_from(d: Optional[dict]) -> Plugins:
     return p
 
 
+# v1 wire key → SchedulerConfiguration field, for every scalar option: the
+# loader and the dumper both walk it, so a key is spelled once
+_SCALAR_KEYS: Dict[str, str] = {
+    "parallelism": "parallelism",
+    "percentageOfNodesToScore": "percentage_of_nodes_to_score",
+    "podInitialBackoffSeconds": "pod_initial_backoff_seconds",
+    "podMaxBackoffSeconds": "pod_max_backoff_seconds",
+    "batchSize": "batch_size",
+    "fastBatchMax": "fast_batch_max",
+    "fastDeviceMin": "fast_device_min",
+    "waveDispatch": "wave_dispatch",
+    "residentDrain": "resident_drain",
+    "residentRunMax": "resident_run_max",
+    "residentWindow": "resident_window",
+    "residentSerialTail": "resident_serial_tail",
+    "gangDispatch": "gang_dispatch",
+    "plannerKernel": "planner_kernel",
+    "kernelLedger": "kernel_ledger",
+    "meshDispatch": "mesh_dispatch",
+    "meshPodsAxis": "mesh_pods_axis",
+    "referenceSamplingCompat": "reference_sampling_compat",
+    "tieBreakSeed": "tie_break_seed",
+}
+
+
 def load_config(source) -> SchedulerConfiguration:
     """Load from a YAML string / path / dict."""
     from kubernetes_tpu.util.yamlsource import load_yaml_source
@@ -507,29 +528,12 @@ def load_config(source) -> SchedulerConfiguration:
         )
         for e in d.get("extenders", [])
     ]
+    # a key the document leaves out keeps the dataclass's default: the
+    # field is the one place a default lives
     cfg = SchedulerConfiguration(
-        parallelism=d.get("parallelism", 16),
         profiles=profiles or [Profile()],
         extenders=extenders,
-        percentage_of_nodes_to_score=d.get("percentageOfNodesToScore", 0),
-        pod_initial_backoff_seconds=d.get("podInitialBackoffSeconds", 1.0),
-        pod_max_backoff_seconds=d.get("podMaxBackoffSeconds", 10.0),
-        batch_size=d.get("batchSize", 512),
-        fast_batch_max=d.get("fastBatchMax", 4096),
-        fast_device_min=d.get("fastDeviceMin", 1024),
-        wave_dispatch=d.get("waveDispatch", True),
-        resident_drain=d.get("residentDrain", True),
-        resident_run_max=d.get("residentRunMax", 16384),
-        resident_window=d.get("residentWindow", 2048),
-        resident_serial_tail=d.get("residentSerialTail", False),
-        resident_epoch_guard=d.get("residentEpochGuard", True),
-        gang_dispatch=d.get("gangDispatch", True),
-        planner_kernel=d.get("plannerKernel", True),
-        kernel_ledger=d.get("kernelLedger", True),
-        mesh_dispatch=d.get("meshDispatch"),
-        mesh_pods_axis=d.get("meshPodsAxis"),
-        reference_sampling_compat=d.get("referenceSamplingCompat", False),
-        tie_break_seed=d.get("tieBreakSeed"),
+        **{f: d[key] for key, f in _SCALAR_KEYS.items() if key in d},
     )
     if "featureGates" in d:
         cfg.feature_gates = dict(DEFAULT_FEATURE_GATES)
@@ -574,26 +578,7 @@ def dump_config(cfg: SchedulerConfiguration) -> dict:
     out = {
         "apiVersion": API_VERSION,
         "kind": "KubeSchedulerConfiguration",
-        "parallelism": cfg.parallelism,
-        "percentageOfNodesToScore": cfg.percentage_of_nodes_to_score,
-        "podInitialBackoffSeconds": cfg.pod_initial_backoff_seconds,
-        "podMaxBackoffSeconds": cfg.pod_max_backoff_seconds,
-        "batchSize": cfg.batch_size,
-        "fastBatchMax": cfg.fast_batch_max,
-        "fastDeviceMin": cfg.fast_device_min,
-        "waveDispatch": cfg.wave_dispatch,
-        "residentDrain": cfg.resident_drain,
-        "residentRunMax": cfg.resident_run_max,
-        "residentWindow": cfg.resident_window,
-        "residentSerialTail": cfg.resident_serial_tail,
-        "residentEpochGuard": cfg.resident_epoch_guard,
-        "gangDispatch": cfg.gang_dispatch,
-        "plannerKernel": cfg.planner_kernel,
-        "kernelLedger": cfg.kernel_ledger,
-        "meshDispatch": cfg.mesh_dispatch,
-        "meshPodsAxis": cfg.mesh_pods_axis,
-        "referenceSamplingCompat": cfg.reference_sampling_compat,
-        "tieBreakSeed": cfg.tie_break_seed,
+        **{key: getattr(cfg, f) for key, f in _SCALAR_KEYS.items()},
         "featureGates": dict(cfg.feature_gates),
         "profiles": profiles,
     }

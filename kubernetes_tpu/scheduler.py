@@ -490,8 +490,8 @@ class Scheduler:
             # scheduler_tpu_shape_check_failures_total{fn=}
             sanitizer.register_shape_counter(self.prom.shape_check_failures)
         # Per-phase hot-loop attribution (queue_pop/pack/h2d/device/d2h/
-        # commit/bind) — the scheduler_perf-style breakdown bench.py emits
-        # as config0_phases.  Feeds the phase_duration histogram too.
+        # commit/bind), the scheduler_perf-style breakdown.  Feeds the
+        # phase_duration histogram too.
         self.phases = PhaseAccumulator(hist=self.prom.phase_duration)
         # Observability layer (observability/): span tracer (off until
         # /debug/trace?action=start — a disabled tracer is one attribute
@@ -536,7 +536,7 @@ class Scheduler:
             slo_getter=_slo_of,
             bid_getter=_bid_of,
         )
-        if getattr(self.config, "kernel_ledger", True):
+        if self.config.kernel_ledger:
             kernels_mod.install()
             kernels_mod.activate(self.kernels)
         else:
@@ -872,10 +872,10 @@ class Scheduler:
 
     # Incremental view maintenance: pod-level cache mutations patch the
     # cached OracleState in place instead of discarding it — a full rebuild
-    # is O(all pods) and preemption storms mutate once per eviction
-    # (the r3 bench spent ~6s/500 preempts rebuilding).  Node-level events
-    # still invalidate.  Any surprise (unknown node, uid miss) falls back
-    # to invalidation, so correctness never depends on these paths.
+    # is O(all pods) and preemption storms mutate once per eviction.
+    # Node-level events still invalidate.  Any surprise (unknown node, uid
+    # miss) falls back to invalidation, so correctness never depends on
+    # these paths.
 
     def _view_pod_added(self, pod: Pod) -> None:
         st = self._oracle_cache
@@ -1061,9 +1061,7 @@ class Scheduler:
                     pending.append(frec)
                     if frec.get(
                         "rstats_dev"
-                    ) is not None and not getattr(
-                        self.config, "resident_serial_tail", False
-                    ):
+                    ) is not None and not self.config.resident_serial_tail:
                         # a resident run may finish its conflict tail on
                         # the HOST committer, after which the chained
                         # device state is stale — harvest immediately so
@@ -3729,8 +3727,8 @@ class Scheduler:
         """Per-signature static rows (masks + raw scores) for this batch,
         cached across batches keyed on the static snapshot: steady-state
         batches reuse them and make ZERO static_eval device calls
-        (signatures recur — bench workloads have ~10).  Returns the row
-        cache, or None when any signature's static score raws vary over its
+        (signatures recur — a Deployment's replicas share one).  Returns the
+        row cache, or None when any signature's static score raws vary over its
         feasible set (normalization would be batch-state-dependent — the
         greedy's argmax-neutrality argument breaks, so the batch must take
         the gang scan)."""
@@ -3841,6 +3839,7 @@ class Scheduler:
         from kubernetes_tpu import fastpath as fp
 
         from kubernetes_tpu.ops import fastpath as ops_fp
+        from kubernetes_tpu.ops import resident as ops_res
 
         cache = self._sig_cache
         check_fit = "NodeResourcesFit" in enabled
@@ -3905,7 +3904,7 @@ class Scheduler:
         # degrades to sig_scan, sig_scan degrades to the host committer
         # (every rung bit-identical, tests/test_fastpath.py /
         # tests/test_resident.py)
-        res_on = getattr(self.config, "resident_drain", False)
+        res_on = self.config.resident_drain
         if res_on and self._breaker_blocked("resident.resident_run"):
             res_on = False
         device_ok = res_on or not self._breaker_blocked("fastpath.sig_scan")
@@ -3921,7 +3920,7 @@ class Scheduler:
         # at dispatch, so they may stay pending)
         if holder["dev_inflight"] == 0 and (
             not device_ok
-            or len(batch) < getattr(self.config, "fast_device_min", 1024)
+            or len(batch) < self.config.fast_device_min
         ):
             if holder["heaps_dirty"]:
                 # device-batch replays changed scores under the lazy heaps
@@ -3967,8 +3966,8 @@ class Scheduler:
         # and extended batches all share the fast_batch_max shape (pad
         # steps are masked inner iterations, ~0.2µs each)
         need = len(batch)
-        levels = [64, 512, getattr(self.config, "fast_batch_max", 4096)]
-        if getattr(self.config, "resident_drain", False):
+        levels = [64, 512, self.config.fast_batch_max]
+        if self.config.resident_drain:
             levels.append(self.config.resident_run_max)
         for level in levels:
             if need <= level:
@@ -4022,8 +4021,6 @@ class Scheduler:
                 # placed on device through the speculation/admission fixed
                 # point — same donated usage state as sig_scan, one d2h
                 # readback of packed placements per run
-                from kubernetes_tpu.ops import resident as ops_res
-
                 choices_dev, holder["dev"], rstats_dev = ops_res.resident_run(
                     jnp.asarray(ids),
                     st["req"],
@@ -4049,9 +4046,7 @@ class Scheduler:
                         self.config.resident_window,
                         int(holder["alloc"].shape[0]),
                     ),
-                    serial_tail=getattr(
-                        self.config, "resident_serial_tail", False
-                    ),
+                    serial_tail=self.config.resident_serial_tail,
                 )
             else:
                 choices_dev, holder["dev"] = ops_fp.sig_scan(
@@ -4075,12 +4070,8 @@ class Scheduler:
             # epoch guard: the device-side checksum of the NEW state rides
             # the same async pipeline; the harvest validates it against
             # the host-tracked sum BEFORE committing the round
-            csum_dev = None
-            if getattr(self.config, "resident_epoch_guard", True):
-                from kubernetes_tpu.ops import resident as ops_res
-
-                csum_dev = ops_res.usage_checksum(*holder["dev"])
-                csum_dev.copy_to_host_async()
+            csum_dev = ops_res.usage_checksum(*holder["dev"])
+            csum_dev.copy_to_host_async()
             # start the device→host result copy NOW; by harvest time the
             # data is local and the blocking fetch is cheap (the same
             # latency-hiding discipline as the chained gang pipeline)
@@ -4196,7 +4187,7 @@ class Scheduler:
             torn = "epoch_stale"
         if choices is None and torn is None:
             rstats_dev = rec.get("rstats_dev")
-            csum_dev = rec.get("csum_dev")
+            csum_dev = rec["csum_dev"]
             kern = (
                 "resident.resident_run"
                 if rstats_dev is not None
@@ -4226,7 +4217,7 @@ class Scheduler:
                 rstats = (
                     np.asarray(fetched[1]) if rstats_dev is not None else None
                 )
-                csum = int(fetched[2]) if csum_dev is not None else None
+                csum = int(fetched[2])
                 choices = choices_np.tolist()
             sp_d2h.end()
         if torn is not None:
@@ -4293,7 +4284,7 @@ class Scheduler:
             # is read at HARVEST time (holder["dev_sum"]): harvests are
             # FIFO, so with two batches in flight the earlier harvest has
             # already folded its delta in by the time the later validates.
-            if csum is not None and holder.get("dev_sum") is not None:
+            if holder.get("dev_sum") is not None:
                 delta = 0
                 if agg is not None:
                     delta = int(
@@ -4571,8 +4562,8 @@ class Scheduler:
         # amortizes over far more pods (RESIDENT.md)
         cap = (
             self.config.resident_run_max
-            if getattr(self.config, "resident_drain", False)
-            else getattr(self.config, "fast_batch_max", 4096)
+            if self.config.resident_drain
+            else self.config.fast_batch_max
         )
         ext = cap - len(batch)
         if ext > 0:

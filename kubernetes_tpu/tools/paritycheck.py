@@ -1,8 +1,10 @@
-"""Bench-time decision-parity evidence at north-star scale → PARITY_r*.json.
+"""Decision-parity evidence at scales beyond a pytest budget.
 
 The flagship claim — "binding decisions identical to default-scheduler" —
 needs evidence at scales no CI-budget pytest run can afford.  This tool
-produces it once per bench run on the real device:
+produces it on the device it is run on; ``chip_smoke.py``'s identity phase
+and tests/test_spans.py, test_wave.py and test_tpu_compile.py use its
+drains and pod generators:
 
   * CROSS-BATCH-SIZE identity at 10k nodes / 50k pods: the extended
     device fast path (fastBatchMax=4096, sig_scan pipeline) against a
@@ -14,8 +16,8 @@ produces it once per bench run on the real device:
     rotation cursor, and seeded tie-break against the scalar
     reference-shaped loop (schedule_one semantics).
 
-Writes one JSON artifact {"checks": {...}, "total_diffs": N}; the driver
-records it next to BENCH_r*.json.  Run standalone:
+Writes one JSON artifact {"checks": {...}, "total_diffs": N}.  Run
+standalone:
 
     python -m kubernetes_tpu.tools.paritycheck [--out PARITY.json]
 """
@@ -289,7 +291,7 @@ def check_wave_vs_oracle(
 ) -> dict:
     """Wave-dispatch drain (speculation + factored conflict resolution,
     ops/wave.py) vs the serial oracle on a mixed spread/anti-affinity
-    workload — the wave's bit-identity evidence at bench scale.
+    workload — the wave's bit-identity evidence at scale.
     ``make_pods(n)`` swaps the workload (chip_smoke.py passes the
     TopologySpreading pods: every statics variant of the wave engine is
     minutes of TPU compile, so its drain and its identity check share
@@ -342,8 +344,8 @@ def _port_heavy_pods(n, seed=13, apps=8, prefix="pp"):
     (some wildcard-IP, some IP-scoped) alongside spread terms — the wave's
     factored [Tpt, N] port-occupancy carry is the only thing standing
     between this workload and the gang scan.  THE workload definition for
-    the de-fallback coverage: bench config13 and tests/test_wave.py both
-    import it, so the artifacts exercise one mix, not drifting copies."""
+    the de-fallback coverage: tests/test_wave.py imports it, so the check
+    and the test exercise one mix, not drifting copies."""
     from kubernetes_tpu.api.types import (
         Container,
         ContainerPort,

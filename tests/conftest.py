@@ -1,7 +1,7 @@
 """Test configuration: the suite runs on the CPU backend with 8 virtual
 devices (the mesh/sharding tests need >1 device; a bare ``pytest`` on a TPU
 host must not grab the chip).  Set before any backend initializes.  Chip
-runs happen through ``chip_smoke.py`` (and bench.py), never under pytest;
+runs happen through ``chip_smoke.py`` and ``benchmarks/run.py``, never under pytest;
 the TPU *compiler* is exercised without a chip by tests/test_tpu_compile.py.
 
 The persistent compile cache stays at the package default

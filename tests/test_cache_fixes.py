@@ -15,7 +15,7 @@ def _node(name):
 
 def test_assume_does_not_mutate_queued_pod():
     """schedule_one.go assumes a DeepCopy; a failed attempt must leave the
-    queued object pristine (ADVICE high)."""
+    queued object pristine."""
     cache = Cache()
     cache.add_node(_node("n1"))
     pod = Pod(name="p", containers=[Container(name="c", requests={"cpu": "1"})])
@@ -53,7 +53,7 @@ def _port_pod(name, node, port):
 
 def test_mirror_port_overflow_repacks_same_cycle():
     """Host-port rows beyond the bucket must be visible to THIS batch, not
-    the next one (ADVICE medium)."""
+    the next one."""
     cache = Cache()
     cache.add_node(_node("n1"))
     cache.add_pod(_port_pod("a", "n1", 8000))

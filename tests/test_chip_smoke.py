@@ -44,9 +44,9 @@ def test_rehearsal_runs_every_phase_and_is_never_ok(capsys):
         "kind": jax.devices()[0].device_kind,
         "count": len(jax.devices()),
     }
-    for phase in ("device", "drain", "constraints", "served", "identity"):
+    for phase in ("device", "drain", "constraints", "identity"):
         assert f"--- phase {phase} ---" in out, phase
-    assert "--- phase mesh ---" not in out
+    assert "--- phase mesh ---" not in out and "--- phase served ---" not in out
     # the ONLY thing wrong with a clean rehearsal is the device itself:
     # every phase bound all its pods, zero diffs, zero breaker failures,
     # the kernels each phase exists to exercise dispatched, and the
@@ -55,7 +55,6 @@ def test_rehearsal_runs_every_phase_and_is_never_ok(capsys):
     assert len(fails) == 1 and "is not a TPU" in fails[0], fails
     assert "second drain bound 2048" in out and "(0 compiles)" in out
     assert "'resident.resident_run'" in out and '"wave.wave_run": 1' in out
-    assert "0 read-back mismatches" in out
     assert out.count('"diffs": 0') == 3
 
 

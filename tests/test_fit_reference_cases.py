@@ -5,11 +5,10 @@ tables), run against BOTH the host oracle (oracle/filters.py) and the
 device kernels (ops/filters.mask_resources + the fast path's
 FastCommitter.feasible_int).
 
-This is the start of the reference-ANCHORED parity story (VERDICT round-5
-"Next round" #2): until now every parity check proved device == our own
-oracle; these cases pin the oracle itself to the reference's published
-expectations, as data (inputs + expected insufficient-resource reasons),
-not translated code.  Units follow the reference table's spirit: cpu in
+This is the start of the reference-ANCHORED parity story: every other
+parity check proves device == our own oracle; these cases pin the oracle
+itself to the reference's published expectations, as data (inputs +
+expected insufficient-resource reasons), not translated code.  Units follow the reference table's spirit: cpu in
 whole cores, memory/ephemeral-storage in Mi (exact under the packed MiB
 lanes, so all three implementations judge identical quantities).
 """

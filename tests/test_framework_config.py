@@ -252,10 +252,74 @@ def test_v1beta3_reads_convert():
 def test_v1_validation_rejections(mutation, msg):
     from kubernetes_tpu.framework.config import load_config
 
-    base = {
-        "apiVersion": "kubescheduler.config.k8s.io/v1",
-        "kind": "KubeSchedulerConfiguration",
-    }
-    base.update(mutation)
     with pytest.raises(ValueError, match=msg):
-        load_config(base)
+        load_config({**V1, **mutation})
+
+
+# ---------------------------------------------------------------------------
+# TPU-extension options: each camelCase key loads, round-trips and is
+# range-checked where a range exists; each default has one home
+# ---------------------------------------------------------------------------
+
+V1 = {
+    "apiVersion": "kubescheduler.config.k8s.io/v1",
+    "kind": "KubeSchedulerConfiguration",
+}
+
+
+@pytest.mark.parametrize(
+    "key,value,refused",
+    [
+        ("fastBatchMax", 64, -1),
+        ("fastDeviceMin", 8, -1),
+        ("waveDispatch", False, None),
+        ("residentDrain", False, None),
+        ("residentRunMax", 256, 0),
+        ("residentWindow", 32, -2048),
+        ("residentSerialTail", True, None),
+        ("gangDispatch", False, None),
+        ("plannerKernel", False, None),
+        ("kernelLedger", False, None),
+        ("meshDispatch", False, None),
+        ("meshPodsAxis", 2, 0),
+    ],
+)
+def test_tpu_option_loads_round_trips_and_is_range_checked(key, value, refused):
+    import dataclasses
+
+    from kubernetes_tpu.framework.config import dump_config, load_config
+
+    field = cfg._SCALAR_KEYS[key]
+    default = cfg.SchedulerConfiguration()
+    assert getattr(default, field) != value
+    # a document without the key keeps the dataclass's default
+    assert load_config(dict(V1)) == default
+    loaded = load_config({**V1, key: value})
+    # the key set that one field and no other
+    assert loaded == dataclasses.replace(default, **{field: value})
+    doc = dump_config(loaded)
+    assert doc[key] == value
+    assert load_config(doc) == loaded
+    if refused is not None:
+        with pytest.raises(ValueError, match=key):
+            load_config({**V1, key: refused})
+
+
+def test_each_default_and_each_wire_key_has_one_home():
+    """The dataclass holds the defaults: the scheduler reads options as
+    attributes (no ``getattr(self.config, name, default)`` with a second
+    default), and the loader and dumper walk one table that covers every
+    scalar field."""
+    import dataclasses
+    import inspect
+
+    from kubernetes_tpu import scheduler
+
+    assert "getattr(self.config" not in inspect.getsource(scheduler)
+    scalar = {f.name for f in dataclasses.fields(cfg.SchedulerConfiguration)} - {
+        "profiles",
+        "extenders",
+        "feature_gates",
+    }
+    assert set(cfg._SCALAR_KEYS.values()) == scalar
+    assert "resident_epoch_guard" not in scalar

@@ -73,8 +73,8 @@ def run_serial(state, pending):
 
 @pytest.mark.parametrize(
     "seed,n_nodes,n_placed,n_pending",
-    # small tier + the wider randomized sweep (VERDICT r2 task 6 — the
-    # breadth tier of schedule_one_test.go)
+    # small tier + the wider randomized sweep (the breadth tier of
+    # schedule_one_test.go)
     [(31, 10, 20, 20), (32, 10, 20, 20), (33, 10, 20, 20), (34, 10, 20, 20),
      (101, 40, 80, 120), (202, 40, 80, 120), (303, 40, 80, 120),
      (404, 40, 80, 120), (505, 40, 80, 120), (606, 40, 80, 120)],

@@ -20,15 +20,11 @@ Plus the PR-7 steady-state SLO tier:
     Perfetto-loadable trace whose window covers the breach;
   * /debug/slo serves the live SLI snapshot schema;
   * black-box mode off is a no-op (one attribute read per site);
-  * Histogram.percentile returns the +Inf sentinel at saturation;
-  * a small deterministic --arrival run shows latency monotone in
-    offered load.
+  * Histogram.percentile returns the +Inf sentinel at saturation.
 """
 
 import json
 import os
-import subprocess
-import sys
 import threading
 import time
 import urllib.request
@@ -55,8 +51,6 @@ from kubernetes_tpu.observability import (
     oracle_explain,
 )
 from kubernetes_tpu.scheduler import Scheduler
-
-REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _mk_sched():
@@ -468,59 +462,6 @@ def test_debug_endpoints_serve_json():
             assert r.status == 200 and b"cache dump" in r.read()
     finally:
         server.stop()
-
-
-# ---------------------------------------------------------------------------
-# bench --trace-out artifact
-# ---------------------------------------------------------------------------
-
-
-def _load_bench():
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "bench", os.path.join(REPO_ROOT, "bench.py")
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_capture_trace_artifact_parses(tmp_path):
-    bench = _load_bench()
-    out = bench.capture_trace(
-        str(tmp_path / "trace.json"), n_nodes=16, n_pods=200
-    )
-    assert out["valid"] and out["events"] > 0
-    with open(out["trace"]) as f:
-        loaded = json.load(f)
-    assert any(e.get("name") == "drain" for e in loaded["traceEvents"])
-
-
-@pytest.mark.slow
-def test_trace_out_flag_subprocess(tmp_path):
-    """The CI-shaped invocation: bench.py --trace-out records a traced
-    config0-style drain end to end in a fresh process."""
-    path = str(tmp_path / "trace.json")
-    env = dict(
-        os.environ,
-        JAX_PLATFORMS="cpu",
-        BENCH_TRACE_NODES="200",
-        BENCH_TRACE_PODS="2000",
-    )
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO_ROOT, "bench.py"), f"--trace-out={path}"],
-        capture_output=True,
-        text=True,
-        timeout=600,
-        env=env,
-        cwd=REPO_ROOT,
-    )
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    out = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert out["valid"] and out["pods"] > 0
-    with open(path) as f:
-        assert json.load(f)["traceEvents"]
 
 
 # ---------------------------------------------------------------------------
@@ -1028,31 +969,3 @@ def test_attempt_duration_carries_batch_size_label():
     assert batch_size_bucket(4) == "2-15"
     assert batch_size_bucket(100) == "16-255"
     assert batch_size_bucket(5000) == "4096+"
-
-
-def test_arrival_harness_latency_monotone_in_offered_load():
-    """A deterministic (seeded) two-point --arrival run: offered load far
-    past the serving capacity must show strictly worse p99 than a lightly
-    loaded run, and the curve schema must match what config9 publishes."""
-    bench = _load_bench()
-    out = bench.run_arrival_harness(
-        n_nodes=150,
-        rates=(40.0, 4000.0),
-        duration_s=1.2,
-        seed=7,
-        slo_p99_s=1.0,
-        warm_pods=512,
-        settle_timeout_s=60.0,
-    )
-    curve = out["curve"]
-    assert [c["rate"] for c in curve] == [40.0, 4000.0]
-    for c in curve:
-        assert {"rate", "offered", "bound", "unbound", "p50_ms", "p99_ms",
-                "achieved_pods_per_s", "met_slo"} <= set(c)
-    lo, hi = curve
-    assert lo["unbound"] == 0 and lo["p99_ms"] is not None
-    # saturation: either the p99 blew past the light-load p99, or pods
-    # didn't even finish (censored +Inf ranks above every finite sample)
-    assert hi["p99_ms"] is None or hi["p99_ms"] > lo["p99_ms"]
-    assert out["max_rate_at_slo"] in (40.0, 4000.0, 0.0)
-    assert out["slo_p99_ms"] == 1000.0

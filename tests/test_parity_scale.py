@@ -29,8 +29,8 @@ from tests.gen import make_cluster, make_pod
 
 # Default parametrization finishes in a CI-sized budget (<5 min on the
 # test backend); PARITY_FULL=1 restores the exhaustive seed sweep.
-# North-star-scale parity evidence lives in the bench-time artifact
-# (kubernetes_tpu/tools/paritycheck.py → PARITY_r*.json).
+# Parity evidence at larger scales comes from
+# kubernetes_tpu/tools/paritycheck.py (chip_smoke.py's identity phase).
 FULL = os.environ.get("PARITY_FULL", "0") == "1"
 
 NS_LABELS = {

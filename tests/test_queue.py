@@ -134,7 +134,7 @@ def test_delete_removes_everywhere():
 
 
 def test_update_reorders_active_heap():
-    """A priority bump while active must reorder the heap (VERDICT weak #7)."""
+    """A priority bump while active must reorder the heap."""
     q, _ = make_queue()
     a = Pod(name="a", priority=0)
     b = Pod(name="b", priority=10)
@@ -148,7 +148,7 @@ def test_update_reorders_active_heap():
 
 def test_stale_backoff_entry_not_resurrected():
     """backoff → activate → fail → backoff again must honor the NEW backoff
-    window, not a stale earlier heap entry (ADVICE low #2)."""
+    window, not a stale earlier heap entry."""
     q, clock = make_queue()
     pod = Pod(name="p")
     q.add(pod)

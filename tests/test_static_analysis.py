@@ -646,37 +646,6 @@ def test_warm_config0_drain_zero_unexpected_recompiles(retrace_armed):
     )
 
 
-# ----- bench --analyze preflight ---------------------------------------------
-
-
-def test_bench_analyze_preflight_refuses_findings(monkeypatch):
-    import io
-    import sys as _sys
-
-    _sys.path.insert(0, os.path.dirname(os.path.dirname(__file__)))
-    try:
-        import bench
-    finally:
-        _sys.path.pop(0)
-
-    import kubernetes_tpu.analysis as analysis_mod
-    from kubernetes_tpu.analysis.core import Finding
-
-    err = io.StringIO()
-    assert bench.analyze_preflight(err=err) is True
-    assert "preflight clean" in err.getvalue()
-
-    def fake_run_analysis():
-        return [Finding("d2h-leak", "x.py", 1, "seeded")]
-
-    monkeypatch.setattr(analysis_mod, "run_analysis", fake_run_analysis)
-    err = io.StringIO()
-    assert bench.analyze_preflight(err=err) is False
-    out = err.getvalue()
-    assert "refusing to record bench JSON" in out
-    assert "d2h-leak" in out
-
-
 # ----- symbolic shape interpreter (shape / dtype / shard) --------------------
 
 
