@@ -126,18 +126,30 @@ class _WatchCache:
         self._watcher_seq = 0
 
     def record(self, event_type: str, envelope: dict, key: Optional[str] = None) -> int:
+        return self.record_many(event_type, [(envelope, key)])
+
+    def record_many(self, event_type: str, entries) -> int:
+        """Append ``entries`` ((envelope, key), ...) as ONE transaction:
+        one acquisition of the cond, consecutive rvs in entry order, each
+        event's frame and nested blob encoded once, ONE wake-up for the
+        lot.  Returns the last rv (the entries hold rv-len+1 .. rv)."""
+        deleted = event_type == "DELETED"
         with self.cond:
-            self.rv += 1
-            nested = wire_codec.encode_nested(envelope)
-            frame = wire_codec.encode_event(event_type, self.rv, nested)
-            self.events.append(_Event(self.rv, event_type, envelope, frame))
-            if key is not None:
-                if event_type == "DELETED":
-                    self.obj_frames.pop(key, None)
-                else:
-                    self.obj_frames[key] = nested
+            rv = self.rv
+            events, frames = self.events, self.obj_frames
+            for envelope, key in entries:
+                rv += 1
+                nested = wire_codec.encode_nested(envelope)
+                frame = wire_codec.encode_event(event_type, rv, nested)
+                events.append(_Event(rv, event_type, envelope, frame))
+                if key is not None:
+                    if deleted:
+                        frames.pop(key, None)
+                    else:
+                        frames[key] = nested
+            self.rv = rv
             self.cond.notify_all()
-            return self.rv
+            return rv
 
     def _stale(self, rv: int) -> bool:
         """rv precedes the retained window → the watcher must relist.
@@ -166,13 +178,26 @@ class _WatchCache:
                 if self._stale(rv):
                     self.gone_total += 1
                     return None  # compacted away → 410 Gone
-                out = [e for e in self.events if e.rv > rv]
+                out = self._tail(rv)
                 if out:
                     return out
                 remaining = deadline - time.monotonic()
                 if remaining <= 0:
                     return []
                 self.cond.wait(remaining)
+
+    def _tail(self, rv: int) -> List[_Event]:
+        """The retained events with rv' > rv, oldest first.  Events are
+        appended in rv order, so the walk starts at the head and stops at
+        the first one the watcher has: a wake-up costs the new events,
+        not the window."""
+        out: List[_Event] = []
+        for e in reversed(self.events):
+            if e.rv <= rv:
+                break
+            out.append(e)
+        out.reverse()
+        return out
 
     def compact(self, keep: int = 0) -> None:
         """Drop all but the last ``keep`` retained events (the etcd
@@ -206,6 +231,11 @@ class ApiServer:
         # scheduler_tpu_wire_bytes_total at scrape time.
         self.wire_bytes: Dict[Tuple[str, str], int] = {}
         self._wire_mu = threading.Lock()
+        # bulk binding transactions (POST /bindings → bind_txn): slices
+        # applied and items in them.  Plain ints written under _mu, like
+        # the watch caches' compactions.
+        self.bulk_bind_txns = 0
+        self.bulk_bind_items = 0
         # subscribe to the store's fan-out so every mutation (from any
         # client, or in-proc drivers) lands in the watch caches
         api.watch_nodes(
@@ -217,6 +247,9 @@ class ApiServer:
             lambda p: self._record("pods", "ADDED", p),
             lambda old, new: self._record("pods", "MODIFIED", new),
             lambda p: self._record("pods", "DELETED", p),
+            # a bind_many slice: the stored pods, borrowed — serialised
+            # into the watch cache before the call returns, nothing kept
+            lambda pods: self._record_many("pods", "MODIFIED", pods),
         )
         server = self
 
@@ -443,41 +476,19 @@ class ApiServer:
                     code, payload = mk(decode(body))
                     return self._json(code, payload)
                 if len(parts) == 3 and parts[2] == "bindings":
-                    # BULK binding write: the per-pod binding subresource
-                    # semantics applied item-wise under the server lock —
-                    # the batch-first extension of assignPod
-                    # (storage.go:169); per-item statuses come back so the
-                    # scheduler can unwind exactly the pods that failed
-                    results = []
+                    # BULK binding write: the slice is ONE store
+                    # transaction under the server lock (bind_txn) — the
+                    # batch-first extension of assignPod (storage.go:169);
+                    # per-item statuses come back so the scheduler can
+                    # unwind exactly the pods that failed
+                    items = [
+                        (item.get("uid"), item.get("node"))
+                        for item in body.get("items", [])
+                    ]
                     ann_lock = annotation("apiserver.lock_wait").begin()
                     with server._mu:
                         ann_lock.end()
-                        for item in body.get("items", []):
-                            uid = item.get("uid")
-                            pod = server.api.pods.get(uid)
-                            if pod is None:
-                                results.append(
-                                    {"code": 404, "error": f"pod {uid} not found"}
-                                )
-                                continue
-                            try:
-                                server.api.bind(pod, item["node"])
-                                results.append(None)
-                            except RuntimeError as e:
-                                # the 409 carries the EXISTING binding so a
-                                # client whose transport-level retry races
-                                # its own applied first attempt can tell
-                                # conflict-on-retry (node matches: success)
-                                # from a real double-bind
-                                results.append(
-                                    {
-                                        "code": 409,
-                                        "error": str(e),
-                                        "node": pod.node_name,
-                                    }
-                                )
-                            except KeyError as e:
-                                results.append({"code": 404, "error": str(e)})
+                        results = server.bind_txn(items)
                     return self._json(200, {"results": results})
                 if len(parts) == 5 and parts[2] == "pods" and parts[4] == "binding":
                     uid = unquote(parts[3])
@@ -640,13 +651,47 @@ class ApiServer:
     # ----- store access -----------------------------------------------------
 
     def _record(self, res: str, etype: str, obj) -> None:
-        key = obj.uid if isinstance(obj, Pod) else obj.name
-        rv = self.caches[res].record(etype, encode(obj), key=key)
+        self._record_many(res, etype, (obj,))
+
+    def _record_many(self, res: str, etype: str, objs) -> None:
+        """``objs`` enter the watch cache as one append (consecutive rvs,
+        one wake-up).  They may be borrowed from the store: each is
+        serialised here and not kept.  A generator, so that an envelope
+        is built as the window drops an old one: a slice's worth of new
+        containers ahead of the frees would only feed the collector."""
+        last = self.caches[res].record_many(
+            etype,
+            (
+                (encode(obj), obj.uid if isinstance(obj, Pod) else obj.name)
+                for obj in objs
+            ),
+        )
         cp = self.cp
         if cp is not None and cp.enabled:
-            # the api_write breadcrumb: this event's rv + its watch-cache
+            # the api_write breadcrumbs: each event's rv + its watch-cache
             # entry time — the root of every pod's causal pipeline chain
-            cp.note_api_write(res, rv, obj)
+            cp.note_api_write_many(
+                res, zip(range(last - len(objs) + 1, last + 1), objs)
+            )
+
+    def bind_txn(self, items) -> list:
+        """One bulk binding POST, under ``_mu``: the store applies the
+        slice as one transaction (``FakeCluster.bind_many``) and this
+        server's batch handler puts its MODIFIED events into the watch
+        cache before the store returns — so a bind is acknowledged only
+        after the store holds it and its event is replayable."""
+        with annotation("apiserver.bind_txn", items=len(items)):
+            results = self.api.bind_many(items)
+            self.bulk_bind_txns += 1
+            self.bulk_bind_items += len(items)
+        return results
+
+    @property
+    def bulk_bind_fallback_items(self) -> int:
+        """Per-item deliveries the store made, in bulk transactions, to
+        subscribers that registered no batch handler (0 where this server
+        is the store's only subscriber)."""
+        return self.api.bind_many_fallback_items
 
     def _note_wire(self, codec: str, direction: str, n: int) -> None:
         if not n:

@@ -1040,6 +1040,16 @@ class SchedulerMetrics:
                 ("resource",),
             )
         )
+        self.apiserver_bulk_bind = r.register(
+            Counter(
+                "scheduler_tpu_apiserver_bulk_bind_total",
+                "Bulk binding POSTs the API server applied as one store "
+                "transaction: what=txns (slices), items (bindings in them), "
+                "fallback_items (per-item deliveries to a store subscriber "
+                "without a batch handler), refreshed on scrape.",
+                ("what",),
+            )
+        )
         self.wire_bytes_total = r.register(
             Counter(
                 "scheduler_tpu_wire_bytes_total",

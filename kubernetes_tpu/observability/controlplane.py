@@ -272,21 +272,29 @@ class ControlPlaneMonitor:
 
     def note_api_write(self, res: str, rv: int, obj) -> None:
         """ApiServer._record: the event entered the watch cache at rv."""
+        self.note_api_write_many(res, ((rv, obj),))
+
+    def note_api_write_many(self, res: str, writes) -> None:
+        """ApiServer._record_many: ``writes`` ((rv, obj), ...) entered the
+        watch cache in one append — one clock reading and one acquisition
+        of the lock for the lot."""
         mono = self._mono()
         lt = self._lt()
-        uid = getattr(obj, "uid", None)  # pods chain; nodes only join rv
+        window = self.config.rv_window
         with self._mu:
             stamps = self._rv_stamp.get(res)
             if stamps is None:
                 stamps = self._rv_stamp[res] = {}
                 self._rv_order[res] = deque()
             order = self._rv_order[res]
-            if len(order) >= self.config.rv_window:
-                stamps.pop(order.popleft(), None)
-            stamps[rv] = mono
-            order.append(rv)
-            if uid is not None:
-                self._stamp_locked(uid, "api_write", rv, mono, lt)
+            for rv, obj in writes:
+                if len(order) >= window:
+                    stamps.pop(order.popleft(), None)
+                stamps[rv] = mono
+                order.append(rv)
+                uid = getattr(obj, "uid", None)  # pods chain; nodes only join rv
+                if uid is not None:
+                    self._stamp_locked(uid, "api_write", rv, mono, lt)
 
     def note_delivery(self, res: str, rv: int, obj) -> None:
         """Reflector watch loop: the event reached this process (decoded,
@@ -592,6 +600,14 @@ class ControlPlaneMonitor:
                 prom.watch_compactions.inc(dc, resource=res)
             if dg:
                 prom.watch_relists.inc(dg, resource=res)
+        for what in ("txns", "items", "fallback_items"):
+            total = getattr(api, "bulk_bind_" + what)
+            with self._mu:
+                key = ("bulk_bind", what)
+                d = total - self._cache_synced.get(key, 0)
+                self._cache_synced[key] = total
+            if d:
+                prom.apiserver_bulk_bind.inc(d, what=what)
         with api._wire_mu:
             wire = dict(api.wire_bytes)
         for (codec, direction), total in wire.items():

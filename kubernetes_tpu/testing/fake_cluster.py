@@ -66,7 +66,10 @@ class FakeCluster:
         self.pods: Dict[str, Pod] = {}
         self.pdbs: Dict[str, object] = {}  # name → PodDisruptionBudget
         self._node_handlers: List[tuple] = []  # (add, update, delete)
-        self._pod_handlers: List[tuple] = []
+        self._pod_handlers: List[tuple] = []  # (add, update, delete, update_many)
+        # per-item (old, new) deliveries bind_many made to subscribers that
+        # registered no batch handler
+        self.bind_many_fallback_items = 0
         self.bindings: Dict[str, str] = {}  # pod uid → node name
         self.evictions: List[str] = []  # uids deleted via preemption
         self.events: List[object] = []  # recorded Events (events.k8s.io)
@@ -174,8 +177,12 @@ class FakeCluster:
         for node in self.nodes.values():
             on_add(node)
 
-    def watch_pods(self, on_add, on_update, on_delete) -> None:
-        self._pod_handlers.append((on_add, on_update, on_delete))
+    def watch_pods(self, on_add, on_update, on_delete, on_update_many=None) -> None:
+        """``on_update_many(pods)``, where given, takes a ``bind_many``
+        slice's updates in ONE call: the stored pods, in item order,
+        BORROWED for the duration of the call (no copy, no ``old``) — the
+        subscriber reads or serialises them and keeps no reference."""
+        self._pod_handlers.append((on_add, on_update, on_delete, on_update_many))
         for pod in self.pods.values():
             on_add(pod)
 
@@ -202,19 +209,22 @@ class FakeCluster:
     # ----- pods -------------------------------------------------------------
 
     def create_pod(self, pod: Pod) -> None:
-        # The store owns its copy and every delivered event carries a fresh
-        # copy — callers keep mutating theirs (assume sets nodeName on the
-        # scheduler's object) without ever aliasing the "API" state.
+        # The store owns its copy and every event delivered to a per-item
+        # handler carries a fresh copy — callers keep mutating theirs
+        # (assume sets nodeName on the scheduler's object) without ever
+        # aliasing the "API" state.  The one exception is declared by the
+        # subscriber: a batch handler (watch_pods' on_update_many) borrows
+        # the stored pods of a bind_many slice for the length of its call.
         pod = copy.deepcopy(pod)
         self.pods[pod.uid] = pod
-        for add, _, _ in self._pod_handlers:
+        for add, *_ in self._pod_handlers:
             add(copy.deepcopy(pod))
 
     def update_pod(self, pod: Pod) -> None:
         pod = copy.deepcopy(pod)
         old = self.pods.get(pod.uid)
         self.pods[pod.uid] = pod
-        for _, update, _ in self._pod_handlers:
+        for _, update, *_ in self._pod_handlers:
             update(copy.deepcopy(old), copy.deepcopy(pod))
 
     def delete_pod(self, uid: str) -> None:
@@ -224,7 +234,7 @@ class FakeCluster:
         # the binding ceases to exist with the pod — bindings is the
         # CURRENTLY-bound set (the HTTP tier and benches read it as such)
         self.bindings.pop(uid, None)
-        for _, _, delete in self._pod_handlers:
+        for _, _, delete, _ in self._pod_handlers:
             delete(pod)
 
     # ----- binding subresource ----------------------------------------------
@@ -234,27 +244,87 @@ class FakeCluster:
     # delivers it; see Scheduler binder_override).
     mirror_extender_binds = True
 
-    def bind(self, pod: Pod, node_name: str) -> None:
-        """POST pods/{name}/binding: CAS-sets nodeName, rejects doubles."""
-        stored = self.pods.get(pod.uid)
-        if stored is None:
-            raise KeyError(f"binding unknown pod {pod.key}")
+    def _bind_cas(self, stored: Pod, node_name: str) -> bool:
+        """The binding CAS (assignPod, storage.go:254): True ⇒ set it,
+        False ⇒ a same-node rebind, raises on a conflict or unknown node."""
         if stored.node_name and stored.node_name != node_name:
             raise RuntimeError(
-                f"pod {pod.key} already bound to {stored.node_name}"
+                f"pod {stored.key} already bound to {stored.node_name}"
             )
         if stored.node_name == node_name and node_name:
             # same-node rebind: a transport-level POST retry replaying an
             # applied binding.  TRUE no-op — re-firing update handlers
             # here would fan a duplicate MODIFIED event to every watcher
-            return
+            return False
         if node_name not in self.nodes:
             raise KeyError(f"binding to unknown node {node_name}")
+        return True
+
+    def bind(self, pod: Pod, node_name: str) -> None:
+        """POST pods/{name}/binding: CAS-sets nodeName, rejects doubles."""
+        stored = self.pods.get(pod.uid)
+        if stored is None:
+            raise KeyError(f"binding unknown pod {pod.key}")
+        if not self._bind_cas(stored, node_name):
+            return
         old = copy.deepcopy(stored)
         stored.node_name = node_name
         self.bindings[pod.uid] = node_name
-        for _, update, _ in self._pod_handlers:
+        for _, update, *_ in self._pod_handlers:
             update(old, copy.deepcopy(stored))
+
+    def bind_many(self, items) -> List[Optional[dict]]:
+        """POST bindings: ``items`` is [(uid, node_name), ...], applied as
+        ONE store transaction.  Each item runs ``bind``'s CAS, in item
+        order, and a failure does not stop the slice; the result list is
+        aligned with the input: None (bound, or a same-node rebind: no-op,
+        no event), ``{"code": 404, "error"}`` (unknown pod or node) or
+        ``{"code": 409, "error", "node"}`` carrying the EXISTING binding,
+        so a client whose transport-level retry races its own applied
+        first attempt can tell conflict-on-retry (node matches: success)
+        from a real double-bind.
+
+        The slice's updates are delivered once, after the last mutation:
+        a subscriber with a batch handler gets one call with the stored
+        pods (borrowed, see ``watch_pods``); one without gets ``bind``'s
+        per-item ``update(old_copy, new_copy)``, and the copies are made
+        only where such a subscriber exists."""
+        per_item = any(h[3] is None for h in self._pod_handlers)
+        results: List[Optional[dict]] = []
+        bound: List[Pod] = []
+        olds: List[Pod] = []
+        for uid, node_name in items:
+            stored = self.pods.get(uid)
+            if stored is None:
+                results.append({"code": 404, "error": f"pod {uid} not found"})
+                continue
+            try:
+                apply = self._bind_cas(stored, node_name)
+            except RuntimeError as e:
+                results.append(
+                    {"code": 409, "error": str(e), "node": stored.node_name}
+                )
+                continue
+            except KeyError as e:
+                results.append({"code": 404, "error": str(e)})
+                continue
+            results.append(None)
+            if not apply:
+                continue
+            if per_item:
+                olds.append(copy.deepcopy(stored))
+            stored.node_name = node_name
+            self.bindings[uid] = node_name
+            bound.append(stored)
+        if bound:
+            for _, update, _, update_many in self._pod_handlers:
+                if update_many is not None:
+                    update_many(bound)
+                    continue
+                self.bind_many_fallback_items += len(bound)
+                for old, stored in zip(olds, bound):
+                    update(old, copy.deepcopy(stored))
+        return results
 
     # ----- pod status subresource -------------------------------------------
 
@@ -267,7 +337,7 @@ class FakeCluster:
         old = copy.deepcopy(stored)
         stored.nominated_node_name = pod.nominated_node_name
         stored.phase = pod.phase
-        for _, update, _ in self._pod_handlers:
+        for _, update, *_ in self._pod_handlers:
             update(old, copy.deepcopy(stored))
 
     # ----- PDBs -------------------------------------------------------------

@@ -355,6 +355,10 @@ def test_full_watch_path_chain_over_http():
         pods = [_pod(f"w{i}", uid=f"default/w{i}") for i in range(4)]
         for p in pods:
             client.create_pod(p)
+        # nodes and pods arrive on two watch streams: a drain that starts
+        # before the node stream has delivered finds no node, and nothing
+        # here drains again
+        assert _wait(lambda: len(sched.cache.real_nodes()) >= 3)
         assert _wait(lambda: len(sched.queue) >= 4)
         sched.schedule_pending()
         assert _wait(lambda: len(api.bindings) == 4)

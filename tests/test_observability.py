@@ -560,6 +560,18 @@ def _slo_cfg(**kw):
     return SLOConfig(**defaults)
 
 
+def _slo_snapshot_once_filed(slo, timeout=10.0):
+    """The snapshot once a detected breach is filed.  The ``slo-eval``
+    worker may detect the breach first, on its own cadence: it then takes
+    the cooldown under the lock (a manual ``evaluate()`` returns None)
+    and files the record only after the freeze, export and dump, outside
+    the lock — a snapshot taken in between reads ``breaches_total`` 0."""
+    deadline = time.time() + timeout
+    while slo.snapshot()["breaches_total"] < 1 and time.time() < deadline:
+        time.sleep(0.02)
+    return slo.snapshot()
+
+
 def test_histogram_percentile_overflow_is_inf_sentinel():
     import math
 
@@ -743,7 +755,7 @@ def test_slo_breach_freezes_and_dumps_blackbox_ring(tmp_path):
         s.on_pod_add(_pod(f"bb{i}"))
     s.schedule_pending()
     s.slo.evaluate()  # settle any cadence race — breach is deterministic
-    snap = s.slo.snapshot()
+    snap = _slo_snapshot_once_filed(s.slo)
     assert snap["breaches_total"] >= 1
     rec = snap["last_breach"]
     assert rec["objective"] == "bind_p99"
@@ -790,7 +802,7 @@ def test_breach_dump_failure_falls_back_and_keeps_tier_alive(tmp_path):
         s.on_pod_add(_pod(f"df{i}"))
     s.schedule_pending()
     s.slo.evaluate()
-    snap = s.slo.snapshot()
+    snap = _slo_snapshot_once_filed(s.slo)
     assert snap["breaches_total"] == 1
     assert snap["last_breach"]["trace"] is None
     assert snap["ingest_errors"] >= 1
