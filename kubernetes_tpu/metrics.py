@@ -390,9 +390,10 @@ class PhaseAccumulator:
 
     Spans are opened from the scheduling loop AND binding workers, so
     booking takes a lock; the frequency is per batch / per bind slice (never
-    per pod), which keeps the overhead unmeasurable next to the phases
-    themselves.  ``snapshot`` returns a plain dict, so a caller can diff
-    two snapshots around a window.
+    per pod that binds; a pod that fails opens one, ``post_filter``), which
+    keeps the overhead unmeasurable next to the phases themselves.
+    ``snapshot`` returns a plain dict, so a caller can diff two snapshots
+    around a window.
     """
 
     def __init__(self, hist: Optional[Histogram] = None):

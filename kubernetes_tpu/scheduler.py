@@ -5473,11 +5473,17 @@ class Scheduler:
         profile has one (schedule_one.go:135-180).  Holds the cache lock:
         the preemption dry-run reads (and temporarily patches) the SHARED
         oracle view, which binding workers now patch in place on
-        forget/assume — unsynchronized interleaving would corrupt it."""
-        with self._mu:
-            return self._post_filter_or_fail_locked(
-                fwk, state, qp, status, n_feas, diagnosis, plugins
-            )
+        forget/assume — unsynchronized interleaving would corrupt it.
+        The ``post_filter`` span is the failure path's whole cost for one
+        pod (diagnosis record, PostFilter's dry run, requeue, the
+        FailedScheduling event); it nests inside ``commit``."""
+        with self._span("post_filter"):
+            sp_lock = self._span("post_filter.lock_wait").begin()
+            with self._mu:
+                sp_lock.end()
+                return self._post_filter_or_fail_locked(
+                    fwk, state, qp, status, n_feas, diagnosis, plugins
+                )
 
     def _post_filter_or_fail_locked(
         self,
@@ -6141,6 +6147,7 @@ class Scheduler:
                 plugins = set()
             else:
                 self.metrics["unschedulable"] += 1
+                self.phases.count("sched.unschedulable", 1)
             if plugins is None:
                 plugins = {status.plugin} if status.plugin else set()
             self.queue.add_unschedulable(qp, plugins)

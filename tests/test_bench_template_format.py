@@ -27,6 +27,22 @@ HOSTNAME = "kubernetes.io/hostname"
 PINNED = "79cb34f282026458487f8052be632c416fcfc9a983882c2dadb3083f178e6d5d"
 
 
+def _digest(T, R, nodes, init, init_nodes, plan) -> str:
+    """sha256 over the repr of every node and pod object, in the program's
+    types (``T``, ``R``) and the frozen reference's, and every uid."""
+    h = hashlib.sha256()
+    for TT, RRR in ((T, R), (RT, RR)):
+        for s in nodes:
+            h.update(repr(workload.build_node(TT, RRR, s)).encode())
+        for s, n in zip(init, init_nodes):
+            h.update(repr(workload.build_pod(TT, s, node_name=n)).encode())
+        for s in plan["warm"] + plan["measure"]:
+            h.update(repr(workload.build_pod(TT, s)).encode())
+    for s in init + plan["warm"] + plan["measure"]:
+        h.update(workload.uid_of(s).encode())
+    return h.hexdigest()
+
+
 def _built():
     cell = cells.cell(CELL)
     return cell["config"], _pr29._groups(cell["config"], cell["traffic"], cell["kind"], 7)
@@ -64,16 +80,6 @@ def test_prefaffinity_pods_are_the_sources_template_and_equal_on_both_sides():
     from kubernetes_tpu.api import types as T
 
     _cfg, (nodes, init, init_nodes, plan) = _built()
-    h = hashlib.sha256()
-    for TT, RRR in ((T, R), (RT, RR)):
-        for s in nodes:
-            h.update(repr(workload.build_node(TT, RRR, s)).encode())
-        for s, n in zip(init, init_nodes):
-            h.update(repr(workload.build_pod(TT, s, node_name=n)).encode())
-        for s in plan["warm"] + plan["measure"]:
-            h.update(repr(workload.build_pod(TT, s)).encode())
-    for s in init + plan["warm"] + plan["measure"]:
-        h.update(workload.uid_of(s).encode())
     for s, n in ((init[0], init_nodes[0]), (init[-1], init_nodes[-1]), (plan["measure"][0], "")):
         pod = workload.build_pod(T, s, node_name=n)
         assert dataclasses.asdict(pod) == dataclasses.asdict(workload.build_pod(RT, s, node_name=n))
@@ -86,4 +92,71 @@ def test_prefaffinity_pods_are_the_sources_template_and_equal_on_both_sides():
         assert wt.weight == 1 and term.topology_key == HOSTNAME
         assert term.label_selector.match_labels == {"color": "red"}
         assert term.namespaces == ("sched-1", "sched-0")
-    assert h.hexdigest() == PINNED
+    assert _digest(T, R, nodes, init, init_nodes, plan) == PINNED
+
+
+# ---- sched-perf-unschedulable-5k (upstream's :724), PR 34 ----------------------
+
+UNSCHED_CELL = "unsched-5k.backlog-pending-first"
+# the same digest over what benchmarks/workload.py builds from the file PR 34
+# adds, seed 7: no init pods; warm-up and measured backlogs, pending pods first
+UNSCHED_PINNED = "23ed4b59c134434a39b485cc945fde858d5b809afa56aca4775f181ba7dded32"
+
+
+def _unsched_built():
+    cell = cells.cell(UNSCHED_CELL)
+    return cell, _pr29._groups(cell["config"], cell["traffic"], cell["kind"], 7)
+
+
+def test_unschedulable_config_round_trips_with_no_key_refused():
+    """Every key of the file is one the harness builds (no ``KeyError``), at
+    the source's counts, with nothing cut; the third group rides in the
+    plan's two lists, pending pods first."""
+    cell, (nodes, init, init_nodes, plan) = _unsched_built()
+    cfg = cell["config"]
+    assert cfg["reduced"] == [] and cfg["name"] == "sched-perf-unschedulable-5k"
+    assert (len(nodes), len(init), len(init_nodes)) == (5000, 0, 0)
+    assert (len(plan["warm"]), plan["n_warm_pending"], len(plan["measure"]), plan["n_pending"]) == \
+        (10200, 200, 10200, 200)
+    assert [s["name"] for s in plan["measure"][198:202]] == ["pending-198", "pending-199", "load-0", "load-1"]
+    assert [s["name"] for s in plan["warm"][198:202]] == \
+        ["warm-pending-198", "warm-pending-199", "warm-0", "warm-1"]
+    assert len({workload.uid_of(s) for s in plan["warm"] + plan["measure"]}) == 20400
+    assert cell["kind"].pods_alive(plan) == 10200  # parked pods counted in
+
+
+def test_pending_group_yields_200_specs_named_pending_i():
+    cell, _groups = _unsched_built()
+    specs = workload.group_specs(cell["config"], "pending_pods", "pending")
+    assert [s["name"] for s in specs] == [f"pending-{i}" for i in range(200)]
+    assert {(s["namespace"], tuple(sorted(s["requests"].items()))) for s in specs} == \
+        {("default", (("cpu", "9"), ("memory", "500Mi")))}
+
+
+def test_unschedulable_pods_are_the_sources_templates_and_equal_on_both_sides():
+    """``pod-large-cpu`` (cpu 9, memory 500Mi, no labels, no term, priority
+    unset) and ``pod-default``, equal field by field in the program's types
+    and the frozen reference's; 9 cpu exceeds every node's 4."""
+    from kubernetes_tpu.api import resource as R
+    from kubernetes_tpu.api import types as T
+
+    _cell, (nodes, _init, _init_nodes, plan) = _unsched_built()
+    for s, requests in ((plan["measure"][0], {"cpu": "9", "memory": "500Mi"}),
+                        (plan["warm"][199], {"cpu": "9", "memory": "500Mi"}),
+                        (plan["measure"][200], {"cpu": "100m", "memory": "500Mi"})):
+        pod = workload.build_pod(T, s)
+        assert dataclasses.asdict(pod) == dataclasses.asdict(workload.build_pod(RT, s))
+        assert pod.labels == {} and pod.affinity is None and pod.topology_spread_constraints == ()
+        assert pod.containers[0].requests == requests and pod.node_name == ""
+        assert pod.priority == workload.build_pod(T, plan["measure"][-1]).priority
+    node = workload.build_node(T, R, nodes[0])
+    assert {str(workload.build_node(T, R, s).capacity) for s in nodes} == {str(node.capacity)}  # ONE node shape
+    assert R.Resource.from_map({"cpu": "9"}).milli_cpu > node.capacity.milli_cpu
+
+
+def test_unschedulable_config_builds_the_pinned_objects():
+    from kubernetes_tpu.api import resource as R
+    from kubernetes_tpu.api import types as T
+
+    _cell, (nodes, init, init_nodes, plan) = _unsched_built()
+    assert _digest(T, R, nodes, init, init_nodes, plan) == UNSCHED_PINNED

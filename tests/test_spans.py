@@ -121,6 +121,8 @@ def test_disabled_tracer_and_no_session_are_not_touched():
 # a small served drain under a CPU profiler session
 # ---------------------------------------------------------------------------
 
+N_TOO_LARGE = 3  # pods at the head of the served drain's queue that no node can hold
+
 SUB_PHASES = (
     "bind.queue_wait", "bind.sink", "bind.lock_wait", "bind.tail",
     "queue_pop.lock_wait", "commit.lock_wait", "flush_binds", "loop.idle",
@@ -130,9 +132,10 @@ SUB_PHASES = (
 @pytest.fixture(scope="module")
 def served_drain(tmp_path_factory):
     """API server over HTTP, reflectors, scheduling loop, binding workers;
-    1,200 one-shape pods on 32 nodes, drained under ``jax.profiler``.
-    Returns (phase totals, {event name: [stats dict, ...]} of the host
-    planes)."""
+    1,200 one-shape pods on 32 nodes, behind three that fit on none, drained
+    under ``jax.profiler``.  Returns (phase totals, {event name: [stats
+    dict, ...]} of the host planes, {event name: [(line, start ns, end
+    ns), ...]}, the scheduler's phase histogram)."""
     import jax
 
     from kubernetes_tpu.api.types import Container, Node, Pod
@@ -156,6 +159,11 @@ def served_drain(tmp_path_factory):
             name=f"n{i}",
             allocatable=Resource.from_map({"cpu": "64", "memory": "256Gi", "pods": "110"}),
         ))
+    for i in range(N_TOO_LARGE):  # the head of the queue: 100 cpu on 64-cpu nodes
+        driver.create_pod(Pod(
+            name=f"big{i}", uid=f"default/big{i}",
+            containers=[Container(name="c", requests={"cpu": "100", "memory": "64Mi"})],
+        ))
     n_pods = 1200
     for i in range(n_pods):
         driver.create_pod(Pod(
@@ -163,9 +171,9 @@ def served_drain(tmp_path_factory):
             containers=[Container(name="c", requests={"cpu": "100m", "memory": "64Mi"})],
         ))
     deadline = time.monotonic() + 60.0
-    while len(sched.queue) < n_pods and time.monotonic() < deadline:
+    while len(sched.queue) < n_pods + N_TOO_LARGE and time.monotonic() < deadline:
         time.sleep(0.01)
-    assert len(sched.queue) == n_pods
+    assert len(sched.queue) == n_pods + N_TOO_LARGE
     trace_dir = str(tmp_path_factory.mktemp("xplane"))
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
@@ -190,17 +198,19 @@ def served_drain(tmp_path_factory):
     from jax.profiler import ProfileData
 
     (path,) = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb"))
-    events = {}
+    events, spans = {}, {}
     for plane in ProfileData.from_file(path).planes:
         for line in plane.lines:
             for e in line.events:
                 if e.name.startswith("ktpu."):
                     events.setdefault(e.name, []).append(dict(e.stats))
-    return sched.phases.snapshot(), events
+                    spans.setdefault(e.name, []).append(
+                        ((plane.name, line.name), e.start_ns, e.start_ns + e.duration_ns))
+    return sched.phases.snapshot(), events, spans, sched.phases.hist
 
 
 def test_every_sub_phase_appears_and_the_parts_of_bind_fit_in_bind(served_drain):
-    phases, _ = served_drain
+    phases, _, _, _ = served_drain
     for name in SUB_PHASES:
         assert name in phases, (name, sorted(phases))
     parts = phases["bind.sink"] + phases["bind.lock_wait"] + phases["bind.tail"]
@@ -210,7 +220,7 @@ def test_every_sub_phase_appears_and_the_parts_of_bind_fit_in_bind(served_drain)
 
 
 def test_profiler_session_holds_the_programs_spans(served_drain):
-    _, events = served_drain
+    _, events, _, _ = served_drain
     for name in ("ktpu.batch", "ktpu.commit", "ktpu.bind",
                  "ktpu.apiserver.POST.bindings", "ktpu.apiserver.lock_wait",
                  "ktpu.bind.sink", "ktpu.loop.idle", "ktpu.flush_binds"):
@@ -223,6 +233,32 @@ def test_profiler_session_holds_the_programs_spans(served_drain):
     assert all("bid" in st for st in events["ktpu.batch"])
     # the ledger's dispatch call is a named span too
     assert any(n.startswith("ktpu.dispatch.") for n in events)
+
+
+def test_post_filter_is_a_span_like_every_other_and_nests_inside_commit(served_drain):
+    """The failure path of one pod (``Scheduler._post_filter_or_fail``): a
+    total, a histogram observation and a ``ktpu.post_filter`` annotation an
+    event, its lock wait split off as ``commit``'s is, each inside a
+    ``ktpu.commit`` span of the loop's thread."""
+    phases, events, spans, hist = served_drain
+    assert 0 < phases["post_filter"] <= phases["commit"]
+    assert phases["post_filter.lock_wait"] <= phases["post_filter"]
+    assert hist.count(phase="post_filter") == hist.count(phase="post_filter.lock_wait") == N_TOO_LARGE
+    assert len(events["ktpu.post_filter"]) == len(events["ktpu.post_filter.lock_wait"]) == N_TOO_LARGE
+    assert all("bid" in st for st in events["ktpu.post_filter"])
+    for line, t0, t1 in spans["ktpu.post_filter"]:
+        assert any(ln == line and c0 <= t0 and t1 <= c1 for ln, c0, c1 in spans["ktpu.commit"])
+
+
+def test_sched_unschedulable_is_a_count_not_an_interval(served_drain):
+    """Booked where ``_handle_failure`` books ``metrics["unschedulable"]``:
+    one a failed attempt; ``snapshot`` and ``diff`` carry it, and it has no
+    histogram observation and no span."""
+    phases, events, _, hist = served_drain
+    assert phases["sched.unschedulable"] == N_TOO_LARGE
+    assert PhaseAccumulator.diff(phases, {"sched.unschedulable": 1.0})["sched.unschedulable"] == N_TOO_LARGE - 1
+    assert hist.count(phase="sched.unschedulable") == 0
+    assert "ktpu.sched.unschedulable" not in events
 
 
 # ---------------------------------------------------------------------------
