@@ -1051,6 +1051,35 @@ class SchedulerMetrics:
                 ("what",),
             )
         )
+        self.gc_collections = r.register(
+            Counter(
+                "scheduler_tpu_gc_collections_total",
+                "Garbage collections of this process while a serving loop "
+                "held the collector policy (util/collector.py), by "
+                "generation and when: load = the interpreter's own, idle = "
+                "made by the loop's idle pass.  generation=2, when=load is a "
+                "full collection that landed on a busy loop.  Refreshed on "
+                "scrape.",
+                ("generation", "when"),
+            )
+        )
+        self.gc_pause_seconds = r.register(
+            Counter(
+                "scheduler_tpu_gc_pause_seconds_total",
+                "Seconds every thread of the process stood still in those "
+                "collections (the program's own gc.callbacks clock), by "
+                "generation, refreshed on scrape.",
+                ("generation",),
+            )
+        )
+        self.gc_frozen_objects = r.register(
+            Gauge(
+                "scheduler_tpu_gc_frozen_objects",
+                "Objects outside the collector's walk while the policy is "
+                "engaged (gc.get_freeze_count(), counted on scrape), 0 once "
+                "released.",
+            )
+        )
         self.wire_bytes_total = r.register(
             Counter(
                 "scheduler_tpu_wire_bytes_total",

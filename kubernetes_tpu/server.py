@@ -21,6 +21,15 @@ around the embeddable Scheduler:
     only the leader runs scheduling cycles, a lost lease stops them;
   * ``CacheDebugger`` — SIGUSR2 dump of cache + queue and a comparer
     against the informer ground truth (backend/cache/debugger).
+
+The serving loop also owns the garbage collector's schedule
+(``util/collector.py``): when it starts leading, the synced cluster state is
+frozen out of the collector's walk (``gc.freeze()``, no collection) and the
+young generation is sized for a batch; a full collection runs at the loop's
+idle point (nothing to decide, active queue and in-flight binds empty), or
+past a ceiling where the loop is never idle; ``stop()`` and a lost lease put
+the interpreter back as it was.  Embedding ``Scheduler`` without a
+``SchedulerServer`` leaves the interpreter's defaults alone.
 """
 
 from __future__ import annotations
@@ -35,6 +44,7 @@ from typing import Dict, List, Optional
 from urllib.parse import parse_qs, urlparse
 
 from kubernetes_tpu.scheduler import Scheduler
+from kubernetes_tpu.util.collector import LoopCollector
 
 # ---------------------------------------------------------------------------
 # Debug-endpoint catalogue: the ONE table both surfaces render from —
@@ -287,6 +297,8 @@ class SchedulerServer:
         self.elector = elector
         self.poll_interval_s = poll_interval_s
         self.debugger = CacheDebugger(scheduler, ground_truth)
+        # the garbage collector's schedule while this loop leads
+        self.collector = LoopCollector(scheduler.phases)
         self._stop = threading.Event()
         self._synced = threading.Event()
         self._loop_thread: Optional[threading.Thread] = None
@@ -328,6 +340,7 @@ class SchedulerServer:
                     else:
                         self._send(500, "informers not synced")
                 elif self.path == "/metrics":
+                    srv.collector.sync_registry(srv.sched.prom)
                     self._send(
                         200,
                         srv.sched.expose_metrics(),
@@ -565,6 +578,9 @@ class SchedulerServer:
                 target=self._run_election, daemon=True
             )
             self._le_thread.start()
+        else:
+            # no election: this loop leads from here on
+            self.collector.engage()
         self._loop_thread = threading.Thread(target=self._run_loop, daemon=True)
         self._loop_thread.start()
 
@@ -601,9 +617,13 @@ class SchedulerServer:
 
     def _run_loop(self) -> None:
         while not self._stop.is_set():
-            if self.elector is not None and not self._is_leader.is_set():
-                self._stop.wait(self.elector.retry_period_s)
-                continue
+            if self.elector is not None:
+                if not self._is_leader.is_set():
+                    self.collector.release()  # the lease is lost, or not won yet
+                    self._stop.wait(self.elector.retry_period_s)
+                    continue
+                self.collector.engage()  # the first iteration as leader
+            outs = None
             try:
                 outs = self.sched.schedule_pending()
                 if outs:
@@ -622,6 +642,15 @@ class SchedulerServer:
                     self.sched.metrics["errors"] += 1
                 except Exception:  # noqa: BLE001
                     pass
+            # idle = nothing decided, nobody queued, no bind in flight (while
+            # "only binding remains" the loop waits; it is not idle)
+            self.collector.poll(
+                busy=bool(
+                    outs
+                    or self.sched.queue._active
+                    or self.sched._inflight_binds
+                )
+            )
             # the sleep on an empty queue, named on the loop's own thread
             with self.sched.phases.span("loop.idle"):
                 self._stop.wait(self.poll_interval_s)
@@ -630,6 +659,7 @@ class SchedulerServer:
         self._stop.set()
         if self._loop_thread is not None:
             self._loop_thread.join(timeout=5)
+        self.collector.release()
         if self._le_thread is not None:
             # settle the renewal loop BEFORE releasing, or a concurrent
             # renew can defeat the release and strand the lease on this

@@ -6,8 +6,10 @@ schedules and a lost lease hands over) and backend/cache/debugger (dump +
 cache-vs-informer comparer).
 """
 
+import gc
 import time
 import urllib.request
+
 
 from kubernetes_tpu.api.resource import Resource
 from kubernetes_tpu.api.types import Container, Node, Pod
@@ -63,6 +65,59 @@ def test_endpoints_serve():
         assert code == 200 and "cache dump" in body
     finally:
         server.stop()
+
+
+def _served_backlog_bindings():
+    """A backlog of mixed requests queued before the loop starts, drained
+    by the served loop: {pod name: node}."""
+    api, sched = _env()
+    for i in range(48):
+        api.create_pod(
+            Pod(
+                name=f"b{i}",
+                uid=f"default/b{i}",
+                containers=[
+                    Container(
+                        requests={
+                            "cpu": f"{100 + 50 * (i % 5)}m",
+                            "memory": f"{64 * (1 + i % 3)}Mi",
+                        }
+                    )
+                ],
+            )
+        )
+    server = SchedulerServer(sched, poll_interval_s=0.005)
+    server.start()
+    try:
+        deadline = time.time() + 20
+        while time.time() < deadline and len(api.bindings) < 48:
+            time.sleep(0.01)
+    finally:
+        server.stop()
+    assert len(api.bindings) == 48
+    return dict(api.bindings)
+
+
+def test_collector_policy_is_invisible_to_decisions(monkeypatch):
+    from kubernetes_tpu.util.collector import YOUNG_THRESHOLD, LoopCollector
+
+    thresholds = []
+    engage = LoopCollector.engage
+
+    def engage_and_note(self):
+        engage(self)
+        thresholds.append(gc.get_threshold())
+
+    monkeypatch.setattr(LoopCollector, "engage", engage_and_note)
+    engaged = _served_backlog_bindings()
+    assert thresholds and thresholds[0][0] == YOUNG_THRESHOLD
+    # the same backlog with the policy released: the interpreter's defaults
+    monkeypatch.setattr(
+        LoopCollector, "engage", lambda self: thresholds.append(gc.get_threshold())
+    )
+    released = _served_backlog_bindings()
+    assert thresholds[-1][0] != YOUNG_THRESHOLD and gc.get_freeze_count() == 0
+    assert engaged == released
 
 
 def test_leader_election_exactly_one_schedules():
