@@ -3582,7 +3582,8 @@ class Scheduler:
     def _wave_resolve(self, fwk, batch, chosen, wstats_dev, e_rows, kernel=None):
         """Harvest one wave's speculation stats: admitted/demoted counters
         (``wave.demoted`` and, with ``e_rows`` — the existing-pod rows live
-        at the dispatch — ``wave.epod_rows`` go to the phase accumulator),
+        at the dispatch — ``wave.epod_rows`` go to the phase accumulator,
+        as does each conflict kind as ``wave.conflicts.<kind>``),
         a ``wave_demoted`` flight-recorder event (with the conflicting
         term) per corrected pod, and — when the framework permits lean
         binds — the interaction-group split the bulk commit path uses.
@@ -3636,6 +3637,7 @@ class Scheduler:
         self.phases.count("wave.epod_rows", e_rows)
         for kind, cnt in conflicts.items():
             self.prom.wave_conflicts.inc(cnt, kind=kind)
+            self.phases.count(f"wave.conflicts.{kind}", cnt)
         # Bulk-commit eligibility: lean_bind_ok()'s and the Reserve/Permit
         # "covered by host filters" no-op guarantees are BOTH conditioned
         # on the batch being spec-irrelevant to every host Filter plugin

@@ -12,6 +12,10 @@ configuration this PR adds, ``sched-perf-prefaffinity-5k`` (upstream's
 
 import dataclasses
 import hashlib
+import json
+import os
+
+import pytest
 
 from benchmarks import cells, workload
 from benchmarks.reference import resource as RR
@@ -160,3 +164,79 @@ def test_unschedulable_config_builds_the_pinned_objects():
 
     _cell, (nodes, init, init_nodes, plan) = _unsched_built()
     assert _digest(T, R, nodes, init, init_nodes, plan) == UNSCHED_PINNED
+
+
+# ---- sched-perf-antiaffinity-5k (upstream's :93), PR 37 -------------------------
+
+ANTI_CELL = "antiaffinity-5k.backlog"
+ANTI_FIXTURE = "sched-perf-antiaffinity"  # the same source at cut counts, in since PR 29
+
+
+def _anti_built():
+    cell = cells.cell(ANTI_CELL)
+    return cell["config"], _pr29._groups(cell["config"], cell["traffic"], cell["kind"], 7)
+
+
+def _but_count(d: dict) -> dict:
+    return {k: v for k, v in d.items() if k != "count"}
+
+
+@pytest.mark.parametrize("part", [
+    "nodes", "pod_templates", "init_pods", "measure_pods", "expect_kernels", "guarantees-but-the-last",
+])
+def test_antiaffinity_config_is_the_fixture_at_the_sources_counts(part):
+    """What PR 29 rehearsed at cut counts is what the cell runs: the same
+    node shape, template, namespaces and kernels; only the counts differ (and
+    the fourth guarantee, which says here what holds the term)."""
+    cfg = cells.cell(ANTI_CELL)["config"]
+    with open(os.path.join(os.path.dirname(_pr29.__file__), "fixtures", f"{ANTI_FIXTURE}.json")) as f:
+        fixture = json.load(f)
+    if part == "guarantees-but-the-last":
+        assert cfg["guarantees"][:3] == fixture["guarantees"][:3]
+        assert cfg["guarantees"][3].startswith(fixture["guarantees"][3])
+    elif part in ("nodes", "init_pods", "measure_pods"):
+        assert _but_count(cfg[part]) == _but_count(fixture[part])
+        assert cfg[part]["count"] == {"nodes": 5000, "init_pods": 1000, "measure_pods": 2000}[part]
+    else:
+        assert cfg[part] == fixture[part]
+
+
+def test_antiaffinity_config_round_trips_with_no_key_refused():
+    """Every key of the file is one the harness builds (no ``KeyError``), at
+    the source's counts, with nothing cut; one init pod a node on 1,000
+    distinct nodes; the sample of identity is 4 and says why."""
+    cfg, (nodes, init, init_nodes, plan) = _anti_built()
+    assert cfg["reduced"] == [] and cfg["name"] == "sched-perf-antiaffinity-5k"
+    assert (len(nodes), len(init), len(plan["warm"]), len(plan["measure"])) == (5000, 1000, 2000, 2000)
+    assert {s["namespace"] for s in init} == {"sched-0"}
+    assert {s["namespace"] for s in plan["warm"] + plan["measure"]} == {"sched-1"}
+    assert len({workload.uid_of(s) for s in init + plan["warm"] + plan["measure"]}) == 5000
+    assert len(set(init_nodes)) == 1000 <= len({n["labels"][HOSTNAME] for n in nodes}) == 5000
+    assert cfg["identity_sample"] == 4 and "recount" in cfg["identity_sample_why"]
+    assert len(cfg["source"]) <= 200 and ":93" in cfg["source"] and "5000Nodes_2000Pods" in cfg["source"]
+
+
+def test_antiaffinity_pods_are_the_sources_template_and_equal_on_both_sides():
+    """The one template, as the source has it: labels color=green and
+    name=test, 100m / 500Mi, ONE required pod-anti-affinity term over both
+    namespaces on the hostname, nothing preferred, no pod-affinity; equal
+    field by field in the program's types and the frozen reference's."""
+    from kubernetes_tpu.api import resource as R
+    from kubernetes_tpu.api import types as T
+
+    _cfg, (nodes, init, init_nodes, plan) = _anti_built()
+    for s, n in ((init[0], init_nodes[0]), (init[-1], init_nodes[-1]), (plan["warm"][0], ""), (plan["measure"][-1], "")):
+        pod = workload.build_pod(T, s, node_name=n)
+        assert dataclasses.asdict(pod) == dataclasses.asdict(workload.build_pod(RT, s, node_name=n))
+        assert pod.labels == {"color": "green", "name": "test"} and pod.affinity.pod_affinity is None
+        assert pod.containers[0].requests == {"cpu": "100m", "memory": "500Mi"}
+        anti = pod.affinity.pod_anti_affinity
+        assert anti.preferred_during_scheduling_ignored_during_execution == ()
+        (term,) = anti.required_during_scheduling_ignored_during_execution
+        assert term.topology_key == HOSTNAME
+        assert term.label_selector.match_labels == {"color": "green"}
+        assert term.namespaces == ("sched-1", "sched-0")
+    for s in (nodes[0], nodes[-1]):
+        node = workload.build_node(T, R, s)
+        assert dataclasses.asdict(node) == dataclasses.asdict(workload.build_node(RT, RR, s))
+        assert node.labels == {HOSTNAME: node.name}

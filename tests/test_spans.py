@@ -262,6 +262,93 @@ def test_sched_unschedulable_is_a_count_not_an_interval(served_drain):
 
 
 # ---------------------------------------------------------------------------
+# a wave's conflicts by kind (counts; ``Scheduler._wave_resolve``)
+# ---------------------------------------------------------------------------
+
+def _followers_and_repellers():
+    """Two pods that repel each other by a REQUIRED hostname anti-affinity
+    term (one ``affinity`` conflict: both speculate the first node) ahead of
+    three that REQUIRE a peer of theirs in the zone and are infeasible until
+    one has committed (three upgrades, which are demotions and no conflict)."""
+    from kubernetes_tpu.api.types import (
+        Affinity, Container, LabelSelector, Pod, PodAffinity, PodAffinityTerm, PodAntiAffinity,
+    )
+    from kubernetes_tpu.tools import paritycheck as pc
+
+    web = LabelSelector(match_labels={"app": "web"})
+    apart = Affinity(pod_anti_affinity=PodAntiAffinity(required_during_scheduling_ignored_during_execution=(
+        PodAffinityTerm(topology_key="kubernetes.io/hostname", label_selector=web),)))
+    beside = Affinity(pod_affinity=PodAffinity(required_during_scheduling_ignored_during_execution=(
+        PodAffinityTerm(topology_key="topology.kubernetes.io/zone", label_selector=web),)))
+
+    def pod(name, app, affinity):
+        return Pod(name=name, labels={"app": app}, affinity=affinity,
+                   containers=[Container(name="c", requests={"cpu": "100m", "memory": "64Mi"})])
+
+    pods = [pod(f"web-{i}", "web", apart) for i in range(2)]
+    return pc._basic_nodes(8, zones=4), pods + [pod(f"follower-{i}", "f", beside) for i in range(3)]
+
+
+def _mixed_cross_pod():
+    from kubernetes_tpu.tools import paritycheck as pc
+
+    return pc._basic_nodes(32, zones=4), pc._cross_pod_pods(96)
+
+
+WAVE_DRAINS = {"followers-and-repellers": (_followers_and_repellers, {}),
+               "mixed-cross-pod-chained": (_mixed_cross_pod, {"batch_size": 16})}
+
+
+@pytest.fixture(scope="module", params=sorted(WAVE_DRAINS))
+def wave_drain(request):
+    """(the scheduler after one drain on the wave path, the drain's name)."""
+    from kubernetes_tpu.tools import paritycheck as pc
+
+    build, cfg_kw = WAVE_DRAINS[request.param]
+    _got, s = pc._drain(*build(), return_sched=True, mesh_dispatch=False, **cfg_kw)
+    assert s.metrics["wave_batches"] >= 1
+    return s, request.param
+
+
+def test_wave_resolve_books_each_conflict_kind_beside_the_prometheus_counter(wave_drain):
+    from kubernetes_tpu.ops.wave import DEMOTE_KINDS
+
+    s, _name = wave_drain
+    phases = s.phases.snapshot()
+    booked = {k[len("wave.conflicts."):]: v for k, v in phases.items() if k.startswith("wave.conflicts.")}
+    assert booked and set(booked) <= set(DEMOTE_KINDS.values())
+    assert booked == {k: n for k in DEMOTE_KINDS.values() if (n := s.prom.wave_conflicts.value(kind=k))}
+
+
+def test_the_conflict_kinds_sum_to_the_demotions_less_the_upgrades(wave_drain):
+    """An upgrade (infeasible alone, placed once a wave peer committed) is a
+    demotion — the admitted node is not the speculated one — and no conflict."""
+    s, name = wave_drain
+    phases = s.phases.snapshot()
+    events = [e["kind"] for e in s.flight.tail(100000)]
+    upgrades = events.count("wave_upgraded")
+    conflicts = sum(v for k, v in phases.items() if k.startswith("wave.conflicts."))
+    assert conflicts == phases["wave.demoted"] - upgrades == events.count("wave_demoted") > 0
+    if name == "followers-and-repellers":
+        assert (upgrades, phases["wave.conflicts.affinity"]) == (3, 1)
+
+
+def test_a_conflict_kind_is_a_count_no_span_and_no_histogram_and_diff_carries_it(wave_drain):
+    """A span books a histogram observation an event; a count books none."""
+    s, _name = wave_drain
+    phases = s.phases.snapshot()
+    kinds = [k for k in phases if k.startswith("wave.conflicts.")]
+    for name in kinds + ["wave.demoted"]:
+        assert s.phases.hist.count(phase=name) == 0
+    assert s.phases.hist.count(phase="wave_resolve") >= 1  # the interval around them is a span
+    # what the benchmark's ``phases`` line holds: a kind that fired, whole; one
+    # that did not is absent, as every zero is
+    window = PhaseAccumulator.diff(phases, {k: 0.0 for k in phases})
+    assert {k: window[k] for k in kinds} == {k: phases[k] for k in kinds}
+    assert not [k for k in window if k.startswith("wave.conflicts.") and not window[k]]
+
+
+# ---------------------------------------------------------------------------
 # stage names on the device ops (jax.named_scope: metadata only)
 # ---------------------------------------------------------------------------
 
