@@ -1752,8 +1752,9 @@ class ShapeEngine:
         if libroot == "jax" and sub == "random":
             return self._random_call(node, name, env, base)
         if libroot == "jax" and sub == "tree_util":
-            for a in node.args:
-                self.eval(a, env, base)
+            vals = [self.eval(a, env, base) for a in node.args]
+            if name == "tree_map" and len(vals) == 2 and not node.keywords:
+                return self._tree_map(node, vals[0], vals[1], base)
             return UNKNOWN
         if libroot == "jax":
             if name == "vmap":
@@ -1765,6 +1766,20 @@ class ShapeEngine:
             return UNKNOWN
         # jnp.* / np.*
         return self._jnp_call(node, name, env, base)
+
+    def _tree_map(self, node, fn, tree, base):
+        """``tree_map(fn, tree)`` over ONE tree of arrays: ``fn`` applied
+        leaf by leaf, the container kept (a record stays its class)."""
+        if isinstance(tree, Arr):
+            return self._call_value(node, fn, [tree], {}, base)
+        if isinstance(tree, TupV):
+            return TupV([self._tree_map(node, fn, it, base) for it in tree.items])
+        if isinstance(tree, RecV):
+            return RecV(tree.cls, {
+                k: self._tree_map(node, fn, it, base)
+                for k, it in tree.fields.items()
+            })
+        return UNKNOWN
 
     def _keyword(self, node, name):
         for kw in node.keywords:

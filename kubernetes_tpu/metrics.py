@@ -672,6 +672,16 @@ class SchedulerMetrics:
                 ("kind",),
             )
         )
+        self.wave_static_signatures = r.register(
+            Counter(
+                "scheduler_tpu_wave_static_signatures_total",
+                "Distinct pod rows a wave dispatch computed its cross-pod "
+                "statics for (gang.precompute by signature), summed over "
+                "the wave batches that ran with the table; a batch with "
+                "more distinct rows than the bucket, or with host-plugin "
+                "vetoes, computes them per pod and adds nothing.",
+            )
+        )
         self.wave_fallback = r.register(
             Counter(
                 "scheduler_tpu_wave_fallback_total",

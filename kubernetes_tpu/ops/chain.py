@@ -79,6 +79,7 @@ def caps_compatible(dc_shapes, pb) -> bool:
 # ktpu: axes(tid_sp=i32[P,C], rep_sp_p=i32[Tsp], rep_sp_c=i32[Tsp])
 # ktpu: axes(tid_ip=i32[P,A], rep_ip_p=i32[Tip], rep_ip_u=i32[Tip], ip_cdv_tab=i32[Kd2,N])
 # ktpu: axes(tid_pt=i32[P,UP], port_conf=bool[Tpt,Tpt])
+# ktpu: axes(sig=i32[P], rep_pod=i32[U])
 # ktpu: accum(i64, i32, bool)
 # ktpu: static(v_cap=16)
 # ktpu: noinstantiate — donates and splices the cluster at host-checked
@@ -139,6 +140,8 @@ def chain_dispatch(
     wave_ports: bool = False,
     tid_pt=None,
     port_conf=None,
+    sig=None,
+    rep_pod=None,
 ):
     """One fused dispatch: gang schedule the batch, then append its
     committed pods into the (donated) cluster at the given cursors.
@@ -161,6 +164,10 @@ def chain_dispatch(
     wave call signature uniform and is the landing slot for a future
     port-row splice.
 
+    ``sig`` / ``rep_pod`` (wave.static_signatures; None = every pod its
+    own row, the per-pod program): the statics are computed once a
+    distinct pod row and read per pod (gang.precompute).
+
     Returns (next_dc, stacked [2, P] (chosen, n_feas), reason_counts
     [, wave_stats])."""
     g = gang.precompute(
@@ -179,6 +186,8 @@ def chain_dispatch(
         sp_keys=sp_keys,
         sp_cdv_tab=sp_cdv_tab,
         ip_keys=ip_keys,
+        sig=sig,
+        rep_pod=rep_pod,
     )
     wave_stats = None
     if wave:

@@ -284,14 +284,29 @@ def precompute(
     sp_keys=None,
     sp_cdv_tab=None,
     ip_keys=None,
+    sig=None,
+    rep_pod=None,
 ) -> GangStatics:
     """When a has_* flag is False the corresponding statics are built with a
     ZERO-width constraint axis; the scan step's reductions over that axis
     vanish at compile time (the PreFilter-Skip of the gang path — shape-
     driven rather than flag-plumbed).  ``enabled`` reflects the profile's
     Filter plugin set.  sp_keys/sp_cdv_tab/ip_keys come from batch_tables();
-    they are required whenever the matching has_* flag is set."""
+    they are required whenever the matching has_* flag is set.
+
+    ``sig`` i32 [P] / ``rep_pod`` i32 [U] (wave.static_signatures): the
+    statics are computed for the batch's U representative rows instead of
+    its P pods and read back per pod through ``sig`` — every field is a
+    function of (dc, the pod's own row; the peer's row too on a trailing
+    batch axis), so the result is the same integers.  ``extra_mask`` is
+    per pod by nature: with it the table is not used."""
+    if extra_mask is not None:
+        sig = None
     with jax.named_scope("ktpu/gang/precompute"):
+        if sig is not None:
+            # padding entries (-1) read row 0; no pod's sig points at them
+            rows = jnp.maximum(rep_pod, 0)
+            db = jax.tree_util.tree_map(lambda x: x[rows], db)
         P = db.valid.shape[0]
         N = dc.node_valid.shape[0]
         tolerated = F._tolerated(dc, db)
@@ -496,7 +511,7 @@ def precompute(
         else:
             sc_image = jnp.zeros((P, N), I64)
 
-        return GangStatics(
+        g = GangStatics(
             static_mask=static_mask,
             **sp,
             **ip,
@@ -511,6 +526,46 @@ def precompute(
             d_ports=d_ports,
             d_extra=d_extra,
         )
+        return g if sig is None else _statics_by_sig(g, sig)
+
+
+# GangStatics fields with no pod axis / with a trailing batch-peer axis
+_NO_POD_AXIS = ("ip_key_cols",)
+_PEER_AXIS = ("sp_bmatch", "ip_bmatch", "port_b")
+
+
+def _select_rows(x_u, sig, axis):
+    """``x_u`` indexed by ``sig`` along ``axis`` as a chain of selects over
+    the U rows: elementwise, so it fuses into what reads it.  (A gather
+    ``x_u[sig]`` is the same values; this chip's gathers are the ops this
+    table exists to remove.)"""
+    P = sig.shape[0]
+    sel = sig.reshape([P if a == axis else 1 for a in range(x_u.ndim)])
+    out = jnp.broadcast_to(
+        jax.lax.slice_in_dim(x_u, 0, 1, axis=axis),
+        [P if a == axis else n for a, n in enumerate(x_u.shape)],
+    )
+    for u in range(1, x_u.shape[axis]):
+        out = jnp.where(
+            sel == u, jax.lax.slice_in_dim(x_u, u, u + 1, axis=axis), out
+        )
+    return out
+
+
+def _statics_by_sig(g_u: GangStatics, sig) -> GangStatics:
+    """Expand [U, …] statics to [P, …]: each pod reads its signature's row
+    (and, on a trailing batch axis, each peer its signature's column)."""
+    out = {}
+    for name in GangStatics._fields:
+        x = getattr(g_u, name)
+        if name not in _NO_POD_AXIS:
+            x = _select_rows(x, sig, 0)
+            # a zero-width trailing axis is a compiled-out term axis
+            # (port_b [P, 0]), not a peer axis
+            if name in _PEER_AXIS and x.shape[-1]:
+                x = _select_rows(x, sig, x.ndim - 1)
+        out[name] = x
+    return GangStatics(**out)
 
 
 # ---------------------------------------------------------------------------

@@ -322,6 +322,69 @@ def wave_tables(pb, node_label_vals, hostname_id: int, hostnames_unique=None):
     )
 
 
+# The ONE bucket of distinct pod rows a batch's statics are computed for
+# (bucket_cap's minimum).  Not 1: a drain's last batch is padded, and the
+# padding rows (valid False) are a second row.  One bucket = at most one
+# more program a batch size; past it the dispatch is the per-pod program.
+STATIC_SIG_CAP = 8
+
+
+def batch_leaves(obj):
+    """Every numpy leaf of a packed batch, nested tables included, in
+    field order (the ``pods`` list is not an array and is skipped)."""
+    import dataclasses
+
+    import numpy as np
+
+    for f in dataclasses.fields(obj):
+        v = getattr(obj, f.name)
+        if isinstance(v, np.ndarray):
+            yield v
+        elif dataclasses.is_dataclass(v):
+            yield from batch_leaves(v)
+
+
+def static_signatures(pb, u_cap: int = STATIC_SIG_CAP):
+    """Dedup the batch's PODS by everything gang.precompute reads of one:
+    the pod's row of EVERY array leaf of the packed batch (not a
+    hand-picked list: a forgotten field is a wrong decision, one too many
+    only costs a signature).  Two pods with equal rows have equal statics,
+    so the device computes them for one representative row a signature.
+
+    Returns None when the batch has more than ``u_cap`` distinct rows
+    (the caller dispatches the per-pod program), else
+
+      sig      i32 [P]      each pod's distinct-row id
+      rep_pod  i32 [u_cap]  one representative pod a signature (-1 padded)
+      n_valid  int          distinct rows among the VALID pods
+    """
+    import numpy as np
+
+    valid = np.asarray(pb.valid, bool)
+    P = valid.shape[0]
+    # rows compared as BYTES, each leaf in its own dtype (one memcmp a
+    # pair; _dedup_slots' sorted i64 rows cost ten times as much, and a
+    # signature id, unlike a term id, is never shown to anyone)
+    rows = np.concatenate(
+        [
+            np.ascontiguousarray(a).reshape(P, -1).view(np.uint8)
+            for a in batch_leaves(pb)
+        ],
+        axis=1,
+    )
+    keys = rows.view(np.dtype((np.void, rows.shape[1]))).reshape(-1)
+    _, rep, sig = np.unique(keys, return_index=True, return_inverse=True)
+    if len(rep) > u_cap:
+        return None
+    rep_pod = np.full(u_cap, -1, np.int32)
+    rep_pod[: len(rep)] = rep
+    return dict(
+        sig=jnp.asarray(sig.astype(np.int32)),
+        rep_pod=jnp.asarray(rep_pod),
+        n_valid=len(np.unique(sig[valid])),
+    )
+
+
 def interaction_groups(pods):
     """Partition a batch into components of mutually-interacting pods by
     topology-term / affinity-probe footprint (fastpath-style host probes).
@@ -988,6 +1051,7 @@ def wave_schedule(
 # ktpu: axes(nom_node=i32[G], nom_prio=i32[G], nom_req=i32[G,Rn], extra_score=i64[P,N])
 # ktpu: axes(sp_keys=i32[Kd], sp_cdv_tab=i32[Kd,N], ip_keys=i32[Kd2])
 # ktpu: axes(sample_k=i32, sample_start=i32, tie_key=key, attempt_base=i32)
+# ktpu: axes(sig=i32[P], rep_pod=i32[U])
 # ktpu: accum(i64, i32, bool)
 # ktpu: static(v_cap=16)
 @functools.partial(
@@ -1042,12 +1106,15 @@ def wave_run(
     sample_start=None,
     tie_key=None,
     attempt_base=None,
+    sig=None,
+    rep_pod=None,
 ):
     """Fused precompute + wave: ONE device dispatch per batch (the wave
     counterpart of gang.gang_run).  The gang scan's pod×pod port matrix
     stays compiled out (precompute has_ports=False): in-batch host ports
     ride the factored [Tpt, N] occupancy carry instead (``has_ports`` here
-    gates THAT carry)."""
+    gates THAT carry).  ``sig`` / ``rep_pod``: the statics by distinct pod
+    row (static_signatures; not used with ``extra_mask``, which is per pod)."""
     g = gang.precompute(
         dc,
         db,
@@ -1063,6 +1130,8 @@ def wave_run(
         sp_keys=sp_keys,
         sp_cdv_tab=sp_cdv_tab,
         ip_keys=ip_keys,
+        sig=sig,
+        rep_pod=rep_pod,
     )
     return wave_schedule(
         dc,

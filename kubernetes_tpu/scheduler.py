@@ -1792,6 +1792,16 @@ class Scheduler:
                 extra_mask, host_diags, host_plugin_sets = self._host_filter_mask(
                     fwk, state, pods, p_cap, db=db, enabled=enabled
                 )
+            # the statics by distinct pod row (gang.precompute's table) —
+            # host-plugin vetoes are per pod, so a batch that carries
+            # them, like one with too many distinct rows, takes the
+            # per-pod program
+            ss = None
+            if wt is not None and extra_mask is None:
+                ss = self._static_signatures(pb)
+            sig_kw = (
+                dict(sig=ss["sig"], rep_pod=ss["rep_pod"]) if ss else {}
+            )
 
             # 1b'. host-backed Score plugins → pre-weighted additive [P, N]
             # matrix merged into the device selection (the RunScorePlugins
@@ -1875,6 +1885,7 @@ class Scheduler:
                         sample_start=sample_start,
                         tie_key=tie_key,
                         attempt_base=attempt_base,
+                        **sig_kw,
                         **shared_kw,
                     )
                 )
@@ -1928,7 +1939,13 @@ class Scheduler:
         wave_groups = None
         if wstats_dev is not None:
             wave_groups = self._wave_resolve(
-                fwk, batch, chosen, wstats_dev, self.mirror.e_used, kernel=kroot
+                fwk,
+                batch,
+                chosen,
+                wstats_dev,
+                self.mirror.e_used,
+                kernel=kroot,
+                static_sigs=ss["n_valid"] if ss else None,
             )
         self._process_results(
             fwk,
@@ -2551,6 +2568,7 @@ class Scheduler:
                 else:
                     self.prom.wave_fallback.inc(reason="kill_switch")
             wave_kw = {}
+            ss = None
             if wt is not None:
                 wave_kw = dict(
                     wave=True,
@@ -2566,6 +2584,9 @@ class Scheduler:
                     tid_pt=wt["tid_pt"],
                     port_conf=wt["port_conf"],
                 )
+                ss = self._static_signatures(pb)
+                if ss is not None:
+                    wave_kw.update(sig=ss["sig"], rep_pod=ss["rep_pod"])
             t0 = time.perf_counter()
             try:
                 out = chain_ops.chain_dispatch(
@@ -2634,6 +2655,7 @@ class Scheduler:
                 "results": results,
                 "reasons": reasons,
                 "wave_stats": wstats,
+                "static_sigs": ss["n_valid"] if ss else None,
                 "e_rows": ch["e"],
                 "t0": t0,
             }
@@ -2694,6 +2716,7 @@ class Scheduler:
                 wstats,
                 rec["e_rows"],
                 kernel="chain.chain_dispatch",
+                static_sigs=rec["static_sigs"],
             )
         self._process_results(
             rec["fwk"],
@@ -2834,6 +2857,36 @@ class Scheduler:
         )
         self._wave_tables_memo = (key, wt)
         return wt
+
+    def _static_signatures(self, pb):
+        """The batch's distinct pod rows for gang.precompute's table
+        (wave.static_signatures): {sig, rep_pod, n_valid}, or None when the
+        batch has more distinct rows than the one bucket — the dispatch is
+        then the per-pod program.
+
+        Memoized like _wave_tables, under a digest of its own over EVERY
+        leaf: a template-stamped drain repeats one content batch after
+        batch and keeps the two device arrays; a leaf the term tables do
+        not read (a toleration, a priority) misses here only.  (Leaf by
+        leaf: stacking the rows first costs three times the digest.)"""
+        import hashlib
+
+        import numpy as np
+
+        from kubernetes_tpu.ops import wave as wave_ops
+
+        h = hashlib.blake2b(digest_size=16)
+        shapes = []
+        for a in wave_ops.batch_leaves(pb):
+            shapes.append(a.shape)
+            h.update(np.ascontiguousarray(a).tobytes())
+        key = (tuple(shapes), h.digest())
+        cached = getattr(self, "_static_sigs_memo", None)
+        if cached is not None and cached[0] == key:
+            return cached[1]
+        ss = wave_ops.static_signatures(pb)
+        self._static_sigs_memo = (key, ss)
+        return ss
 
     # ----- the workloads tier: gang/coscheduling + DRA + volume topology ----
     #
@@ -3579,11 +3632,16 @@ class Scheduler:
             outcomes.append(outcome)
         sp_commit.end()
 
-    def _wave_resolve(self, fwk, batch, chosen, wstats_dev, e_rows, kernel=None):
+    def _wave_resolve(
+        self, fwk, batch, chosen, wstats_dev, e_rows, kernel=None, static_sigs=None
+    ):
         """Harvest one wave's speculation stats: admitted/demoted counters
         (``wave.demoted`` and, with ``e_rows`` — the existing-pod rows live
         at the dispatch — ``wave.epod_rows`` go to the phase accumulator,
-        as does each conflict kind as ``wave.conflicts.<kind>``),
+        as does each conflict kind as ``wave.conflicts.<kind>``;
+        ``static_sigs`` — the distinct valid pod rows the dispatch computed
+        its statics for — goes to ``wave.static_sigs``, and None, a
+        dispatch that computed them per pod, counts one ``wave.static_full``),
         a ``wave_demoted`` flight-recorder event (with the conflicting
         term) per corrected pod, and — when the framework permits lean
         binds — the interaction-group split the bulk commit path uses.
@@ -3638,6 +3696,11 @@ class Scheduler:
         for kind, cnt in conflicts.items():
             self.prom.wave_conflicts.inc(cnt, kind=kind)
             self.phases.count(f"wave.conflicts.{kind}", cnt)
+        if static_sigs is None:
+            self.phases.count("wave.static_full", 1)
+        else:
+            self.prom.wave_static_signatures.inc(static_sigs)
+            self.phases.count("wave.static_sigs", static_sigs)
         # Bulk-commit eligibility: lean_bind_ok()'s and the Reserve/Permit
         # "covered by host filters" no-op guarantees are BOTH conditioned
         # on the batch being spec-irrelevant to every host Filter plugin

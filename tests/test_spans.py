@@ -6,6 +6,7 @@ Everything runs on the CPU backend: a profiler session there has the host
 plane (where the program's spans land) and no device plane.
 """
 
+import collections
 import glob
 import os
 import re
@@ -401,6 +402,31 @@ def test_lowered_root_carries_its_stage_names(root, dispatched_specs):
     found = set(re.findall(r"ktpu/[a-z_]+/[a-z_]+", text))
     assert set(ROOT_STAGES[root]) <= found, sorted(set(ROOT_STAGES[root]) - found)
     assert found <= set(STAGES), sorted(found - set(STAGES))
+
+
+def test_the_statics_by_signature_add_no_op_outside_a_stage():
+    """``gang.precompute`` by signature (PR 38): the gather of the
+    representative rows and the expansion back to [P, …] are the first and
+    the last thing INSIDE ``ktpu/gang/precompute``, so the compiled program
+    with the table names no op outside a stage that the per-pod program does
+    not name too (its two arguments apart) and ``kernels.scoped_share``
+    stays where it was."""
+    from tests.test_statics_signatures import lowered_wave_runs
+
+    plain, _, tabled = lowered_wave_runs()
+
+    def op_names(lowered):
+        return collections.Counter(re.findall(r'op_name="([^"]*)"', lowered.compile().as_text()))
+
+    per_pod, by_sig = op_names(plain), op_names(tabled)
+    unstaged = {n for n in by_sig if "ktpu/" not in n and n not in per_pod}
+    assert unstaged <= {"sig", "rep_pod"}, sorted(unstaged)
+
+    # the expansion itself is there, as selects under the stage
+    def selects(names):
+        return sum(k for n, k in names.items() if n.endswith("ktpu/gang/precompute/select_n"))
+
+    assert selects(by_sig) > selects(per_pod)
 
 
 # every root whose program holds gang.pod_step (scan, speculation, admission)

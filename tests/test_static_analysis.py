@@ -1053,6 +1053,28 @@ def test_shape_engine_negative_slice_bounds(tmp_path):
     assert dim_str(c.shape[0]) == "N-1"
 
 
+def test_shape_engine_maps_a_lambda_over_the_leaves_of_one_tree(tmp_path):
+    """``jax.tree_util.tree_map(fn, tree)`` over ONE tuple of arrays is
+    ``fn`` leaf by leaf (gang.precompute gathers the batch's representative
+    rows that way): a row gather by ``rows`` [U] swaps each leaf's leading
+    axis and keeps the others, instead of turning the whole tree unknown."""
+    from kubernetes_tpu.analysis.core import SourceModule
+    from kubernetes_tpu.analysis.shape import ShapeEngine, dim_str
+
+    p = tmp_path / "rows.py"
+    p.write_text(
+        "import jax\nimport jax.numpy as jnp\n"
+        "# ktpu: axes(a=i32[P,N], b=bool[P], rows=i32[U])\n"
+        "@jax.jit\n"
+        "def take(a, b, rows):\n"
+        "    return jax.tree_util.tree_map(lambda x: x[rows], (a, b))\n"
+    )
+    eng = ShapeEngine().run([SourceModule.load(str(p))])
+    a, b = eng.root_returns["rows.take"].items
+    assert [dim_str(d) for d in a.shape] == ["U", "N"] and a.dtype == "i32"
+    assert [dim_str(d) for d in b.shape] == ["U"] and b.dtype == "bool"
+
+
 def test_accum_contract_not_erased_by_summary_reuse(tmp_path):
     """Review regression: a helper first analyzed under a contract-free
     root must still report its float carry when reached from a root
