@@ -394,6 +394,19 @@ class PhaseAccumulator:
     keeps the overhead unmeasurable next to the phases themselves.
     ``snapshot`` returns a plain dict, so a caller can diff two snapshots
     around a window.
+
+    ``span(..., off_cpu=True)`` (those of the scheduling loop's spans that
+    a metric reads it from, ``Scheduler._OFF_CPU_SPANS``) also reads the
+    thread's CPU clock and books the count ``<phase>.off_cpu`` = wall -
+    thread CPU: the seconds of the interval in which the thread did not
+    run.  For a span that blocks on nothing by design that is time spent
+    runnable but not running (the interpreter lock held by another thread,
+    a contended mutex, the OS).  A total, not a timeline, and not split by
+    cause.  Booked SIGNED, not clipped at 0 span by span: where the thread
+    CPU clock advances in ticks (10 ms under gVisor) one span reads a tick
+    too many or too few, and only the unclipped sum lets those errors
+    cancel.  So a phase that never waits totals within a tick of 0, either
+    side, and ``diff`` carries a window's negative delta as it is.
     """
 
     def __init__(self, hist: Optional[Histogram] = None):
@@ -405,13 +418,17 @@ class PhaseAccumulator:
         # recording thread's track — one hook covers all dispatch paths
         self.tracer = None
 
-    def add(self, phase: str, dt: float) -> None:
+    def add(self, phase: str, dt: float, off_cpu: Optional[float] = None) -> None:
         """Book ``dt`` seconds that ended now.  ``span`` ends here; called
         directly only for an interval no one thread spans (a bind slice's
         wait in the pool's queue: submitted by the loop, picked up by a
-        worker)."""
+        worker).  ``off_cpu`` (a span that read the thread's CPU clock)
+        goes to the count ``<phase>.off_cpu`` under the same acquisition."""
         with self._mu:
             self._totals[phase] = self._totals.get(phase, 0.0) + dt
+            if off_cpu is not None:
+                key = phase + ".off_cpu"
+                self._totals[key] = self._totals.get(key, 0.0) + off_cpu
             if self.hist is not None:
                 self.hist.observe(dt, phase=phase)
         tr = self.tracer
@@ -425,12 +442,13 @@ class PhaseAccumulator:
         with self._mu:
             self._totals[name] = self._totals.get(name, 0.0) + n
 
-    def span(self, phase: str, **ctx) -> "PhaseSpan":
+    def span(self, phase: str, off_cpu: bool = False, **ctx) -> "PhaseSpan":
         """The interval ``phase``, as a context manager, or opened with
         ``.begin()`` and closed with ``.end()`` where the interval does not
         sit in one block.  ``ctx`` (batch id, pod count) goes to the
-        profiler's annotation only."""
-        return PhaseSpan(self, phase, ctx)
+        profiler's annotation only.  ``off_cpu``: also book the count
+        ``<phase>.off_cpu`` (begun and ended on one thread)."""
+        return PhaseSpan(self, phase, ctx, off_cpu)
 
     def snapshot(self) -> Dict[str, float]:
         with self._mu:
@@ -438,10 +456,13 @@ class PhaseAccumulator:
 
     @staticmethod
     def diff(after: Dict[str, float], before: Dict[str, float]) -> Dict[str, float]:
+        """What the window between two snapshots booked; a key that did
+        not move is left out.  Seconds and counts only grow; a signed
+        ``.off_cpu`` total may fall, and its delta is kept."""
         out = {}
         for k, v in after.items():
             d = v - before.get(k, 0.0)
-            if d > 0.0:
+            if d != 0.0:
                 out[k] = d
         return out
 
@@ -467,23 +488,35 @@ def annotation(name: str, **ctx) -> Annotation:
 
 
 class PhaseSpan:
-    __slots__ = ("acc", "phase", "_ann", "_t0")
+    __slots__ = ("acc", "phase", "_ctx", "_ann", "_t0", "_cpu0")
 
-    def __init__(self, acc: PhaseAccumulator, phase: str, ctx: dict):
+    def __init__(
+        self, acc: PhaseAccumulator, phase: str, ctx: dict, off_cpu: bool = False
+    ):
         self.acc = acc
         self.phase = phase
-        self._ann = annotation(phase, **ctx)
+        self._ctx = ctx
+        self._cpu0 = 0.0 if off_cpu else None
 
     def begin(self) -> "PhaseSpan":
-        self._ann.begin()
+        # made here, not with the span: a profiler annotation's interval
+        # opens where it is constructed
+        self._ann = annotation(self.phase, **self._ctx).begin()
+        # the CPU clock is read OUTSIDE the wall clock's interval at both
+        # ends: the reads' own cost never shows as off-CPU time
+        if self._cpu0 is not None:
+            self._cpu0 = time.thread_time()
         self._t0 = time.perf_counter()
         return self
 
     def end(self) -> float:
         """Close and book the interval; returns its seconds."""
         dt = time.perf_counter() - self._t0
+        off = None
+        if self._cpu0 is not None:
+            off = dt - (time.thread_time() - self._cpu0)
         self._ann.end()
-        self.acc.add(self.phase, dt)
+        self.acc.add(self.phase, dt, off)
         return dt
 
     __enter__ = begin
@@ -756,18 +789,13 @@ class SchedulerMetrics:
                 "Bytes copied device→host by blocking result fetches.",
             )
         )
-        self.snapshot_pack_duration = r.register(
-            Histogram(
-                "scheduler_tpu_snapshot_pack_duration_seconds",
-                "Host time packing the incremental snapshot mirror.",
-                (),
-            )
-        )
         self.phase_duration = r.register(
             Histogram(
                 "scheduler_tpu_phase_duration_seconds",
                 "Per-batch hot-loop time by phase (queue_pop/pack/h2d/"
-                "device/d2h/wave_resolve/resident_rounds/commit/bind).",
+                "device/d2h/wave_resolve/resident_rounds/commit/bind, "
+                "chain_dispatch and the parts of it and of commit: "
+                "chain_dispatch.pack, commit.assume, ...).",
                 ("phase",),
             )
         )

@@ -1133,9 +1133,34 @@ class Scheduler:
             and not fwk.reserve_permit_covered_by_host_filters()
         )
 
+    # The loop's spans whose off-CPU seconds a per-layer metric reads: the
+    # top-level ones that block on nothing by design (loop.off_cpu_s_per_kpod;
+    # top-level, so nothing nested is counted twice) and the two parts of
+    # chain_dispatch that hand the interpreter lock away
+    # (loop.chain_prep_off_cpu_s_per_kpod.<part>).  A span around a wait
+    # (device, d2h, a lock_wait) would book its own wall.
+    _OFF_CPU_SPANS = frozenset(
+        (
+            "queue_pop",
+            "chain_dispatch",
+            "pack",
+            "h2d",
+            "commit",
+            "wave_resolve",
+            "resident_rounds",
+            "flush_binds",
+            "chain_dispatch.h2d",
+            "chain_dispatch.release",
+        )
+    )
+
     def _span(self, phase: str):
-        """A loop-thread phase span carrying the current batch id."""
-        return self.phases.span(phase, bid=self._bid)
+        """A loop-thread phase span carrying the current batch id; one of
+        ``_OFF_CPU_SPANS`` also books ``<phase>.off_cpu``, the seconds of it
+        the thread did not run (``PhaseAccumulator``)."""
+        return self.phases.span(
+            phase, off_cpu=phase in self._OFF_CPU_SPANS, bid=self._bid
+        )
 
     def _trace_dispatch(self, kind: str, t0: float, batch, rec=None) -> int:
         """Stamp a monotonically-increasing batch id and — when tracing —
@@ -1699,13 +1724,8 @@ class Scheduler:
 
             # scan path: bring the full mirror (usage tensors included) up
             # to date — its kernels read requested/num_pods per node.
-            t_pack = time.perf_counter()
             with self._span("pack"):
                 self._repack_mirror()
-                self.prom.recorder.observe(
-                    self.prom.snapshot_pack_duration,
-                    time.perf_counter() - t_pack,
-                )
             trace.step("Snapshot mirror updated")
 
             self._p_cap_max = max(self._p_cap_max, self._p_bucket(len(pods)))
@@ -1717,7 +1737,6 @@ class Scheduler:
                 p_cap=p_cap,
                 namespace_labels=self.namespace_labels,
             )
-            t_sync = time.perf_counter()
             sp_h2d = self._span("h2d").begin()
             from kubernetes_tpu.observability import kernels as kernels_mod
 
@@ -1729,11 +1748,6 @@ class Scheduler:
                 self._note_dispatch_failure(e)
                 return outcomes + self._schedule_batch_serial(fwk, batch)
             db = self._place_db(DeviceBatch.from_host(pb))
-            self.prom.recorder.observe(
-                self.prom.snapshot_pack_duration,
-                time.perf_counter() - t_sync,
-                phase="device_sync",
-            )
             sp_h2d.end()
             v_cap = bucket_cap(len(vocab.label_vals))
             hostname_key = self._hostname_dev(vocab)
@@ -2321,11 +2335,7 @@ class Scheduler:
             getattr(self, "_nonfast_commits", 0),
         )
         if self.mirror.nodes is None or getattr(self, "_mirror_sync", None) != sync:
-            t0 = time.perf_counter()
             self._repack_mirror()
-            self.prom.recorder.observe(
-                self.prom.snapshot_pack_duration, time.perf_counter() - t0
-            )
 
     def _pod_sig_key(self, pod, params, lanes_box):
         """signature_key for one pod, memoized twice over: ON the pod object
@@ -2382,64 +2392,97 @@ class Scheduler:
             append(k)
         return keys
 
+    def _chain_restart(self, vocab, epoch):
+        """(Re)start the chain from the host mirror (the pipeline is settled,
+        so its tensors are the ground truth).  Returns the new chain, or
+        None on a persistent placement failure (hbm_oom class): the caller
+        bails to the direct path, which owns the serial fallback."""
+        from kubernetes_tpu.observability import kernels as kernels_mod
+
+        with self._span("chain_dispatch.sync"):
+            try:
+                dc = self._sync_device_cluster(vocab)
+            except kernels_mod.DispatchFailed as e:
+                self._note_dispatch_failure(e)
+                return None
+            # the chain will donate/diverge these buffers — the delta
+            # cache must not touch them again
+            self._dc_cache.invalidate()
+        return {
+            "dc": dc,
+            "e": self.mirror.e_used,
+            "m": self.mirror.m_used,
+            "epoch": epoch,
+        }
+
     def _try_dispatch_chained(self, fwk, batch, outcomes, can_restart: bool):
         """Dispatch the batch on the chained device cluster.  Returns a
         pending record (dict), "handled" (nothing left to schedule),
         "flush" (pipeline must settle before the chain can restart), or
-        None (fall back to the direct path)."""
+        None (fall back to the direct path).
+
+        The caller's ``chain_dispatch`` span is divided into consecutive,
+        disjoint parts (``chain_dispatch.lock_wait``, ``.pack``, ``.repack``,
+        ``.sync``, ``.prefilter``, ``.h2d``, ``.tables``, ``.submit``,
+        ``.release``); the capacity checks and the record stay outside them.
+        ``release`` is ``_dispatch_chained``'s return once a dispatch went
+        out: ``Scheduler._mu`` released, then the frame's teardown dropping
+        the batch's locals.  Deleting a device array that the dispatch in
+        flight reads (the ``DeviceBatch``'s) hands the interpreter lock
+        away, and beside binding the wait to win it back is most of the
+        interval."""
+        sp_release = self._span("chain_dispatch.release")
+        rec = self._dispatch_chained(fwk, batch, outcomes, can_restart, sp_release)
+        if isinstance(rec, dict):
+            sp_release.end()
+        return rec
+
+    def _dispatch_chained(self, fwk, batch, outcomes, can_restart, sp_release):
+        """``_try_dispatch_chained``'s body, in a frame of its own so that
+        its teardown can be spanned: ``sp_release`` is begun at the return
+        of a pending record, and only there."""
         from kubernetes_tpu.observability import kernels as kernels_mod
         from kubernetes_tpu.ops import chain as chain_ops
 
+        sp_lock = self._span("chain_dispatch.lock_wait").begin()
         with self._mu:
-            vocab = self.mirror.vocab
-            for qp in batch:
-                for k, v in qp.pod.labels.items():
-                    vocab.intern_label(k, v)
-            epoch = self._chain_epoch(vocab)
-            ch = getattr(self, "_chain", None)
-            if (ch is None or ch["epoch"] != epoch) and not can_restart:
-                return "flush"
+            sp_lock.end()
+            with self._span("chain_dispatch.pack"):
+                vocab = self.mirror.vocab
+                for qp in batch:
+                    for k, v in qp.pod.labels.items():
+                        vocab.intern_label(k, v)
+                epoch = self._chain_epoch(vocab)
+                ch = getattr(self, "_chain", None)
+                if (ch is None or ch["epoch"] != epoch) and not can_restart:
+                    return "flush"
 
             # ---- side-effect-free preparation: every bail-out below must
             # happen BEFORE PreFilter runs (its failures mutate outcomes/
             # queue/nominator and must not be replayed by the direct path)
-            t_pack = time.perf_counter()
-            self._repack_mirror()
-            pods = [qp.pod for qp in batch]
-            self._p_cap_max = max(self._p_cap_max, self._p_bucket(len(pods)))
-            pb = pack_pod_batch(
-                pods,
-                vocab,
-                k_cap=self.mirror.nodes.k_cap,
-                p_cap=self._p_cap_max,
-                namespace_labels=self.namespace_labels,
-            )
-            epoch = self._chain_epoch(vocab)  # interning may have grown it
-            ch = getattr(self, "_chain", None)
+            with self._span("chain_dispatch.repack"):
+                self._repack_mirror()
+            with self._span("chain_dispatch.pack"):
+                pods = [qp.pod for qp in batch]
+                self._p_cap_max = max(self._p_cap_max, self._p_bucket(len(pods)))
+                pb = pack_pod_batch(
+                    pods,
+                    vocab,
+                    k_cap=self.mirror.nodes.k_cap,
+                    p_cap=self._p_cap_max,
+                    namespace_labels=self.namespace_labels,
+                )
+                epoch = self._chain_epoch(vocab)  # interning may have grown it
+                ch = getattr(self, "_chain", None)
             if ch is None or ch["epoch"] != epoch:
                 if not can_restart:
                     # packing interned new vocab (epoch moved) — the
                     # pipeline must settle before a host-state restart
                     return "flush"
-                # (re)start: the host mirror is current (pipeline settled —
-                # can_restart) so its tensors are the ground truth.  A
-                # persistent placement failure (hbm_oom class) bails to
-                # the direct path, which owns the serial fallback — this
-                # is still the side-effect-free prep, so None is safe.
-                try:
-                    dc = self._sync_device_cluster(vocab)
-                except kernels_mod.DispatchFailed as e:
-                    self._note_dispatch_failure(e)
+                # this is still the side-effect-free prep, so None is safe
+                ch = self._chain_restart(vocab, epoch)
+                if ch is None:
                     return None
-                # the chain will donate/diverge these buffers — the delta
-                # cache must not touch them again
-                self._dc_cache.invalidate()
-                ch = {
-                    "dc": dc,
-                    "e": self.mirror.e_used,
-                    "m": self.mirror.m_used,
-                    "epoch": epoch,
-                }
             # capacity/width checks against the CHAINED cluster's own
             # tensors — the live host mirror may have repacked to different
             # buckets mid-chain
@@ -2473,155 +2516,149 @@ class Scheduler:
                 )
                 self.mirror._epod_slots = None  # full existing repack
                 self.mirror._existing_version = -1
-                try:
-                    dc = self._sync_device_cluster(vocab)
-                except kernels_mod.DispatchFailed as e:
-                    self._note_dispatch_failure(e)
+                ch = self._chain_restart(vocab, epoch)
+                if ch is None:
                     return None  # direct path owns the serial fallback
-                self._dc_cache.invalidate()
-                ch = {
-                    "dc": dc,
-                    "e": self.mirror.e_used,
-                    "m": self.mirror.m_used,
-                    "epoch": epoch,
-                }
                 cdc = ch["dc"]
                 E = cdc.epod_node.shape[0]
                 M = cdc.term_pod.shape[0]
                 if ch["e"] + P > E or ch["m"] + P * AT > M:
                     return None  # genuinely beyond capacity — direct path
-            self.prom.recorder.observe(
-                self.prom.snapshot_pack_duration, time.perf_counter() - t_pack
-            )
 
             # ---- PreFilter (side effects OK now: the dispatch is certain)
-            state = CycleState()
-            pf_failures = fwk.run_pre_filter(state, [qp.pod for qp in batch])
+            with self._span("chain_dispatch.prefilter"):
+                state = CycleState()
+                pf_failures = fwk.run_pre_filter(state, [qp.pod for qp in batch])
+                if pf_failures:
+                    live = []
+                    for qp in batch:
+                        s = pf_failures.get(qp.pod.uid)
+                        if s is None:
+                            live.append(qp)
+                            continue
+                        self.metrics["schedule_attempts"] += 1
+                        outcomes.append(
+                            self._post_filter_or_fail(fwk, state, qp, s, 0)
+                        )
+                    batch = live
+                    if not batch:
+                        return "handled"
             if pf_failures:
-                live = []
-                for qp in batch:
-                    s = pf_failures.get(qp.pod.uid)
-                    if s is None:
-                        live.append(qp)
-                        continue
-                    self.metrics["schedule_attempts"] += 1
-                    outcomes.append(
-                        self._post_filter_or_fail(fwk, state, qp, s, 0)
-                    )
-                batch = live
-                if not batch:
-                    return "handled"
                 # repack without the rejected pods (their rows must not
                 # reach the device as schedulable entries)
-                pods = [qp.pod for qp in batch]
-                pb = pack_pod_batch(
-                    pods,
-                    vocab,
-                    k_cap=self.mirror.nodes.k_cap,
-                    p_cap=self._p_cap_max,
-                    namespace_labels=self.namespace_labels,
-                )
-                append_terms = bool((pb.aff_kind != PAD).any())
-                AT = pb.aff_kind.shape[1] if append_terms else 0
+                with self._span("chain_dispatch.pack"):
+                    pods = [qp.pod for qp in batch]
+                    pb = pack_pod_batch(
+                        pods,
+                        vocab,
+                        k_cap=self.mirror.nodes.k_cap,
+                        p_cap=self._p_cap_max,
+                        namespace_labels=self.namespace_labels,
+                    )
+                    append_terms = bool((pb.aff_kind != PAD).any())
+                    AT = pb.aff_kind.shape[1] if append_terms else 0
 
-            db = self._place_db(DeviceBatch.from_host(pb))
-            v_cap = bucket_cap(len(vocab.label_vals))
-            tables = self._gang_tables(pb, vocab)
-            nom_node = nom_prio = nom_req = None
-            if len(self.nominator):
-                nom_node, nom_prio, nom_req = self._nominated_arrays(
-                    {qp.pod.uid for qp in batch}
+            with self._span("chain_dispatch.h2d"):
+                db = self._place_db(DeviceBatch.from_host(pb))
+            with self._span("chain_dispatch.tables"):
+                v_cap = bucket_cap(len(vocab.label_vals))
+                tables = self._gang_tables(pb, vocab)
+                nom_node = nom_prio = nom_req = None
+                if len(self.nominator):
+                    nom_node, nom_prio, nom_req = self._nominated_arrays(
+                        {qp.pod.uid for qp in batch}
+                    )
+                # any term row in the chained cluster (host rows OR device-
+                # appended ones, which ch["m"] counts past) keeps interpod on
+                has_interpod = bool((pb.aff_kind != PAD).any()) or ch["m"] > 0
+                has_spread = bool((pb.tsc_topo_key != PAD).any())
+                has_images = bool((pb.img_ids >= 0).any())
+                has_ports = bool(
+                    (pb.want_ppk != PAD).any()
+                    or (self.mirror.nodes.used_ppk != PAD).any()
                 )
-            # any term row in the chained cluster (host rows OR device-
-            # appended ones, which ch["m"] counts past) keeps interpod on
-            has_interpod = bool((pb.aff_kind != PAD).any()) or ch["m"] > 0
-            has_spread = bool((pb.tsc_topo_key != PAD).any())
-            has_images = bool((pb.img_ids >= 0).any())
-            has_ports = bool(
-                (pb.want_ppk != PAD).any()
-                or (self.mirror.nodes.used_ppk != PAD).any()
-            )
-            enabled = fwk.device_enabled()
-            weights = tuple(
-                fwk.score_weights.get(n, 0) for n in gang.WEIGHT_ORDER
-            )
-            fit_strategy = fwk.fit_strategy()
-            # cross-pod-constraint batches ride the speculative wave
-            # inside the chained dispatch (same self-append, wave
-            # scheduling) — computed from the FINAL pb (post-PreFilter
-            # repack).  Port batches never reach here (_chain_quickcheck
-            # refuses them: the device append doesn't splice port rows),
-            # so the want_ppk arm and the wave_ports pass-through below
-            # are inert today — kept so the wave surface stays uniform
-            # with the direct path.
-            wave_shaped = bool(
-                (pb.aff_kind != PAD).any()
-                or (pb.tsc_topo_key != PAD).any()
-                or (pb.want_ppk != PAD).any()
-            )
-            wt = None
-            if wave_shaped:
-                if self.config.wave_dispatch:
-                    wt = self._wave_tables(pb)
-                    if wt is None:
-                        self.prom.wave_fallback.inc(reason="dup_hostname")
-                else:
-                    self.prom.wave_fallback.inc(reason="kill_switch")
-            wave_kw = {}
-            ss = None
-            if wt is not None:
-                wave_kw = dict(
-                    wave=True,
-                    tid_sp=wt["tid_sp"],
-                    rep_sp_p=wt["rep_sp_p"],
-                    rep_sp_c=wt["rep_sp_c"],
-                    tid_ip=wt["tid_ip"],
-                    rep_ip_p=wt["rep_ip_p"],
-                    rep_ip_u=wt["rep_ip_u"],
-                    ip_cdv_tab=wt["ip_cdv_tab"],
-                    d2_cap=wt["d2_cap"],
-                    wave_ports=wt["has_ports"],
-                    tid_pt=wt["tid_pt"],
-                    port_conf=wt["port_conf"],
+                enabled = fwk.device_enabled()
+                weights = tuple(
+                    fwk.score_weights.get(n, 0) for n in gang.WEIGHT_ORDER
                 )
-                ss = self._static_signatures(pb)
-                if ss is not None:
-                    wave_kw.update(sig=ss["sig"], rep_pod=ss["rep_pod"])
+                fit_strategy = fwk.fit_strategy()
+                # cross-pod-constraint batches ride the speculative wave
+                # inside the chained dispatch (same self-append, wave
+                # scheduling) — computed from the FINAL pb (post-PreFilter
+                # repack).  Port batches never reach here (_chain_quickcheck
+                # refuses them: the device append doesn't splice port rows),
+                # so the want_ppk arm and the wave_ports pass-through below
+                # are inert today — kept so the wave surface stays uniform
+                # with the direct path.
+                wave_shaped = bool(
+                    (pb.aff_kind != PAD).any()
+                    or (pb.tsc_topo_key != PAD).any()
+                    or (pb.want_ppk != PAD).any()
+                )
+                wt = None
+                if wave_shaped:
+                    if self.config.wave_dispatch:
+                        wt = self._wave_tables(pb)
+                        if wt is None:
+                            self.prom.wave_fallback.inc(reason="dup_hostname")
+                    else:
+                        self.prom.wave_fallback.inc(reason="kill_switch")
+                wave_kw = {}
+                ss = None
+                if wt is not None:
+                    wave_kw = dict(
+                        wave=True,
+                        tid_sp=wt["tid_sp"],
+                        rep_sp_p=wt["rep_sp_p"],
+                        rep_sp_c=wt["rep_sp_c"],
+                        tid_ip=wt["tid_ip"],
+                        rep_ip_p=wt["rep_ip_p"],
+                        rep_ip_u=wt["rep_ip_u"],
+                        ip_cdv_tab=wt["ip_cdv_tab"],
+                        d2_cap=wt["d2_cap"],
+                        wave_ports=wt["has_ports"],
+                        tid_pt=wt["tid_pt"],
+                        port_conf=wt["port_conf"],
+                    )
+                    ss = self._static_signatures(pb)
+                    if ss is not None:
+                        wave_kw.update(sig=ss["sig"], rep_pod=ss["rep_pod"])
             t0 = time.perf_counter()
-            try:
-                out = chain_ops.chain_dispatch(
-                    ch["dc"],
-                    db,
-                    self._hostname_dev(vocab),
-                    jnp.asarray(ch["e"], I32),
-                    jnp.asarray(ch["m"], I32),
-                    v_cap,
-                    has_interpod=has_interpod,
-                    has_spread=has_spread,
-                    has_ports=has_ports,
-                    has_images=has_images,
-                    enabled=enabled,
-                    weights=weights,
-                    nom_node=nom_node,
-                    nom_prio=nom_prio,
-                    nom_req=nom_req,
-                    append_terms=append_terms,
-                    fit_strategy=fit_strategy,
-                    **wave_kw,
-                    **tables,
-                )
-            except kernels_mod.DispatchFailed as e:
-                # the chained cluster was donated into the dead dispatch —
-                # drop the chain (the next batch rebuilds from the host
-                # mirror) and hand the LIVE batch back for the serial
-                # host-oracle fallback; nothing was committed, so the
-                # fallback is exact.  The serial drain itself runs in
-                # the caller OUTSIDE this lock — the snapshot-under-lock
-                # / replay-outside-lock discipline every other serial
-                # engine follows.
-                self._note_dispatch_failure(e)
-                self._chain = None
-                return ("serial", batch)
+            with self._span("chain_dispatch.submit"):
+                try:
+                    out = chain_ops.chain_dispatch(
+                        ch["dc"],
+                        db,
+                        self._hostname_dev(vocab),
+                        jnp.asarray(ch["e"], I32),
+                        jnp.asarray(ch["m"], I32),
+                        v_cap,
+                        has_interpod=has_interpod,
+                        has_spread=has_spread,
+                        has_ports=has_ports,
+                        has_images=has_images,
+                        enabled=enabled,
+                        weights=weights,
+                        nom_node=nom_node,
+                        nom_prio=nom_prio,
+                        nom_req=nom_req,
+                        append_terms=append_terms,
+                        fit_strategy=fit_strategy,
+                        **wave_kw,
+                        **tables,
+                    )
+                except kernels_mod.DispatchFailed as e:
+                    # the chained cluster was donated into the dead dispatch —
+                    # drop the chain (the next batch rebuilds from the host
+                    # mirror) and hand the LIVE batch back for the serial
+                    # host-oracle fallback; nothing was committed, so the
+                    # fallback is exact.  The serial drain itself runs in
+                    # the caller OUTSIDE this lock — the snapshot-under-lock
+                    # / replay-outside-lock discipline every other serial
+                    # engine follows.
+                    self._note_dispatch_failure(e)
+                    self._chain = None
+                    return ("serial", batch)
             if wt is not None:
                 dc2, results, reasons, wstats = out
             else:
@@ -2660,6 +2697,7 @@ class Scheduler:
                 "t0": t0,
             }
             self._trace_dispatch("wave" if wt is not None else "chain", t0, batch, rec)
+            sp_release.begin()
             return rec
 
     def _finish_chained(self, rec) -> List[ScheduleOutcome]:
@@ -5774,20 +5812,21 @@ class Scheduler:
         # values for the placement's whole lifetime.
         from kubernetes_tpu import fastpath as fp
 
-        req_by_spec: Dict[object, tuple] = {}
-        for qp_ in run:
-            pod = qp_.pod
-            d = pod.__dict__
-            if "_nzreq_memo" in d:
-                continue
-            sk = fp.spec_key_memo(pod)
-            rep = req_by_spec.get(sk) if sk is not None else None
-            if rep is None:
-                rep = (pod.compute_requests(), pod.non_zero_requests())
-                if sk is not None:
-                    req_by_spec[sk] = rep
-            else:
-                d["_req_memo"], d["_nzreq_memo"] = rep
+        with self._span("commit.requests"):
+            req_by_spec: Dict[object, tuple] = {}
+            for qp_ in run:
+                pod = qp_.pod
+                d = pod.__dict__
+                if "_nzreq_memo" in d:
+                    continue
+                sk = fp.spec_key_memo(pod)
+                rep = req_by_spec.get(sk) if sk is not None else None
+                if rep is None:
+                    rep = (pod.compute_requests(), pod.non_zero_requests())
+                    if sk is not None:
+                        req_by_spec[sk] = rep
+                else:
+                    d["_req_memo"], d["_nzreq_memo"] = rep
         # one Status shared by the whole run: success statuses are treated
         # as immutable everywhere (failure paths REPLACE outcome.status)
         success = STATUS_SUCCESS
@@ -5803,9 +5842,11 @@ class Scheduler:
                 self._nonfast_commits = (
                     getattr(self, "_nonfast_commits", 0) + len(run)
                 )
-            results = self.cache.assume_pods_bulk(
-                list(zip((qp.pod for qp in run), names))
-            )
+            with self._span("commit.assume"):
+                results = self.cache.assume_pods_bulk(
+                    list(zip((qp.pod for qp in run), names))
+                )
+            sp_out = self._span("commit.outcomes").begin()
             view_live = self._oracle_cache is not None
             fr = self.flight
             fr_on = fr.enabled
@@ -5842,6 +5883,7 @@ class Scheduler:
             self._bulk_bind_buffer.append(
                 _BulkBindTask(fwk, state, items, bid=self._bid)
             )
+        sp_out.end()
 
     def _ensure_bind_pool(self) -> None:
         if self._bind_pool is None:

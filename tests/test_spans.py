@@ -459,3 +459,261 @@ def test_the_four_roots_cover_every_listed_stage_but_the_fastpath_ones():
 
     covered = {s for names in ROOT_STAGES.values() for s in names}
     assert set(STAGES) - covered == {"ktpu/fastpath/sig_step", "ktpu/fastpath/static_eval"}
+
+
+# ---------------------------------------------------------------------------
+# the loop's pacing intervals divided (PR 39): the parts of ``chain_dispatch``
+# and of ``commit``, and each loop span's off-CPU seconds
+# ---------------------------------------------------------------------------
+
+CHAIN_PARTS = ("lock_wait", "repack", "pack", "sync", "prefilter", "h2d", "tables", "submit", "release")
+COMMIT_PARTS = ("requests", "assume", "outcomes")
+CROSS_POD_CELLS = ["spread-5k.backlog", "interpod-5k.backlog", "antiaffinity-5k.backlog"]
+ALL_CELLS = ["basic-5k.backlog", "spread-5k.backlog", "interpod-5k.backlog",
+             "unsched-5k.backlog-pending-first", "antiaffinity-5k.backlog"]
+TOP_LEVEL_LOOP_SPANS = ("queue_pop", "chain_dispatch", "pack", "h2d", "commit", "wave_resolve",
+                        "resident_rounds", "flush_binds")
+# metric -> (the phases its data file names, the cells BENCHMARK.json lists it for)
+NEW_PHASE_METRICS = {
+    "loop.chain_prep_s_per_kpod.backlog": (["chain_dispatch"], CROSS_POD_CELLS),
+    **{f"loop.chain_prep_s_per_kpod.{p}.backlog": ([f"chain_dispatch.{p}"], CROSS_POD_CELLS) for p in CHAIN_PARTS},
+    **{f"loop.commit_s_per_kpod.{p}.backlog": ([f"commit.{p}"], ALL_CELLS) for p in COMMIT_PARTS},
+    "loop.off_cpu_s_per_kpod.backlog": ([f"{p}.off_cpu" for p in TOP_LEVEL_LOOP_SPANS], ALL_CELLS),
+}
+IDLE_UNDER_PREP = "device.idle_under_chain_prep_s_per_kpod.backlog"
+# the two parts of ``chain_dispatch`` that hand the interpreter lock away
+OFF_CPU_PARTS = ("h2d", "release")
+PART_OFF_CPU_METRICS = {
+    f"loop.chain_prep_off_cpu_s_per_kpod.{p}.backlog": ([f"chain_dispatch.{p}.off_cpu"], CROSS_POD_CELLS)
+    for p in OFF_CPU_PARTS
+}
+
+
+def _cpu_clock_tick():
+    """The largest step the thread's CPU clock takes, probed: under gVisor it
+    advances 10 ms at a time whatever ``get_clock_info`` says, and a span's
+    signed ``off_cpu`` is then off by up to a tick either side."""
+    tick = time.get_clock_info("thread_time").resolution
+    for _ in range(3):
+        c0 = time.thread_time()
+        while True:
+            c1 = time.thread_time()
+            if c1 != c0:
+                break
+        tick = max(tick, c1 - c0)
+    return tick
+
+
+@pytest.fixture(scope="module")
+def chained_drain(tmp_path_factory):
+    """The chained wave drain (six batches of 16: the first dispatched
+    directly, five through ONE chain) under ``jax.profiler``.  Returns (the
+    scheduler, {event name: [(line, start ns, end ns), ...]})."""
+    import jax
+    from jax.profiler import ProfileData
+
+    from kubernetes_tpu.tools import paritycheck as pc
+
+    build, cfg_kw = WAVE_DRAINS["mixed-cross-pod-chained"]
+    trace_dir = str(tmp_path_factory.mktemp("xplane_chain"))
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(trace_dir, profiler_options=opts)
+    try:
+        _got, s = pc._drain(*build(), return_sched=True, mesh_dispatch=False, **cfg_kw)
+    finally:
+        jax.profiler.stop_trace()
+    assert s.metrics["wave_batches"] >= 2
+    (path,) = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb"))
+    spans = {}
+    for plane in ProfileData.from_file(path).planes:
+        # a thread is a LINE of the host plane; the lines share one name
+        for i, line in enumerate(plane.lines):
+            for e in line.events:
+                if e.name.startswith("ktpu."):
+                    spans.setdefault(e.name, []).append(
+                        ((plane.name, i), e.start_ns, e.start_ns + e.duration_ns))
+    return s, spans
+
+
+def test_every_part_of_chain_dispatch_appears_and_one_chain_start_is_one_sync(chained_drain):
+    s, _ = chained_drain
+    phases, hist = s.phases.snapshot(), s.phases.hist
+    dispatches = hist.count(phase="chain_dispatch")
+    assert dispatches >= 2
+    for part in CHAIN_PARTS:
+        assert f"chain_dispatch.{part}" in phases, (part, sorted(phases))
+    # a steady batch has no ``sync``: the drain started ONE chain
+    assert hist.count(phase="chain_dispatch.sync") == 1
+    for part in ("lock_wait", "repack", "prefilter", "h2d", "tables", "submit", "release"):
+        assert hist.count(phase=f"chain_dispatch.{part}") == dispatches, part
+    # interning + epoch before the repack, ``pack_pod_batch`` after it
+    assert hist.count(phase="chain_dispatch.pack") == 2 * dispatches
+
+
+def test_the_parts_of_chain_dispatch_fit_in_it_and_cover_most_of_it(chained_drain):
+    s, _ = chained_drain
+    phases = s.phases.snapshot()
+    parts = sum(phases[f"chain_dispatch.{p}"] for p in CHAIN_PARTS)
+    assert 0.8 * phases["chain_dispatch"] <= parts <= phases["chain_dispatch"]
+
+
+def test_the_parts_of_commit_fit_in_it(chained_drain, served_drain):
+    for phases in (chained_drain[0].phases.snapshot(), served_drain[0]):
+        for part in COMMIT_PARTS:
+            assert phases[f"commit.{part}"] > 0, part
+        parts = sum(phases[f"commit.{p}"] for p in COMMIT_PARTS) + phases["commit.lock_wait"]
+        assert parts <= phases["commit"]
+    # one set a contiguous RUN of placed pods, never one a pod: the served
+    # drain's 1,200 pods are one run a batch
+    _, _, _, hist = served_drain
+    for part in COMMIT_PARTS:
+        assert hist.count(phase=f"commit.{part}") == hist.count(phase="commit.lock_wait") < 40
+
+
+def test_the_parts_of_a_bulk_commit_cover_most_of_it(served_drain):
+    """Where ``commit`` is the bulk commit (1,200 pods in a few runs; the
+    three failing pods' ``post_filter`` is the rest of the span)."""
+    phases, _, _, _ = served_drain
+    parts = sum(phases[f"commit.{p}"] for p in COMMIT_PARTS) + phases["commit.lock_wait"]
+    assert parts + phases["post_filter"] >= 0.5 * phases["commit"]
+
+
+def test_the_parts_are_events_on_the_loops_thread_inside_chain_dispatch(chained_drain):
+    _, spans = chained_drain
+    outer = spans["ktpu.chain_dispatch"]
+    loop_thread, = {line for line, _, _ in spans["ktpu.batch"]}
+    assert {line for line, _, _ in outer} == {loop_thread}
+    assert {line for line, _, _ in spans["ktpu.bind"]} - {loop_thread}  # workers have lines of their own
+    for part in CHAIN_PARTS:
+        inner = spans[f"ktpu.chain_dispatch.{part}"]
+        for line, t0, t1 in inner:
+            assert any(ln == line and o0 <= t0 and t1 <= o1 for ln, o0, o1 in outer), part
+    # consecutive and disjoint: no part's event overlaps another's (``release``
+    # is made by the caller and begun at the callee's return: its event opens
+    # where the span begins, not where it was made)
+    parts = sorted((t0, t1) for p in CHAIN_PARTS for _, t0, t1 in spans[f"ktpu.chain_dispatch.{p}"])
+    assert all(a1 <= b0 for (_, a1), (b0, _) in zip(parts, parts[1:]))
+    # the ledger's dispatch annotation sits inside ``submit``
+    submits = spans["ktpu.chain_dispatch.submit"]
+    for line, t0, t1 in spans["ktpu.dispatch.chain.chain_dispatch"]:
+        assert any(ln == line and o0 <= t0 and t1 <= o1 for ln, o0, o1 in submits)
+    for part in COMMIT_PARTS:
+        for line, t0, t1 in spans[f"ktpu.commit.{part}"]:
+            assert any(ln == line and o0 <= t0 and t1 <= o1 for ln, o0, o1 in spans["ktpu.commit"]), part
+
+
+def test_a_span_around_a_sleep_books_its_wall_as_off_cpu():
+    acc = PhaseAccumulator(hist=_hist())
+    tick = _cpu_clock_tick()
+    with acc.span("h2d", off_cpu=True, bid=1):
+        time.sleep(0.05)
+    snap = acc.snapshot()
+    assert set(snap) == {"h2d", "h2d.off_cpu"}
+    assert 0.04 - tick <= snap["h2d.off_cpu"] <= snap["h2d"] + tick
+
+
+def test_a_span_around_a_busy_loop_books_no_more_than_the_wall_it_did_not_run():
+    """0 on an idle machine (to a tick of the CPU clock, either side: the
+    count is signed); on a loaded one whatever the OS took, which is never
+    the CPU the loop itself burned."""
+    acc = PhaseAccumulator(hist=_hist())
+    tick = _cpu_clock_tick()
+    sp = acc.span("pack", off_cpu=True, bid=1).begin()
+    c0, t0 = time.thread_time(), time.perf_counter()
+    while time.perf_counter() - t0 < 0.05:
+        pass
+    burned = time.thread_time() - c0
+    dt = sp.end()
+    off = acc.snapshot()["pack.off_cpu"]
+    assert dt >= 0.05 and -(tick + 1e-3) <= off <= dt - burned + tick + 1e-3
+
+
+def test_a_span_not_asked_for_its_cpu_clock_books_no_off_cpu():
+    """Binding workers open their spans on the accumulator directly: their
+    wall is a wait on the sink by design."""
+    acc = PhaseAccumulator(hist=_hist())
+    with acc.span("bind.sink", bid=1):
+        time.sleep(0.01)
+    assert set(acc.snapshot()) == {"bind.sink"}
+
+
+def test_off_cpu_is_a_count_no_histogram_observation_and_diff_carries_it():
+    acc = PhaseAccumulator(hist=_hist())
+    acc.tracer = _TailTap()
+    for _ in range(2):
+        with acc.span("queue_pop", off_cpu=True):
+            time.sleep(0.01)
+    snap = acc.snapshot()
+    assert acc.hist.count(phase="queue_pop") == 2 and acc.hist.count(phase="queue_pop.off_cpu") == 0
+    assert [n for n, _ in acc.tracer.calls] == ["queue_pop", "queue_pop"]
+    before = {"queue_pop": snap["queue_pop"] / 2, "queue_pop.off_cpu": snap["queue_pop.off_cpu"] / 2}
+    window = PhaseAccumulator.diff(snap, before)
+    assert window["queue_pop.off_cpu"] == pytest.approx(snap["queue_pop.off_cpu"] / 2)
+    # the count is signed (a coarse CPU clock's tick errors cancel in the
+    # sum): a window in which it fell keeps its negative delta
+    fell = PhaseAccumulator.diff({"pack": 2.0, "pack.off_cpu": -0.004}, {"pack": 2.0, "pack.off_cpu": 0.001})
+    assert fell == {"pack.off_cpu": pytest.approx(-0.005)}
+
+
+def test_only_the_loop_spans_a_metric_reads_carry_off_cpu(served_drain, chained_drain):
+    from kubernetes_tpu.scheduler import Scheduler
+
+    # what the metrics sum, and nothing else
+    assert Scheduler._OFF_CPU_SPANS == set(TOP_LEVEL_LOOP_SPANS) | {f"chain_dispatch.{p}" for p in OFF_CPU_PARTS}
+    tick = _cpu_clock_tick()
+    s = chained_drain[0]
+    for phases, hist in ((served_drain[0], served_drain[3]), (s.phases.snapshot(), s.phases.hist)):
+        booked = {k[: -len(".off_cpu")] for k in phases if k.endswith(".off_cpu")}
+        # not the binding workers' spans, not the waits by design (device,
+        # d2h, loop.idle, the lock_waits), not the other nested parts
+        assert booked == {n for n in Scheduler._OFF_CPU_SPANS if n in phases}
+        for name in booked:
+            # signed: each span is off by up to a tick of the CPU clock
+            slack = hist.count(phase=name) * tick + 1e-3
+            assert -slack <= phases[name + ".off_cpu"] <= phases[name] + slack, name
+    assert {"chain_dispatch", "chain_dispatch.h2d", "chain_dispatch.release"} <= booked
+    assert not any(name.endswith(".off_cpu") for name in served_drain[1])  # no annotation
+
+
+def test_every_new_metric_is_listed_for_its_cells_and_reads_a_number(chained_drain):
+    import json
+
+    from benchmarks import cells
+
+    s, _ = chained_drain
+    bench = json.load(open(os.path.join(os.path.dirname(__file__), "..", "BENCHMARK.json")))
+    entries = {m["name"]: m for m in bench["per_layer"]}
+    window = PhaseAccumulator.diff(s.phases.snapshot(), {})
+    ctx = {"phases": window, "pods_in_window": 96}
+    by_cell = {c: {sp["name"]: sp for sp in cells.layer_metrics(c, bench)} for c in ALL_CELLS}
+    tick = _cpu_clock_tick()
+    for name, (phases, listed) in {**NEW_PHASE_METRICS, **PART_OFF_CPU_METRICS}.items():
+        e = entries[name]
+        assert e["workloads"] == listed and e["moves"] == "pods_per_s" and e["better"] == "lower", name
+        assert (e["unit"], e["source"], e["layer"]) == ("s/kpod", "program_span", "scheduling loop"), name
+        assert [c for c in ALL_CELLS if name in by_cell[c]] == listed, name
+        spec = by_cell[listed[0]][name]
+        assert spec["reader"] == "phase" and spec["params"]["phases"] == phases, name
+        value = spec["read"](ctx, spec["params"])
+        assert isinstance(value, float), name
+        assert value == pytest.approx(sum(window.get(p, 0.0) for p in phases) / 0.096), name
+        # seconds and their parts are never negative; an off-CPU count is
+        # signed, to a tick of the CPU clock a span
+        assert value >= (-(100 * tick + 1e-3) / 0.096 if "off_cpu" in name else 0.0), name
+        # a program without the span (the parent) reads 0.0 and raises nothing
+        assert spec["read"]({"phases": {"commit": 1.0}, "pods_in_window": 5000}, spec["params"]) == 0.0
+    e = entries[IDLE_UNDER_PREP]
+    assert e["workloads"] == CROSS_POD_CELLS and (e["source"], e["layer"]) == ("device_trace", "device")
+    spec = by_cell["spread-5k.backlog"][IDLE_UNDER_PREP]
+    assert spec["reader"] == "xspan"
+    assert spec["params"] == {"what": "idle_overlap_s", "spans": ["ktpu.chain_dispatch"]}
+    # appended together, in this order, after everything the benchmark had
+    new = list(NEW_PHASE_METRICS) + [IDLE_UNDER_PREP] + list(PART_OFF_CPU_METRICS)
+    names = [m["name"] for m in bench["per_layer"]]
+    first = names.index(new[0])
+    assert first >= 33 and names[first:first + len(new)] == new
+    # loop.off_cpu is at most the spans it is taken over
+    off = by_cell["basic-5k.backlog"]["loop.off_cpu_s_per_kpod.backlog"]
+    assert off["read"](ctx, off["params"]) <= sum(window.get(p, 0.0) for p in TOP_LEVEL_LOOP_SPANS) / 0.096 + 1e-9
