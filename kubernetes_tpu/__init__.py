@@ -38,7 +38,15 @@ __version__ = "0.1.0"
 # is an error, not something to carry on from.
 import os as _os
 
-import jax as _jax
+# The process's malloc policy (one glibc arena: util/allocator.py), set
+# here because glibc fixes its arena limit once and keeps the arenas it
+# has made: before the backend's thread pools, so before anything below
+# can start one.
+from kubernetes_tpu.util import allocator as _allocator
+
+_allocator.engage()
+
+import jax as _jax  # noqa: E402
 
 _jax.config.update("jax_enable_x64", True)
 

@@ -1118,6 +1118,30 @@ class SchedulerMetrics:
                 "released.",
             )
         )
+        self.malloc_system_bytes = r.register(
+            Gauge(
+                "scheduler_tpu_malloc_system_bytes",
+                "Bytes the process's malloc arenas hold from the kernel "
+                "(glibc mallinfo2 arena, summed over arenas; "
+                "util/allocator.py), read on scrape.  With one arena it rises "
+                "to the main heap's high-water mark and stays.",
+            )
+        )
+        self.malloc_mmapped_bytes = r.register(
+            Gauge(
+                "scheduler_tpu_malloc_mmapped_bytes",
+                "Bytes in blocks malloc mapped one by one (glibc mallinfo2 "
+                "hblkhd: requests over the mmap threshold), read on scrape.",
+            )
+        )
+        self.malloc_arenas = r.register(
+            Gauge(
+                "scheduler_tpu_malloc_arenas",
+                "Arenas glibc has made in this process (malloc_info), read "
+                "on scrape: 1 where the allocator policy was engaged before "
+                "any other thread of the process allocated.",
+            )
+        )
         self.wire_bytes_total = r.register(
             Counter(
                 "scheduler_tpu_wire_bytes_total",

@@ -44,6 +44,7 @@ from typing import Dict, List, Optional
 from urllib.parse import parse_qs, urlparse
 
 from kubernetes_tpu.scheduler import Scheduler
+from kubernetes_tpu.util.allocator import HeapWatch
 from kubernetes_tpu.util.collector import LoopCollector
 
 # ---------------------------------------------------------------------------
@@ -299,6 +300,8 @@ class SchedulerServer:
         self.debugger = CacheDebugger(scheduler, ground_truth)
         # the garbage collector's schedule while this loop leads
         self.collector = LoopCollector(scheduler.phases)
+        # what the process obtains from the kernel while this loop runs
+        self.heap = HeapWatch(scheduler.phases)
         self._stop = threading.Event()
         self._synced = threading.Event()
         self._loop_thread: Optional[threading.Thread] = None
@@ -341,6 +344,7 @@ class SchedulerServer:
                         self._send(500, "informers not synced")
                 elif self.path == "/metrics":
                     srv.collector.sync_registry(srv.sched.prom)
+                    srv.heap.sync_registry(srv.sched.prom)
                     self._send(
                         200,
                         srv.sched.expose_metrics(),
@@ -581,6 +585,7 @@ class SchedulerServer:
         else:
             # no election: this loop leads from here on
             self.collector.engage()
+        self.heap.sample()  # the base growth is counted from
         self._loop_thread = threading.Thread(target=self._run_loop, daemon=True)
         self._loop_thread.start()
 
@@ -651,6 +656,8 @@ class SchedulerServer:
                     or self.sched._inflight_binds
                 )
             )
+            if outs:
+                self.heap.sample(due_only=True)  # the rest at stop()
             # the sleep on an empty queue, named on the loop's own thread
             with self.sched.phases.span("loop.idle"):
                 self._stop.wait(self.poll_interval_s)
@@ -660,6 +667,7 @@ class SchedulerServer:
         if self._loop_thread is not None:
             self._loop_thread.join(timeout=5)
         self.collector.release()
+        self.heap.sample()  # what grew since the loop's last iteration
         if self._le_thread is not None:
             # settle the renewal loop BEFORE releasing, or a concurrent
             # renew can defeat the release and strand the lease on this
