@@ -183,12 +183,15 @@ class Cache:
         """assume_pod for one dispatch's worth of placements in one pass.
 
         Same protocol and invariants as the per-pod assume, minus the
-        per-pod overhead: callers guarantee the pods are signature-gated
-        (no (anti-)affinity terms, no host ports — the fast path's
-        eligibility), so the feature-flag probes collapse, and the
-        generation bump aggregates to one per TOUCHED NODE instead of one
-        per pod (the mirror repacks per node row, so per-pod bumps carry
-        no extra information).  Returns a list aligned with ``pairs``:
+        per-pod overhead: callers guarantee the pods use no host ports
+        (the fast path's eligibility; the chained path refuses them), so
+        that probe collapses; a pod that carries (anti-)affinity terms —
+        a wave's bulk tail commits those here — is counted and registered
+        as ``assume_pod`` counts it, or the fast gate would never learn
+        of its terms and its removal would take the count below zero.
+        The generation bump aggregates to one per TOUCHED NODE instead of
+        one per pod (the mirror repacks per node row, so per-pod bumps
+        carry no extra information).  Returns a list aligned with ``pairs``:
         the assumed pod copy, or an error STRING for pods that violated
         the protocol (already assumed/added) — those are not assumed,
         exactly like the per-pod path's CacheError."""
@@ -222,7 +225,10 @@ class Cache:
             pod_states[pod.uid] = _PodState(assumed)
             assumed_set.add(pod.uid)
             out.append(assumed)
-            n_ok += 1
+            if assumed.affinity is not None:
+                self._count_pod(assumed, +1)  # bumps pod_version itself
+            else:
+                n_ok += 1
         self.pod_version += n_ok
         for cn in touched.values():
             cn.generation = next_generation()

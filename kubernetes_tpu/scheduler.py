@@ -360,6 +360,9 @@ class Scheduler:
         self._bulk_bind_buffer: List = []  # _BulkBindTask runs (fast path)
         # chained-dispatch state (see _try_dispatch_chained)
         self._chain = None
+        # why _fast_gate_ok last said no (None: it said yes, or was not
+        # asked); the loop books it once a batch, see _book_route
+        self._fast_gate_refused: Optional[str] = None
 
         # storage/DRA object views: assume caches for the objects plugins
         # optimistically mutate (PV/PVC/ResourceClaim, scheduler.go:298-302),
@@ -994,6 +997,7 @@ class Scheduler:
                     profile_name, next(iter(self.profiles.values()))
                 )
                 rec = None
+                self._fast_gate_refused = None
                 if self._chain_quickcheck(fwk, group):
                     # the host's side of one chained dispatch (prep under
                     # the lock, tables, the dispatch call): its own phase —
@@ -1008,6 +1012,9 @@ class Scheduler:
                             rec = self._try_dispatch_chained(
                                 fwk, group, outcomes, can_restart=True
                             )
+                    # a record, "handled" or the serial fallback: its batch
+                    if rec is not None:
+                        self._book_route("chained", group)
                 if isinstance(rec, tuple) and rec and rec[0] == "serial":
                     # breaker fallback for an abandoned chained dispatch:
                     # settle the pipeline (its commits must land first),
@@ -1057,6 +1064,8 @@ class Scheduler:
                     frec = self._try_dispatch_fast(
                         fwk, group, outcomes, chain_settled=True
                     )
+                if frec is not None:  # a record or "handled"
+                    self._book_route("fast", group)
                 if isinstance(frec, dict):
                     pending.append(frec)
                     if frec.get(
@@ -1081,6 +1090,7 @@ class Scheduler:
                 t0 = time.perf_counter()
                 outs = self._schedule_batch(group)
                 dt = time.perf_counter() - t0
+                self._book_route("direct", group)
                 self._record_batch_metrics(profile_name, group, outs, dt)
                 outcomes.extend(outs)
             # hand this batch's buffered binds to the workers — they overlap
@@ -1132,6 +1142,19 @@ class Scheduler:
             fwk.has_reserve_or_permit()
             and not fwk.reserve_permit_covered_by_host_filters()
         )
+
+    def _book_route(self, route: str, group) -> None:
+        """One count a batch, where the loop has routed it: the batch's pods
+        by the route that took them (``route.chained``: the chained
+        dispatch, wave or scan, its serial fallback included; ``route.fast``:
+        the signature / resident path, the pods its extension popped
+        included; ``route.direct``) and, where ``_fast_gate_ok``'s last verdict on it
+        was no, by the gate's reason (``fast_gate.refused.<reason>``)."""
+        self.phases.count("route." + route, len(group))
+        if self._fast_gate_refused is not None:
+            self.phases.count(
+                "fast_gate.refused." + self._fast_gate_refused, len(group)
+            )
 
     # The loop's spans whose off-CPU seconds a per-layer metric reads: the
     # top-level ones that block on nothing by design (loop.off_cpu_s_per_kpod;
@@ -2201,22 +2224,33 @@ class Scheduler:
           batch label-group against the cache's term-pod registry;
         * placed host-port users never constrain port-FREE pods (and port
           users are already signature-ineligible), so no port gate at all.
+
+        Called from three places a batch may pass; ``_fast_gate_refused``
+        keeps the last verdict's reason (``gang``, ``nomination``,
+        ``term_count``: more placed term pods than a probe is asked about,
+        ``term_admits``: a placed term admits a batch pod) for the loop to
+        book once, ``_book_route``.
         """
+        self._fast_gate_refused = self._fast_gate_refusal(batch)
+        return self._fast_gate_refused is None
+
+    def _fast_gate_refusal(self, batch) -> Optional[str]:
+        """Why the fast gate refuses ``batch``; None where it does not."""
         # gang members need the workloads tier's all-or-nothing admission —
         # the signature committer has no rollback
         if self.config.gang_dispatch and any(
             wlg.group_key_of(qp.pod) is not None for qp in batch
         ):
-            return False
+            return "gang"
         if len(self.nominator):
             max_nom = max(p.priority for _, p in self.nominator.entries())
             if any(qp.pod.priority <= max_nom for qp in batch):
-                return False
+                return "nomination"
         n_t = self.cache.n_term_pods
         if n_t:
             if n_t > 64:
                 # probe checks would cost more than the scan saves
-                return False
+                return "term_count"
             from kubernetes_tpu.fastpath import _pod_probes
 
             key = self.cache.term_version
@@ -2238,8 +2272,8 @@ class Scheduler:
                     hit = any(pr.admits(qp.pod) for pr in probes)
                     seen[gk] = hit
                 if hit:
-                    return False
-        return True
+                    return "term_admits"
+        return None
 
     def _fast_pod_predicate(self, fwk, group_name: str, known_rows=None):
         """Per-pod closure mirroring _try_dispatch_fast's batch gates +
@@ -4683,7 +4717,9 @@ class Scheduler:
                     for qp in extra:
                         for k, v in qp.pod.labels.items():
                             vocab.intern_label(k, v)
-                batch = batch + extra
+                # in place: the loop's count of the pods this route took
+                # (_book_route) holds the extension's too
+                batch.extend(extra)
                 keys = self._batch_signature_keys(batch)
                 assert keys is not None  # predicate guarantees eligibility
 

@@ -240,3 +240,120 @@ def test_antiaffinity_pods_are_the_sources_template_and_equal_on_both_sides():
         node = workload.build_node(T, R, s)
         assert dataclasses.asdict(node) == dataclasses.asdict(workload.build_node(RT, RR, s))
         assert node.labels == {HOSTNAME: node.name}
+
+
+# ---- sched-perf-mixedbase-5k (upstream's :615), PR 41 ----------------------------
+
+MIXED_CELL = "mixedbase-5k.backlog-on-base"
+ZONE = "topology.kubernetes.io/zone"
+NAMESPACES = ("sched-1", "sched-0")
+# base group -> (role its pods are named after, template, labels, the one term:
+# side, required or preferred, topology key, the colour it selects)
+MIXED_BASE = {
+    "base_affinity": ("base-affinity", "pod-with-pod-affinity", {"color": "blue"},
+                      ("pod_affinity", "required", ZONE, "blue")),
+    "base_anti_affinity": ("base-anti-affinity", "pod-with-pod-anti-affinity", {"color": "green", "name": "test"},
+                           ("pod_anti_affinity", "required", HOSTNAME, "green")),
+    "base_preferred_affinity": ("base-preferred-affinity", "pod-with-preferred-pod-affinity", {"color": "red"},
+                                ("pod_affinity", "preferred", HOSTNAME, "red")),
+    "base_preferred_anti_affinity": ("base-preferred-anti-affinity", "pod-with-preferred-pod-anti-affinity",
+                                     {"color": "yellow"}, ("pod_anti_affinity", "preferred", HOSTNAME, "yellow")),
+}
+# the same digest over what benchmarks/workload.py and the traffic kind
+# backlog_on_base build from the files PR 41 adds, seed 7: the init pods and
+# the four planted groups on their seeded nodes, warm-up and measured backlogs
+MIXED_PINNED = "b49a5c88f677b29b9aacb4b68e40fc4e44ea8035b8c81d96b91ca69721fadc6e"
+
+
+def _mixed_built():
+    cell = cells.cell(MIXED_CELL)
+    return cell, _pr29._groups(cell["config"], cell["traffic"], cell["kind"], 7)
+
+
+def test_mixedbase_config_round_trips_with_no_key_refused():
+    """Every key of the file is one the harness or the traffic kind builds
+    (no ``KeyError``), at the source's counts, with nothing cut: five placed
+    groups of 2,000 in ``sched-0``, two pods a node over ONE seeded node
+    order, the measured pods in a namespace no term names."""
+    cell, (nodes, init, init_nodes, plan) = _mixed_built()
+    cfg = cell["config"]
+    assert cfg["reduced"] == [] and cfg["name"] == "sched-perf-mixedbase-5k"
+    assert len(cfg["source"]) <= 200 and "performance-config.yaml:615" in cfg["source"]
+    assert "MixedSchedulingBasePod/5000Nodes_5000Pods" in cfg["source"]
+    assert cfg["identity_sample"] >= 4 and "BLIND" in cfg["identity_sample_why"] and cfg["assumed"]
+    assert (len(nodes), len(init), len(plan["base"]), len(plan["warm"]), len(plan["measure"])) == \
+        (5000, 2000, 8000, 5000, 5000)
+    assert cell["traffic"]["base"] == list(MIXED_BASE)
+    assert cell["kind"].pods_alive(plan) == 13000  # beside the 2,000 init pods the harness counts itself
+    base_specs = [s for s, _n in plan["base"]]
+    assert {s["namespace"] for s in init + base_specs} == {"sched-0"}
+    assert {s["namespace"] for s in plan["warm"] + plan["measure"]} == {"namespace-6"}
+    assert not {"namespace-6"} & {ns for s in base_specs for side in s["affinity"].values()
+                                  for t in side.get("required", []) + [p["term"] for p in side.get("preferred", [])]
+                                  for ns in t["namespaces"]}
+    assert len({workload.uid_of(s) for s in init + base_specs + plan["warm"] + plan["measure"]}) == 20000
+    # ONE node order: the base continues where the harness's init pods ended
+    order = workload.init_placement(cfg, 10000, nodes, 7)
+    assert order[:2000] == init_nodes and order[2000:] == [n for _s, n in plan["base"]]
+    assert sorted(order) == sorted([n["name"] for n in nodes] * 2)  # two base pods a node
+    green = [n for s, n in plan["base"] if s["labels"].get("color") == "green"]
+    assert len(green) == len(set(green)) == 2000  # the required anti-affinity group on distinct nodes
+    built = {n["name"]: n["labels"] for n in nodes}
+    assert all(lb == {ZONE: "zone1", HOSTNAME: name} for name, lb in built.items())
+    assert cfg["expect_kernels"] == ["resident.resident_run", "chain.chain_dispatch", "wave.wave_run", "gang.gang_run"]
+    assert len(cfg["guarantees"]) == 5 and "15,000" in cfg["guarantees"][4]
+
+
+@pytest.mark.parametrize("group", sorted(MIXED_BASE))
+def test_mixedbase_base_groups_resolve_through_group_specs_and_are_the_sources_templates(group):
+    """Each of the four term-carrying groups is a group like ``init_pods``
+    (``workload.group_specs`` takes any group name): 2,000 specs of its
+    template in ``sched-0``, named after the group, carrying the template's
+    ONE term over both namespaces; equal field by field in the program's
+    types and the frozen reference's."""
+    from kubernetes_tpu.api import types as T
+
+    cell, (_nodes, _init, _init_nodes, plan) = _mixed_built()
+    role, template, labels, (side, strength, key, colour) = MIXED_BASE[group]
+    specs = workload.group_specs(cell["config"], group, role)
+    assert cell["config"][group] == {"count": 2000, "template": template, "namespace": "sched-0"}
+    assert [s["name"] for s in specs[:2] + specs[-1:]] == [f"{role}-0", f"{role}-1", f"{role}-1999"]
+    start = list(MIXED_BASE).index(group) * 2000
+    assert [s for s, _n in plan["base"][start:start + 2000]] == specs
+    for s, n in (plan["base"][start], plan["base"][start + 1999]):
+        pod = workload.build_pod(T, s, node_name=n)
+        assert dataclasses.asdict(pod) == dataclasses.asdict(workload.build_pod(RT, s, node_name=n))
+        assert pod.labels == labels and pod.node_name == n and pod.namespace == "sched-0"
+        assert pod.containers[0].requests == {"cpu": "100m", "memory": "500Mi"}
+        other = "pod_anti_affinity" if side == "pod_affinity" else "pod_affinity"
+        assert getattr(pod.affinity, other) is None
+        body = getattr(pod.affinity, side)
+        req = body.required_during_scheduling_ignored_during_execution
+        pref = body.preferred_during_scheduling_ignored_during_execution
+        if strength == "required":
+            (term,), weights = req, [w for w in pref]
+        else:
+            (wt,), weights = pref, [w for w in req]
+            term = wt.pod_affinity_term
+            assert wt.weight == 1
+        assert weights == []  # one term a template, of one kind
+        assert term.topology_key == key and term.namespaces == NAMESPACES
+        assert term.label_selector.match_labels == {"color": colour}
+
+
+def test_mixedbase_plain_pods_and_the_pinned_objects():
+    """``pod-default`` on both ends (the init group in ``sched-0``, warm-up
+    and measured pods in ``namespace-6``): no labels, no term, 100m / 500Mi;
+    and everything the cell builds, pinned by digest."""
+    from kubernetes_tpu.api import resource as R
+    from kubernetes_tpu.api import types as T
+
+    _cell, (nodes, init, init_nodes, plan) = _mixed_built()
+    for s, n in ((init[0], init_nodes[0]), (plan["warm"][0], ""), (plan["measure"][-1], "")):
+        pod = workload.build_pod(T, s, node_name=n)
+        assert dataclasses.asdict(pod) == dataclasses.asdict(workload.build_pod(RT, s, node_name=n))
+        assert pod.labels == {} and pod.affinity is None and pod.topology_spread_constraints == ()
+        assert pod.containers[0].requests == {"cpu": "100m", "memory": "500Mi"}
+    placed = init + [s for s, _n in plan["base"]]
+    placed_on = init_nodes + [n for _s, n in plan["base"]]
+    assert _digest(T, R, nodes, placed, placed_on, plan) == MIXED_PINNED

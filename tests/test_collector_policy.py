@@ -87,6 +87,22 @@ def _pods(api, n, prefix="p"):
         )
 
 
+@pytest.fixture(scope="module", autouse=True)
+def warm_process():
+    """The cases below wait, ten seconds at most, on a drain and count the
+    collections and idle passes around it.  A process's FIRST drain loads or
+    compiles its programs; where this file opens a worker's run beside five
+    other cold workers that takes longer than the waits allow and allocates
+    enough for passes of its own.  One drain of each size the cases use,
+    before any of them, makes them independent of where the file falls."""
+    for n in (20, 8, 10):
+        api, sched = _env()
+        _pods(api, n)
+        sched.schedule_pending()
+        sched.wait_for_bindings()
+        assert len(api.bindings) == n
+
+
 def _churn():
     """More than a young generation's worth of cyclic garbage: the
     interpreter collects at least once, so an idle pass is due."""

@@ -468,9 +468,13 @@ def test_the_four_roots_cover_every_listed_stage_but_the_fastpath_ones():
 
 CHAIN_PARTS = ("lock_wait", "repack", "pack", "sync", "prefilter", "h2d", "tables", "submit", "release")
 COMMIT_PARTS = ("requests", "assume", "outcomes")
-CROSS_POD_CELLS = ["spread-5k.backlog", "interpod-5k.backlog", "antiaffinity-5k.backlog"]
+# the cells whose batches take the chained dispatch: the three whose pods carry a
+# cross-pod constraint, and (PR 41) the one whose plain pods land on a base of
+# term-carrying pods and are sent there by the fast gate's count
+CROSS_POD_CELLS = ["spread-5k.backlog", "interpod-5k.backlog", "antiaffinity-5k.backlog",
+                   "mixedbase-5k.backlog-on-base"]
 ALL_CELLS = ["basic-5k.backlog", "spread-5k.backlog", "interpod-5k.backlog",
-             "unsched-5k.backlog-pending-first", "antiaffinity-5k.backlog"]
+             "unsched-5k.backlog-pending-first", "antiaffinity-5k.backlog", "mixedbase-5k.backlog-on-base"]
 TOP_LEVEL_LOOP_SPANS = ("queue_pop", "chain_dispatch", "pack", "h2d", "commit", "wave_resolve",
                         "resident_rounds", "flush_binds")
 # metric -> (the phases its data file names, the cells BENCHMARK.json lists it for)
@@ -675,6 +679,48 @@ def test_only_the_loop_spans_a_metric_reads_carry_off_cpu(served_drain, chained_
             assert -slack <= phases[name + ".off_cpu"] <= phases[name] + slack, name
     assert {"chain_dispatch", "chain_dispatch.h2d", "chain_dispatch.release"} <= booked
     assert not any(name.endswith(".off_cpu") for name in served_drain[1])  # no annotation
+
+
+def test_the_route_and_the_gates_reason_are_counts_no_span_and_no_histogram(chained_drain):
+    """``route.<route>`` and ``fast_gate.refused.<reason>`` (PR 41) go through
+    ``PhaseAccumulator.count``: totals that ``snapshot`` and ``diff`` carry,
+    with no histogram observation and no profiler event; every pod the loop
+    popped is booked under exactly one route."""
+    s, spans = chained_drain
+    phases = s.phases.snapshot()
+    booked = {k: v for k, v in phases.items() if k.startswith(("route.", "fast_gate.refused."))}
+    routes = {k: v for k, v in booked.items() if k.startswith("route.")}
+    assert set(routes) == {"route.direct", "route.chained"}  # the first batch direct, the rest through the chain
+    assert sum(routes.values()) == s.metrics["schedule_attempts"] == 96
+    assert all(v <= sum(routes.values()) for k, v in booked.items() if k.startswith("fast_gate."))
+    for name in booked:
+        assert s.phases.hist.count(phase=name) == 0
+    assert not [n for n in spans if n.startswith(("ktpu.route", "ktpu.fast_gate"))]
+    assert PhaseAccumulator.diff(phases, {k: 0.0 for k in phases}).items() >= booked.items()
+
+
+def test_the_route_is_booked_once_a_batch_and_holds_the_pods_a_fast_batch_pulled_in():
+    """One ``count`` a batch, never one a pod: a fast batch that extends
+    itself from the queue (16 popped, 32 pulled in) is ONE booking of 48."""
+    from kubernetes_tpu.api.resource import Resource
+    from kubernetes_tpu.api.types import Container, Node, Pod
+    from kubernetes_tpu.framework.config import SchedulerConfiguration
+    from kubernetes_tpu.scheduler import Scheduler
+
+    cfg = SchedulerConfiguration()
+    cfg.batch_size = 16
+    sched = Scheduler(configuration=cfg)
+    sched.binding_sink = lambda pod, node: None
+    for i in range(8):
+        sched.on_node_add(Node(name=f"n{i}", capacity=Resource.from_map({"cpu": "64", "memory": "64Gi", "pods": 110})))
+    for i in range(48):
+        sched.on_pod_add(Pod(name=f"p{i}", containers=[Container(name="c", requests={"cpu": "100m"})]))
+    calls = []
+    count = sched.phases.count
+    sched.phases.count = lambda name, n: (calls.append((name, n)), count(name, n))[1]
+    outs = sched.schedule_pending()
+    assert len(outs) == 48 and all(o.node for o in outs)
+    assert [c for c in calls if c[0].startswith(("route.", "fast_gate."))] == [("route.fast", 48)]
 
 
 def test_every_new_metric_is_listed_for_its_cells_and_reads_a_number(chained_drain):
