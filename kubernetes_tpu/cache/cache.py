@@ -27,6 +27,7 @@ from typing import Dict, List, Optional, Tuple
 from kubernetes_tpu.analysis import sanitizer
 from kubernetes_tpu.api.resource import Resource
 from kubernetes_tpu.api.types import Node, Pod
+from kubernetes_tpu.cache.term_probes import ProbeRegistry
 
 # Lock-discipline registry (kubernetes_tpu.analysis): Cache has no lock of
 # its own — every mutating method is contractually entered with the owning
@@ -37,7 +38,10 @@ from kubernetes_tpu.api.types import Node, Pod
 _KTPU_GUARDED = {
     "Cache": {
         "external_lock": "Scheduler._mu",
-        "readonly": ["is_assumed", "real_nodes", "placed_pods", "stats", "_pod_flags"],
+        "readonly": [
+            "is_assumed", "real_nodes", "placed_pods", "stats", "_pod_flags",
+            "term_probe_view",
+        ],
     },
 }
 
@@ -108,6 +112,9 @@ class Cache:
         # pod" instead of disabling itself cluster-globally
         self.term_pods: Dict[str, Pod] = {}
         self.term_version = 0
+        # the same pods' terms as DISTINCT probes with reference counts:
+        # what the gate asks, at any count of placed term pods
+        self.term_probes = ProbeRegistry()
 
     @staticmethod
     def _pod_flags(pod: Pod) -> Tuple[bool, bool]:
@@ -125,8 +132,13 @@ class Cache:
             self.term_version += 1
             if sign > 0:
                 self.term_pods[pod.uid] = pod
+                self.term_probes.add(pod)
             else:
-                self.term_pods.pop(pod.uid, None)
+                # by the object that was added (an adopted API object may
+                # stand in ``pod``): exactly what its addition counted
+                old = self.term_pods.pop(pod.uid, None)
+                if old is not None:
+                    self.term_probes.remove(old)
         if has_ports:
             self.n_port_pods += sign
 
@@ -325,6 +337,12 @@ class Cache:
         self._count_pod(pod, -1)
 
     # ----- introspection ----------------------------------------------------
+
+    def term_probe_view(self):
+        """The placed terms the fast gate asks, as an immutable view: safe
+        to take and to read without the lock, beside ``_count_pod`` (the
+        accessor the lock discipline above registers as read-only)."""
+        return self.term_probes.view()
 
     def is_assumed(self, uid: str) -> bool:
         return uid in self.assumed

@@ -24,7 +24,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from kubernetes_tpu.api.types import LabelSelector, Pod
+from kubernetes_tpu.api.types import Pod
 from kubernetes_tpu.snapshot.schema import (
     LANE_CPU,
     LANE_MEM,
@@ -35,89 +35,6 @@ from kubernetes_tpu.snapshot.schema import (
 )
 
 MAX = 100  # MaxNodeScore
-
-
-# ---------------------------------------------------------------------------
-# Placed-term interaction probes — the fast gate's "could any placed pod's
-# (anti-)affinity/spread term admit this newcomer" check (_fast_gate_ok).
-# Conservative: may claim interaction where none exists (only costs fast-path
-# eligibility, never correctness).
-# ---------------------------------------------------------------------------
-
-
-def _selector_matches(sel: Optional[LabelSelector], labels: Dict[str, str]) -> bool:
-    """LabelSelector match; unknown operators match conservatively."""
-    if sel is None:
-        # a nil selector matches nothing (labels.Nothing()) in spread
-        # counting; the callers that mean "everything" pass empty selector
-        return False
-    for k, v in (sel.match_labels or {}).items():
-        if labels.get(k) != v:
-            return False
-    for e in sel.match_expressions or ():
-        op = e.operator
-        if op == "In":
-            if labels.get(e.key) not in (e.values or ()):
-                return False
-        elif op == "NotIn":
-            if e.key in labels and labels[e.key] in (e.values or ()):
-                return False
-        elif op == "Exists":
-            if e.key not in labels:
-                return False
-        elif op == "DoesNotExist":
-            if e.key in labels:
-                return False
-        else:  # unknown op: conservative
-            return True
-    return True
-
-
-class _Probe:
-    """One selector-with-namespace-scope an interacting pod would match."""
-
-    __slots__ = ("sel", "ns_any", "namespaces")
-
-    def __init__(self, sel, ns_any: bool, namespaces: Tuple[str, ...]):
-        self.sel = sel
-        self.ns_any = ns_any
-        self.namespaces = namespaces
-
-    def admits(self, pod: Pod) -> bool:
-        if not self.ns_any and pod.namespace not in self.namespaces:
-            return False
-        return _selector_matches(self.sel, pod.labels)
-
-
-def _pod_probes(pod: Pod) -> List[_Probe]:
-    """Probes for every selector through which ``pod`` could interact with
-    a newcomer: spread constraints count same-namespace peers only
-    (podtopologyspread/filtering.go:236-310); affinity/anti terms scope by
-    their namespace set, a namespaceSelector conservatively admitting
-    everything (interpodaffinity/filtering.go:306-365)."""
-    probes: List[_Probe] = []
-    for c in pod.topology_spread_constraints:
-        probes.append(_Probe(c.label_selector, False, (pod.namespace,)))
-    aff = pod.affinity
-    terms = []
-    if aff is not None:
-        for grp in (aff.pod_affinity, aff.pod_anti_affinity):
-            if grp is None:
-                continue
-            terms.extend(
-                grp.required_during_scheduling_ignored_during_execution or ()
-            )
-            for wt in (
-                grp.preferred_during_scheduling_ignored_during_execution or ()
-            ):
-                terms.append(wt.pod_affinity_term)
-    for t in terms:
-        if getattr(t, "namespace_selector", None) is not None:
-            probes.append(_Probe(t.label_selector, True, ()))
-        else:
-            nss = tuple(t.namespaces or ()) or (pod.namespace,)
-            probes.append(_Probe(t.label_selector, False, nss))
-    return probes
 
 
 def spec_key(pod: Pod):

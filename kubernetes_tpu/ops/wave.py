@@ -399,7 +399,7 @@ def interaction_groups(pods):
 
     Returns (group_id per pod, n_groups).
     """
-    from kubernetes_tpu.fastpath import _pod_probes
+    from kubernetes_tpu.cache.term_probes import MAX_PROBES_ASKED, probe_entries
 
     n = len(pods)
     parent = list(range(n))
@@ -415,27 +415,12 @@ def interaction_groups(pods):
         if ra != rb:
             parent[rb] = ra
 
-    def sel_key(sel):
-        """Hashable content key of a LabelSelector (match_labels is a
-        plain dict, so the dataclass itself doesn't hash)."""
-        if sel is None:
-            return None
-        return (
-            tuple(sorted((sel.match_labels or {}).items())),
-            tuple(sel.match_expressions or ()),
-        )
-
     # dedup probes by content so template-stamped pods share one probe and
     # the admits sweep runs per (probe, label-group) pair, not per pod²
     probe_owner: dict = {}
     probes = []  # (owner pod index, probe) — distinct by content
     for i, pod in enumerate(pods):
-        for pr in _pod_probes(pod):
-            try:
-                key = (pr.ns_any, pr.namespaces, sel_key(pr.sel))
-                hash(key)
-            except TypeError:
-                key = None
+        for key, pr in probe_entries(pod):
             if key is None:
                 probes.append((i, pr))
                 continue
@@ -449,7 +434,7 @@ def interaction_groups(pods):
     # pods with DISTINCT label sets defeat the cache, so bound the worst
     # case: past ~100k (probe, pod) pairs fall back to one conservative
     # all-interacting component (a single bulk run — always safe).
-    if len(probes) * n > 100_000:
+    if len(probes) * n > MAX_PROBES_ASKED:
         return [0] * n, 1
     hit_cache: dict = {}
     for i, pod in enumerate(pods):

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from kubernetes_tpu.analysis import sanitizer
 from kubernetes_tpu.api.types import Node, Pod
 from kubernetes_tpu.cache import Cache, SnapshotMirror
+from kubernetes_tpu.cache.term_probes import MAX_PROBES_ASKED
 from kubernetes_tpu.framework import config as cfg
 from kubernetes_tpu.framework.interface import (
     ActionType,
@@ -363,6 +364,9 @@ class Scheduler:
         # why _fast_gate_ok last said no (None: it said yes, or was not
         # asked); the loop books it once a batch, see _book_route
         self._fast_gate_refused: Optional[str] = None
+        # admits() evaluations of the gate's last call, and of the batch
+        # extension's predicate after it; booked beside the verdict
+        self._fast_gate_asked = 0
 
         # storage/DRA object views: assume caches for the objects plugins
         # optimistically mutate (PV/PVC/ResourceClaim, scheduler.go:298-302),
@@ -998,6 +1002,7 @@ class Scheduler:
                 )
                 rec = None
                 self._fast_gate_refused = None
+                self._fast_gate_asked = 0
                 if self._chain_quickcheck(fwk, group):
                     # the host's side of one chained dispatch (prep under
                     # the lock, tables, the dispatch call): its own phase —
@@ -1149,12 +1154,16 @@ class Scheduler:
         dispatch, wave or scan, its serial fallback included; ``route.fast``:
         the signature / resident path, the pods its extension popped
         included; ``route.direct``) and, where ``_fast_gate_ok``'s last verdict on it
-        was no, by the gate's reason (``fast_gate.refused.<reason>``)."""
+        was no, by the gate's reason (``fast_gate.refused.<reason>``); and the
+        placed terms that verdict asked (``fast_gate.probes_asked``, the
+        extension's asking included; absent where it asked none)."""
         self.phases.count("route." + route, len(group))
         if self._fast_gate_refused is not None:
             self.phases.count(
                 "fast_gate.refused." + self._fast_gate_refused, len(group)
             )
+        if self._fast_gate_asked:
+            self.phases.count("fast_gate.probes_asked", self._fast_gate_asked)
 
     # The loop's spans whose off-CPU seconds a per-layer metric reads: the
     # top-level ones that block on nothing by design (loop.off_cpu_s_per_kpod;
@@ -2221,16 +2230,19 @@ class Scheduler:
           nomination, the signature committer's capacity view is exact;
         * a placed pod's required anti-affinity (and symmetric term score)
           affects only newcomers its term selectors ADMIT — checked per
-          batch label-group against the cache's term-pod registry;
+          batch label-group against the cache's registry of DISTINCT
+          placed terms, at any count of placed term pods;
         * placed host-port users never constrain port-FREE pods (and port
           users are already signature-ineligible), so no port gate at all.
 
         Called from three places a batch may pass; ``_fast_gate_refused``
         keeps the last verdict's reason (``gang``, ``nomination``,
-        ``term_count``: more placed term pods than a probe is asked about,
-        ``term_admits``: a placed term admits a batch pod) for the loop to
-        book once, ``_book_route``.
+        ``term_admits``: a placed term admits a batch pod, ``term_count``:
+        the batch's label-groups have more candidate terms than one sweep
+        may ask, ``MAX_PROBES_ASKED``) for the loop to book once,
+        ``_book_route``.
         """
+        self._fast_gate_asked = 0
         self._fast_gate_refused = self._fast_gate_refusal(batch)
         return self._fast_gate_refused is None
 
@@ -2246,33 +2258,33 @@ class Scheduler:
             max_nom = max(p.priority for _, p in self.nominator.entries())
             if any(qp.pod.priority <= max_nom for qp in batch):
                 return "nomination"
-        n_t = self.cache.n_term_pods
-        if n_t:
-            if n_t > 64:
-                # probe checks would cost more than the scan saves
-                return "term_count"
-            from kubernetes_tpu.fastpath import _pod_probes
-
-            key = self.cache.term_version
-            cached = getattr(self, "_term_probe_cache", None)
-            if cached is None or cached[0] != key:
-                probes = []
-                for p in self.cache.term_pods.values():
-                    probes.extend(_pod_probes(p))
-                cached = self._term_probe_cache = (key, probes)
-            probes = cached[1]
-            seen: Dict[tuple, bool] = {}
+        if self.cache.n_term_pods:
+            # an immutable view: this runs outside _mu (_chain_quickcheck)
+            # while the informer thread counts term pods in and out
+            view = self.cache.term_probe_view()
+            seen = set()
             for qp in batch:
-                gk = (
-                    qp.pod.namespace,
-                    tuple(sorted(qp.pod.labels.items())),
-                )
-                hit = seen.get(gk)
-                if hit is None:
-                    hit = any(pr.admits(qp.pod) for pr in probes)
-                    seen[gk] = hit
-                if hit:
-                    return "term_admits"
+                pod = qp.pod
+                gk = (pod.namespace, tuple(sorted(pod.labels.items())))
+                if gk not in seen:
+                    seen.add(gk)
+                    refusal = self._ask_probes(view, pod)
+                    if refusal is not None:
+                        return refusal
+        return None
+
+    def _ask_probes(self, view, pod) -> Optional[str]:
+        """Ask the placed terms that could admit ``pod`` (``view``'s
+        candidates for its labels) whether one does: ``term_admits`` where
+        one does, ``term_count`` where asking would take the batch past the
+        work bound, else None.  ``_fast_gate_asked`` counts the asking."""
+        candidates = view.candidates(pod)
+        if self._fast_gate_asked + len(candidates) > MAX_PROBES_ASKED:
+            return "term_count"
+        for pr in candidates:
+            self._fast_gate_asked += 1
+            if pr.admits(pod):
+                return "term_admits"
         return None
 
     def _fast_pod_predicate(self, fwk, group_name: str, known_rows=None):
@@ -2294,14 +2306,7 @@ class Scheduler:
         max_nom = None
         if len(self.nominator):
             max_nom = max(p.priority for _, p in self.nominator.entries())
-        probes = ()
-        if self.cache.n_term_pods:
-            cached = getattr(self, "_term_probe_cache", None)
-            # _fast_gate_ok just ran on the seed batch, so the cache is hot;
-            # if it somehow isn't, refuse to extend rather than skip probes
-            if cached is None or cached[0] != self.cache.term_version:
-                return lambda qp: False
-            probes = cached[1]
+        view = self.cache.term_probe_view() if self.cache.n_term_pods else None
         group_hit: Dict[tuple, bool] = {}
         vocab = self.mirror.vocab
         n_lanes = self.mirror.nodes.allocatable.shape[1]
@@ -2336,12 +2341,11 @@ class Scheduler:
             for pl in host_scores:
                 if pl.score_relevant(p):
                     return False
-            if probes:
+            if view:
                 gk = (p.namespace, tuple(sorted(p.labels.items())))
                 hit = group_hit.get(gk)
                 if hit is None:
-                    hit = any(pr.admits(p) for pr in probes)
-                    group_hit[gk] = hit
+                    hit = group_hit[gk] = self._ask_probes(view, p) is not None
                 if hit:
                     return False
             memo = p.__dict__.get("_sigkey_memo")
