@@ -47,7 +47,16 @@ from kubernetes_tpu.api.types import Node, Pod
 from kubernetes_tpu.client import wire_codec
 from kubernetes_tpu.metrics import annotation
 
-WATCH_WINDOW = 4096  # events kept per resource (watch_cache.go capacity)
+# Events kept per resource.  Upstream's watch cache is not a fixed ring: it
+# doubles, up to ``defaultUpperBoundCapacity`` = 100 * 1024 events, while its
+# oldest event is younger than ``eventFreshDuration`` (75 s) — under a burst
+# it holds the burst (watch_cache.go ``resizeCacheLocked``).  A drain's binds
+# ARE such a burst (thousands of MODIFIED events a second while the reflector
+# shares the interpreter with the loop): at 4,096 a watcher a second behind
+# got 410 and re-LISTed every pod of the cluster in the middle of the drain
+# (10 s of decoding at 100,000 pods), so the window is the bound upstream's
+# cache grows to.
+WATCH_WINDOW = 100 * 1024
 
 # idle-watcher bookmark cadence: how long a stream sleeps ON THE CONDITION
 # VARIABLE before emitting a progress BOOKMARK.  Event delivery never

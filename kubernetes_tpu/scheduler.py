@@ -791,9 +791,17 @@ class Scheduler:
         if cp is not None and cp.enabled:
             cp.note_applied()
         self.gangs.note_removed(pod)
-        if pod.node_name:
+        ps = self.cache.pod_states.get(pod.uid)
+        if pod.node_name or ps is not None:
+            # ``ps`` without a node name on the event: a pod this scheduler
+            # ASSUMED whose deletion arrives in its last-known UNASSIGNED
+            # state — the reflector re-LISTed past a watch window that
+            # held both the bind's update and the delete (a 100,000-pod
+            # drain deleted at once overruns it), and a re-LIST reports a
+            # vanished object as it last saw it.  The pod is gone all the
+            # same: left in the cache it holds its node's resources for
+            # good (assumed pods do not expire)
             self._external_mutations += 1
-            ps = self.cache.pod_states.get(pod.uid)
             self._view_pod_removed(
                 ps.pod if ps is not None else pod,
                 ps.pod.node_name if ps is not None else None,
@@ -801,10 +809,10 @@ class Scheduler:
             self.cache.remove_pod(pod)
             self.queue.move_all_on_event(
                 ClusterEvent(EventResource.ASSIGNED_POD, ActionType.DELETE),
-                pod,
+                pod if pod.node_name else ps.pod,  # the hints read its node
                 None,
             )
-        else:
+        if not pod.node_name:
             self.queue.delete(pod)
         self.nominator.delete(pod)
 
@@ -4189,6 +4197,7 @@ class Scheduler:
                     ),
                     serial_tail=self.config.resident_serial_tail,
                 )
+                self.phases.count("resident.runs", 1)
             else:
                 choices_dev, holder["dev"] = ops_fp.sig_scan(
                     jnp.asarray(ids),

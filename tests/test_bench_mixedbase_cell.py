@@ -215,11 +215,13 @@ def test_the_routers_metrics_read_the_windows_counts_through_the_phase_reader(ru
     _res, seen, bench = run
     listed = {s["name"]: s for s in cells.layer_metrics(CELL, bench)}
     assert set(NEW_METRICS) <= set(listed)
-    # interpod-5k's metrics but those, and resident_round, which reads since the gate lets the batch through
+    # interpod-5k's metrics but those, and the two of the resident route, which read since the gate lets
+    # the batch through: resident_round, and the runs the drain was cut into (PR 44: one here)
     interpod = {s["name"] for s in cells.layer_metrics("interpod-5k.backlog", bench)}
-    assert set(listed) == (interpod - NOT_HERE) | {"kernels.stage_ms_per_kpod.resident_round.backlog"}
+    assert set(listed) == (interpod - NOT_HERE) | {
+        "kernels.stage_ms_per_kpod.resident_round.backlog", "loop.resident_runs_per_kpod.backlog"}
     per_cell = {w["name"]: {s["name"] for s in cells.layer_metrics(w["name"], bench)} for w in bench["workloads"]}
-    assert all(NEW_METRICS[0] in names for names in per_cell.values())  # the route: all six cells
+    assert all(NEW_METRICS[0] in names for names in per_cell.values())  # the route: every cell
     for gate_metric in NEW_METRICS[1:]:  # the gate's two: the three cells with placed terms
         assert sorted(c for c, names in per_cell.items() if gate_metric in names) == \
             ["antiaffinity-5k.backlog", "interpod-5k.backlog", CELL]

@@ -11,6 +11,7 @@ configuration this PR adds, ``sched-perf-prefaffinity-5k`` (upstream's
 """
 
 import dataclasses
+import functools
 import hashlib
 import json
 import os
@@ -357,3 +358,66 @@ def test_mixedbase_plain_pods_and_the_pinned_objects():
     placed = init + [s for s, _n in plan["base"]]
     placed_on = init_nodes + [n for _s, n in plan["base"]]
     assert _digest(T, R, nodes, placed, placed_on, plan) == MIXED_PINNED
+
+
+# ---- northstar-basic-10k (BASELINE.json's north star on :51's templates), PR 44 ----
+
+NORTHSTAR_CELL = "northstar-10k.backlog"
+# the same digest over what benchmarks/workload.py builds from the file PR 44
+# adds, seed 7: 10,000 nodes, 2,000 init pods, warm-up and measured backlogs of 65,536
+NORTHSTAR_PINNED = "71d3f56398a914b7c5187bc88fa9870243c06d129b527b0aa9b812828bfda49f"
+
+
+@functools.lru_cache(maxsize=1)  # 143,072 specs: built once for the cases below, which only read them
+def _northstar_built():
+    cell = cells.cell(NORTHSTAR_CELL)
+    return cell, _pr29._groups(cell["config"], cell["traffic"], cell["kind"], 7)
+
+
+def test_northstar_config_round_trips_with_no_key_refused():
+    """Every key of the file is one the harness builds (no ``KeyError``), at
+    the north star's node count and with ONE cut, the measured pods (100,000
+    -> 65,536, so that the window closes inside ``run_seconds`` on the chip:
+    the file's ``reduced_why``): a queue of 65,536 is four resident runs of
+    ``residentRunMax`` = 16,384."""
+    from kubernetes_tpu.framework.config import SchedulerConfiguration
+
+    cell, (nodes, init, init_nodes, plan) = _northstar_built()
+    cfg = cell["config"]
+    assert cfg["reduced"] == ["measure_pods"] and "89,088 of 100,000" in cfg["reduced_why"]
+    assert cfg["name"] == "northstar-basic-10k"
+    assert (len(nodes), len(init), len(plan["warm"]), len(plan["measure"])) == (10000, 2000, 65536, 65536)
+    assert len(set(init_nodes)) == 2000  # one init pod a node, on nodes drawn from the seed
+    assert len({workload.uid_of(s) for s in init + plan["warm"] + plan["measure"]}) == 133072
+    assert cell["kind"].pods_alive(plan) == 65536
+    run_max = SchedulerConfiguration().resident_run_max
+    assert len(plan["measure"]) == 4 * run_max
+
+
+@pytest.mark.parametrize("group,index", [("init", 0), ("warm", 0), ("measure", 0), ("measure", 16383),
+                                         ("measure", 16384), ("measure", 65535)])
+def test_northstar_pods_are_pod_default_and_equal_on_both_sides(group, index):
+    """ONE shape before and behind every run boundary: ``pod-default`` (100m /
+    500Mi, no labels, no term), equal field by field in the program's types
+    and the frozen reference's, built from the same spec."""
+    from kubernetes_tpu.api import types as T
+
+    _cell, (_nodes, init, init_nodes, plan) = _northstar_built()
+    spec = {"init": init, **plan}[group][index]
+    on = init_nodes[index] if group == "init" else ""
+    pod = workload.build_pod(T, spec, node_name=on)
+    assert dataclasses.asdict(pod) == dataclasses.asdict(workload.build_pod(RT, spec, node_name=on))
+    assert pod.labels == {} and pod.affinity is None and pod.topology_spread_constraints == ()
+    assert pod.containers[0].requests == {"cpu": "100m", "memory": "500Mi"}
+    assert pod.uid == f"default/{ {'init': 'init', 'warm': 'warm', 'measure': 'load'}[group] }-{index}"
+
+
+def test_northstar_config_builds_the_pinned_objects():
+    from kubernetes_tpu.api import resource as R
+    from kubernetes_tpu.api import types as T
+
+    _cell, (nodes, init, init_nodes, plan) = _northstar_built()
+    node = workload.build_node(T, R, nodes[0])
+    assert {str(workload.build_node(T, R, s).capacity) for s in nodes} == {str(node.capacity)}  # ONE node shape
+    assert (nodes[0]["name"], nodes[-1]["name"]) == ("scheduler-perf-0", "scheduler-perf-9999")
+    assert _digest(T, R, nodes, init, init_nodes, plan) == NORTHSTAR_PINNED

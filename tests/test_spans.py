@@ -474,7 +474,8 @@ COMMIT_PARTS = ("requests", "assume", "outcomes")
 CROSS_POD_CELLS = ["spread-5k.backlog", "interpod-5k.backlog", "antiaffinity-5k.backlog",
                    "mixedbase-5k.backlog-on-base"]
 ALL_CELLS = ["basic-5k.backlog", "spread-5k.backlog", "interpod-5k.backlog",
-             "unsched-5k.backlog-pending-first", "antiaffinity-5k.backlog", "mixedbase-5k.backlog-on-base"]
+             "unsched-5k.backlog-pending-first", "antiaffinity-5k.backlog", "mixedbase-5k.backlog-on-base",
+             "northstar-10k.backlog"]  # PR 44: the resident set's lists gained the north star's cell
 TOP_LEVEL_LOOP_SPANS = ("queue_pop", "chain_dispatch", "pack", "h2d", "commit", "wave_resolve",
                         "resident_rounds", "flush_binds")
 # metric -> (the phases its data file names, the cells BENCHMARK.json lists it for)
