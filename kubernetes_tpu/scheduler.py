@@ -4021,7 +4021,16 @@ class Scheduler:
                 holder["shadow"] = fp.FastCommitter(
                     nt, weights, check_fit=check_fit
                 )
-            self._fc_key = fc_key
+            # The committer is built from the MIRROR, so it is as old as
+            # the mirror's last sync, not as the counters fc_key reads: the
+            # pipelined prep between that sync and here (_try_dispatch_fast:
+            # a static eval, a first compile) runs outside self._mu, and an
+            # informer event that lands there moves the counters and not the
+            # mirror.  Keyed by the counters, a committer that never saw
+            # that node would pass for current at the next batch, and at
+            # every batch until another event; keyed by the sync it was
+            # built from, the next batch finds it stale and rebuilds it.
+            self._fc_key = getattr(self, "_mirror_sync", fc_key[:2]) + fc_key[2:]
             self._sig_objs: Dict[object, fp.Signature] = {}
             self._sig_list: List[fp.Signature] = []
 

@@ -164,7 +164,8 @@ class TestSameAsPerItem:
         assert one.bindings == per.bindings
         assert one.pods == per.pods
         # every acknowledged bind reads back equal through LIST
-        listed = {e["object"]["uid"]: e["object"]["node_name"] for e in s_one.list_payload("pods")["items"]}
+        # (a pending pod's node_name stands at its default: off the wire)
+        listed = {e["object"]["uid"]: e["object"].get("node_name", "") for e in s_one.list_payload("pods")["items"]}
         for (uid, node), result in zip(items, got):
             if result is None:
                 assert listed[uid] == node
