@@ -59,27 +59,15 @@ from typing import Any, Dict, List, Optional, Tuple, get_args, get_type_hints
 CT_JSON = "application/json"
 CT_BINARY = "application/vnd.ktpu.wire+binary"
 
-_TAG_NONE = 0x00
-_TAG_FALSE = 0x01
-_TAG_TRUE = 0x02
-_TAG_INT = 0x03
-_TAG_FLOAT = 0x04
-_TAG_STR = 0x05
-_TAG_SREF = 0x06
-_TAG_DREF = 0x07
-_TAG_LIST = 0x08
-_TAG_DICT = 0x09
-_TAG_NESTED = 0x0A
-
 _U32 = struct.Struct("!I")
 _F64 = struct.Struct("!d")
 
 # Lock-discipline registry (kubernetes_tpu.analysis): the codec is PURE —
-# the static table below is built once at import and never mutated, and
-# every encoder/decoder carries its state in locals/instance fields owned
-# by one call.  Registered empty so the checker vets any mutable state a
-# future change introduces here (encoders ride apiserver handler threads,
-# reflector threads, and the watch-cache append path concurrently).
+# the static table and the fragment tables below are built once at import
+# and never mutated, and every encode/decode call carries its state in the
+# locals of that one call.  Registered empty so the checker vets any mutable
+# state a future change introduces here (encoders ride apiserver handler
+# threads, reflector threads, and the watch-cache append path concurrently).
 # Plain assignment — analysis.core.module_literal reads ast.Assign only.
 _KTPU_GUARDED = {}
 
@@ -189,133 +177,136 @@ def _build_static_table() -> Tuple[str, ...]:
 
 
 STATIC_STRINGS: Tuple[str, ...] = _build_static_table()
-_STATIC_INDEX: Dict[str, int] = {s: i for i, s in enumerate(STATIC_STRINGS)}
 
 
 # ---------------------------------------------------------------------------
-# primitives
+# primitives and fragments
 # ---------------------------------------------------------------------------
 
 
-def _write_varint(out: List[bytes], n: int) -> None:
-    while True:
-        b = n & 0x7F
+def _varint(n: int) -> bytes:
+    """LEB128 of ``n`` — the general path, for what no fragment table holds
+    (an rv, a large int, a length or an index past one byte)."""
+    out = bytearray()
+    while n > 0x7F:
+        out.append(n & 0x7F | 0x80)
         n >>= 7
-        if n:
-            out.append(bytes((b | 0x80,)))
-        else:
-            out.append(bytes((b,)))
-            return
+    out.append(n)
+    return bytes(out)
 
 
-def _read_varint(buf: bytes, pos: int) -> Tuple[int, int]:
-    shift = 0
-    n = 0
-    while True:
-        b = buf[pos]
-        pos += 1
-        n |= (b & 0x7F) << shift
-        if not b & 0x80:
-            return n, pos
-        shift += 7
+def _heads(tag: int) -> Tuple[bytes, ...]:
+    """``tag`` + the one-byte varint of every n below 128, as one ``bytes``."""
+    return tuple(bytes((tag, n)) for n in range(0x80))
 
 
-def _zigzag(n: int) -> int:
-    return (n << 1) if n >= 0 else (-(n << 1) - 1)
+# FRAGMENTS: what is constant on the wire, built once at import from the same
+# STATIC_STRINGS both sides derive — looked up where the codec used to compute
+# them a byte a statement.  They ARE the frame format: every fragment is byte
+# for byte what ``tag + _varint(n)`` gives (pinned in tests/test_wire_codec.py).
+_STR_HEAD, _DREF, _LIST_HEAD, _DICT_HEAD, _NESTED_HEAD = (
+    _heads(tag) for tag in (0x05, 0x07, 0x08, 0x09, 0x0A)
+)
+_SREF: Dict[str, bytes] = {
+    s: b"\x06" + _varint(i) for i, s in enumerate(STATIC_STRINGS)
+}
 
 
-def _unzigzag(z: int) -> int:
-    return (z >> 1) if not z & 1 else -((z + 1) >> 1)
+def _head(table: Tuple[bytes, ...], n: int) -> bytes:
+    """``table``'s tag + varint(n), for any n (the encoder's hot loops spell
+    this out with their tag's literal instead of calling)."""
+    return table[n] if n < 0x80 else table[0][:1] + _varint(n)
+
+
+def _int(n: int) -> bytes:
+    return b"\x03" + _varint((n << 1) if n >= 0 else (-(n << 1) - 1))  # zigzag
 
 
 # ---------------------------------------------------------------------------
 # encoder
 # ---------------------------------------------------------------------------
 
-
-class _Encoder:
-    """One frame's encoding context (the dynamic string table is
-    per-frame; a nested blob carries its own)."""
-
-    def __init__(self):
-        self.out: List[bytes] = []
-        self.dynamic: Dict[str, int] = {}
-
-    def value(self, v: Any) -> None:
-        out = self.out
-        if v is None:
-            out.append(b"\x00")
-        elif v is True:
-            out.append(b"\x02")
-        elif v is False:
-            out.append(b"\x01")
-        elif isinstance(v, int):
-            out.append(b"\x03")
-            _write_varint(out, _zigzag(v))
-        elif isinstance(v, float):
-            out.append(b"\x04")
-            out.append(_F64.pack(v))
-        elif isinstance(v, str):
-            self.string(v)
-        elif isinstance(v, (list, tuple)):
-            out.append(b"\x08")
-            _write_varint(out, len(v))
-            for x in v:
-                self.value(x)
-        elif isinstance(v, dict):
-            out.append(b"\x09")
-            _write_varint(out, len(v))
-            for k, x in v.items():
-                if not isinstance(k, str):
-                    raise TypeError(f"wire_codec: non-str dict key {k!r}")
-                self.string(k)
-                self.value(x)
-        else:
-            raise TypeError(f"wire_codec: unsupported {type(v)!r}")
-
-    def string(self, s: str) -> None:
-        out = self.out
-        idx = _STATIC_INDEX.get(s)
-        if idx is not None:
-            out.append(b"\x06")
-            _write_varint(out, idx)
-            return
-        idx = self.dynamic.get(s)
-        if idx is not None:
-            out.append(b"\x07")
-            _write_varint(out, idx)
-            return
-        self.dynamic[s] = len(self.dynamic)
-        raw = s.encode()
-        out.append(b"\x05")
-        _write_varint(out, len(raw))
-        out.append(raw)
-
-    def splice(self, nested_blob: bytes) -> None:
-        """Append a pre-encoded NESTED blob (from ``encode_nested``) where
-        a value is expected — the zero-copy path: the blob's own dynamic
-        table means no re-encode and no table interaction."""
-        self.out.append(nested_blob)
-
-    def body(self) -> bytes:
-        return b"".join(self.out)
+# a subclass is encoded as the first of these it is an instance of: the
+# order of the isinstance chain this dispatch replaced
+_BASES = (int, float, str, list, tuple, dict)
 
 
 def encode_value(v: Any) -> bytes:
-    """Value → frame BODY bytes (no length prefix)."""
-    enc = _Encoder()
-    enc.value(v)
-    return enc.body()
+    """Value → frame BODY bytes (no length prefix).  One call is one
+    frame's encoding context: the dynamic string table is per-frame (a
+    nested blob carries its own)."""
+    out: List[bytes] = []
+    append = out.append
+    dynamic: Dict[str, int] = {}
+    sref = _SREF.get
+
+    def string(s: str) -> None:
+        frag = sref(s)
+        if frag is None:
+            idx = dynamic.get(s)
+            if idx is None:
+                dynamic[s] = len(dynamic)
+                raw = s.encode()
+                n = len(raw)
+                append(_STR_HEAD[n] if n < 0x80 else b"\x05" + _varint(n))
+                append(raw)
+                return
+            frag = _DREF[idx] if idx < 0x80 else b"\x07" + _varint(idx)
+        append(frag)
+
+    def value(v: Any, t: type) -> None:
+        # t: type(v), or the base of _BASES a subclass is encoded as
+        if t is str:
+            string(v)
+        elif t is dict:
+            n = len(v)
+            append(_DICT_HEAD[n] if n < 0x80 else b"\x09" + _varint(n))
+            for k, x in v.items():
+                frag = sref(k) if type(k) is str else None
+                if frag is not None:
+                    append(frag)  # a static key: its fragment, no call
+                elif isinstance(k, str):
+                    string(k)
+                else:
+                    raise TypeError(f"wire_codec: non-str dict key {k!r}")
+                t = type(x)
+                if t is str:
+                    string(x)
+                else:
+                    value(x, t)
+        elif t is list or t is tuple:
+            n = len(v)
+            append(_LIST_HEAD[n] if n < 0x80 else b"\x08" + _varint(n))
+            for x in v:
+                value(x, type(x))
+        elif t is int:
+            append(_int(v))
+        elif v is None:
+            append(b"\x00")
+        elif t is bool:
+            append(b"\x02" if v else b"\x01")
+        elif t is float:
+            append(b"\x04")
+            append(_F64.pack(v))
+        else:
+            for base in _BASES:
+                if isinstance(v, base):
+                    return value(v, base)
+            raise TypeError(f"wire_codec: unsupported {type(v)!r}")
+
+    try:
+        value(v, type(v))
+    finally:
+        value = None  # the closure names itself: leave the collector no cycle
+    return b"".join(out)
 
 
 def encode_nested(v: Any) -> bytes:
-    """Value → a NESTED blob: splice it into any frame via
-    ``_Encoder.splice`` / the event and list assemblers below."""
+    """Value → a NESTED blob: the event and list assemblers below splice
+    it where a value is expected — the zero-copy path: the blob's own
+    dynamic table means no re-encode and no table interaction."""
     body = encode_value(v)
-    out: List[bytes] = [b"\x0a"]
-    _write_varint(out, len(body))
-    out.append(body)
-    return b"".join(out)
+    return _head(_NESTED_HEAD, len(body)) + body
 
 
 def encode_frame(v: Any) -> bytes:
@@ -324,22 +315,29 @@ def encode_frame(v: Any) -> bytes:
     return _U32.pack(len(body)) + body
 
 
+def _event_heads(etype: str) -> Tuple[bytes, bytes]:
+    """An event body up to its rv, in the 2-key and the 3-key form."""
+    rest = _SREF["type"] + encode_value(etype) + _SREF["rv"]
+    return b"\x09\x02" + rest, b"\x09\x03" + rest
+
+
+_EVENT_HEADS: Dict[str, Tuple[bytes, bytes]] = {
+    s: _event_heads(s) for s in STATIC_STRINGS
+}
+_OBJECT_KEY = _SREF["object"]
+_LIST_FRAME_HEAD = b"\x09\x02" + _SREF["resourceVersion"]
+
+
 def encode_event(etype: str, rv: int, nested_obj: Optional[bytes]) -> bytes:
     """One watch event as a full frame:
     ``{"type": etype, "rv": rv, "object": <spliced blob>}`` — the blob is
     the object envelope encoded ONCE at watch-cache append time and
     shared across every watcher's stream and the binary list path."""
-    enc = _Encoder()
-    enc.out.append(b"\x09")
-    _write_varint(enc.out, 3 if nested_obj is not None else 2)
-    enc.string("type")
-    enc.string(etype)
-    enc.string("rv")
-    enc.value(rv)
-    if nested_obj is not None:
-        enc.string("object")
-        enc.splice(nested_obj)
-    body = enc.body()
+    short, long = _EVENT_HEADS.get(etype) or _event_heads(etype)
+    if nested_obj is None:
+        body = short + _int(rv)
+    else:
+        body = b"".join((long, _int(rv), _OBJECT_KEY, nested_obj))
     return _U32.pack(len(body)) + body
 
 
@@ -349,17 +347,10 @@ def encode_list_frame(rv: int, nested_items: List[bytes]) -> bytes:
     the per-object blobs maintained by the watch cache, NOT re-encoded
     per request (the JSON list path re-serializes the full object set on
     every call; this path just concatenates)."""
-    enc = _Encoder()
-    enc.out.append(b"\x09")
-    _write_varint(enc.out, 2)
-    enc.string("resourceVersion")
-    enc.value(rv)
-    enc.string("items")
-    enc.out.append(b"\x08")
-    _write_varint(enc.out, len(nested_items))
-    for blob in nested_items:
-        enc.splice(blob)
-    body = enc.body()
+    body = b"".join(
+        (_LIST_FRAME_HEAD, _int(rv), _SREF["items"],
+         _head(_LIST_HEAD, len(nested_items)), *nested_items)
+    )
     return _U32.pack(len(body)) + body
 
 
@@ -368,57 +359,92 @@ def encode_list_frame(rv: int, nested_items: List[bytes]) -> bytes:
 # ---------------------------------------------------------------------------
 
 
-def _decode(buf: bytes, pos: int, dynamic: List[str]) -> Tuple[Any, int]:
-    tag = buf[pos]
-    pos += 1
-    if tag == _TAG_NONE:
-        return None, pos
-    if tag == _TAG_FALSE:
-        return False, pos
-    if tag == _TAG_TRUE:
-        return True, pos
-    if tag == _TAG_INT:
-        z, pos = _read_varint(buf, pos)
-        return _unzigzag(z), pos
-    if tag == _TAG_FLOAT:
-        return _F64.unpack_from(buf, pos)[0], pos + 8
-    if tag == _TAG_STR:
-        n, pos = _read_varint(buf, pos)
-        s = buf[pos : pos + n].decode()
-        dynamic.append(s)
-        return s, pos + n
-    if tag == _TAG_SREF:
-        i, pos = _read_varint(buf, pos)
-        return STATIC_STRINGS[i], pos
-    if tag == _TAG_DREF:
-        i, pos = _read_varint(buf, pos)
-        return dynamic[i], pos
-    if tag == _TAG_LIST:
-        n, pos = _read_varint(buf, pos)
-        out = []
-        for _ in range(n):
-            v, pos = _decode(buf, pos, dynamic)
-            out.append(v)
-        return out, pos
-    if tag == _TAG_DICT:
-        n, pos = _read_varint(buf, pos)
-        d = {}
-        for _ in range(n):
-            k, pos = _decode(buf, pos, dynamic)
-            v, pos = _decode(buf, pos, dynamic)
-            d[k] = v
-        return d, pos
-    if tag == _TAG_NESTED:
-        n, pos = _read_varint(buf, pos)
-        v, _ = _decode(buf, pos, [])  # fresh table: self-contained blob
-        return v, pos + n
-    raise ValueError(f"wire_codec: bad tag 0x{tag:02x} at {pos - 1}")
+def _varint_at(buf: bytes, pos: int) -> Tuple[int, int]:
+    """The general varint read — past one byte, which is read inline."""
+    shift = n = 0
+    while True:
+        b = buf[pos]
+        pos += 1
+        n |= (b & 0x7F) << shift
+        if b < 0x80:
+            return n, pos
+        shift += 7
 
 
 def decode_value(body: bytes) -> Any:
     """Frame BODY bytes → value (the exact structure ``json.loads`` of
-    the JSON encoding would produce)."""
-    v, pos = _decode(body, 0, [])
+    the JSON encoding would produce).  ``pos`` and the dynamic table live
+    in this call's closure: no ``(value, pos)`` tuple a value."""
+    pos = 0
+    dynamic: List[str] = []
+
+    def value() -> Any:
+        nonlocal pos, dynamic
+        tag = body[pos]
+        pos += 1
+        if tag == 0x04:
+            pos += 8
+            return _F64.unpack_from(body, pos - 8)[0]
+        if tag > 0x0A or tag < 0x03:
+            if tag > 0x02:
+                raise ValueError(f"wire_codec: bad tag 0x{tag:02x} at {pos - 1}")
+            return (None, False, True)[tag]
+        n = body[pos]  # every other tag carries a varint: one byte, mostly
+        if n < 0x80:
+            pos += 1
+        else:
+            n, pos = _varint_at(body, pos)
+        if tag == 0x09:
+            d = {}
+            p = pos
+            for _ in range(n):
+                # a static key and a static or short inline string — nearly
+                # every pair of the traffic — are read here, without a call
+                if body[p] == 0x06 and (m := body[p + 1]) < 0x80:
+                    k = STATIC_STRINGS[m]
+                    p += 2
+                else:
+                    pos = p
+                    k = value()
+                    p = pos
+                tag = body[p]
+                if tag == 0x05 and (m := body[p + 1]) < 0x80:
+                    p += 2 + m
+                    d[k] = s = body[p - m : p].decode()
+                    dynamic.append(s)
+                elif tag == 0x06 and (m := body[p + 1]) < 0x80:
+                    d[k] = STATIC_STRINGS[m]
+                    p += 2
+                else:
+                    pos = p
+                    d[k] = value()
+                    p = pos
+            pos = p
+            return d
+        if tag == 0x05:
+            s = body[pos : pos + n].decode()
+            pos += n
+            dynamic.append(s)
+            return s
+        if tag == 0x06:
+            return STATIC_STRINGS[n]
+        if tag == 0x07:
+            return dynamic[n]
+        if tag == 0x08:
+            return [value() for _ in range(n)]
+        if tag == 0x03:
+            return (n >> 1) if not n & 1 else -((n + 1) >> 1)  # zigzag
+        end = pos + n  # NESTED: a self-contained body, its own fresh table
+        outer, dynamic = dynamic, []
+        v = value()
+        dynamic = outer
+        pos = end
+        return v
+
+    try:
+        v = value()
+    finally:
+        value = None  # the closure names itself: leave the collector no cycle
     if pos != len(body):
         raise ValueError(
             f"wire_codec: {len(body) - pos} trailing bytes after value"
