@@ -35,6 +35,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from kubernetes_tpu import routing
 from kubernetes_tpu.planner.forks import Fork, collect_clones, pack_forks
 
 # Steering bonus for target-node what-ifs: large enough to dominate every
@@ -95,11 +96,8 @@ def _pod_ineligible(sched, fwk, pod) -> Optional[str]:
     for e in sched.extenders:
         if e.is_interested(pod):
             return "extender"
-    for pl in sched._normalizing_score_plugins(fwk):
+    for pl in routing.normalizing_score_plugins(fwk) + routing.weighted_host_scores(fwk):
         if pl.score_relevant(pod):
-            return "host_score"
-    for pl in fwk.host_score_plugins():
-        if fwk.score_weights.get(pl.name, 0) and pl.score_relevant(pod):
             return "host_score"
     if pod.pvc_names() and not sched._vol_kernel_ok(pod):
         return "volume_shape"

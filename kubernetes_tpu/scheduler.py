@@ -20,16 +20,17 @@ import threading
 import time
 import weakref
 from concurrent.futures import ThreadPoolExecutor
+from types import SimpleNamespace
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from kubernetes_tpu import routing
 from kubernetes_tpu.analysis import sanitizer
 from kubernetes_tpu.api.types import Node, Pod
 from kubernetes_tpu.cache import Cache, SnapshotMirror
-from kubernetes_tpu.cache.term_probes import MAX_PROBES_ASKED
 from kubernetes_tpu.framework import config as cfg
 from kubernetes_tpu.framework.interface import (
     ActionType,
@@ -84,6 +85,7 @@ _KTPU_GUARDED = {
             "_repack_mirror",
             "_sync_mirror_external",
             "_wave_tables",
+            "_wave_tables_for",
             "_hostnames_unique",
             "_pull_gang_siblings",
         ],
@@ -359,14 +361,20 @@ class Scheduler:
         self._inflight_binds: List = []
         self._bind_buffer: List = []
         self._bulk_bind_buffer: List = []  # _BulkBindTask runs (fast path)
-        # chained-dispatch state (see _try_dispatch_chained)
+        # ----- the routing state the offers share; written on the loop
+        # thread, under the lock named
+        # the chained cluster, None = restart from the mirror: _dispatch_chained (_mu); dropped by any commit outside it
         self._chain = None
-        # why _fast_gate_ok last said no (None: it said yes, or was not
-        # asked); the loop books it once a batch, see _book_route
-        self._fast_gate_refused: Optional[str] = None
-        # admits() evaluations of the gate's last call, and of the batch
-        # extension's predicate after it; booked beside the verdict
-        self._fast_gate_asked = 0
+        # the fast lineage's committer + device state: _fast_dispatch builds it; _reform_mesh resets its device copy (_mu)
+        self._fastdev = None
+        # the _fast_key _fastdev was built under, by the mirror sync it read: _fast_dispatch, with the holder
+        self._fc_key = None
+        # (_external_mutations, _nonfast_commits) at the mirror's last repack: _repack_mirror (_mu)
+        self._mirror_sync = None
+        # commits the fast committer did not see (scan, wave, one-pod): _commit, _commit_fast_bulk (_mu)
+        self._nonfast_commits = 0
+        # the fast gate's last verdict and asking: _fast_gate_ok + the extension's predicate; booked by _book_route
+        self._gate = SimpleNamespace(refused=None, asked=0)
 
         # storage/DRA object views: assume caches for the objects plugins
         # optimistically mutate (PV/PVC/ResourceClaim, scheduler.go:298-302),
@@ -1008,44 +1016,45 @@ class Scheduler:
                 fwk = self.profiles.get(
                     profile_name, next(iter(self.profiles.values()))
                 )
-                rec = None
-                self._fast_gate_refused = None
-                self._fast_gate_asked = 0
+                self._gate.refused, self._gate.asked = None, 0
+                # the cascade (routing.py): each engine is OFFERED the batch
+                # in turn; the first that does not decline takes it
+                offers = [("fast", self._try_dispatch_fast)]
                 if self._chain_quickcheck(fwk, group):
-                    # the host's side of one chained dispatch (prep under
-                    # the lock, tables, the dispatch call): its own phase —
-                    # the chained path books no pack/h2d/device
-                    with self._span("chain_dispatch"):
-                        rec = self._try_dispatch_chained(
-                            fwk, group, outcomes, can_restart=not pending
-                        )
-                    if rec == "flush":
-                        flush(0)
-                        with self._span("chain_dispatch"):
-                            rec = self._try_dispatch_chained(
-                                fwk, group, outcomes, can_restart=True
-                            )
-                    # a record, "handled" or the serial fallback: its batch
-                    if rec is not None:
-                        self._book_route("chained", group)
-                if isinstance(rec, tuple) and rec and rec[0] == "serial":
-                    # breaker fallback for an abandoned chained dispatch:
-                    # settle the pipeline (its commits must land first),
-                    # then drain the live batch serially OUTSIDE the
-                    # scheduler lock
-                    flush(0)
-                    t0 = time.perf_counter()
-                    outs = self._schedule_batch_serial(fwk, rec[1])
-                    self._record_batch_metrics(
-                        profile_name, rec[1], outs, time.perf_counter() - t0
+                    offers.insert(0, ("chained", self._try_dispatch_chained))
+                for engine, offer in offers:
+                    pipe = routing.Pipeline(
+                        not pending, all(r.get("kind") == "fast" for r in pending)
                     )
+                    ans = offer(fwk, group, outcomes, pipe)
+                    if ans.status is routing.Offer.SETTLE:
+                        flush(0)
+                        ans = offer(fwk, group, outcomes, routing.Pipeline(True, True))
+                    if ans.status is not routing.Offer.DECLINED:
+                        break
+                else:
+                    # direct path: settle the pipeline first — its commits
+                    # must land before a non-chained dispatch reads host
+                    # state — and drop the chain (these commits happen
+                    # outside it)
+                    flush(0)
+                    self._chain = None
+                    t0 = time.perf_counter()
+                    outs = self._schedule_batch(group)
+                    dt = time.perf_counter() - t0
+                    self._book_route("direct", group)
+                    self._record_batch_metrics(profile_name, group, outs, dt)
                     outcomes.extend(outs)
                     continue
-                if isinstance(rec, dict):
+                # a record, nothing left or the serial fallback: its batch
+                self._book_route(engine, group)
+                if ans.status is routing.Offer.IN_FLIGHT:
                     # pipelined: keep up to two batches in flight so the
                     # harvest of batch k overlaps k+1's device compute AND
                     # k+2's dispatch (the async result copy finishes before
-                    # the blocking fetch).  With Reserve/Permit plugins in
+                    # the blocking fetch; the fast path's state chains on
+                    # device the same way, so the device link's round trip
+                    # hides behind host work).  With Reserve/Permit plugins in
                     # play a commit can realistically fail (and forget), so
                     # harvest eagerly — one batch in flight — to keep the
                     # optimism window close to the reference's (a forget is
@@ -1054,58 +1063,24 @@ class Scheduler:
                     # already proved irrelevant (the default volumebinding/
                     # DRA shape), their walks are no-ops for these batches —
                     # keep the full two-deep double buffer.
-                    pending.append(rec)
-                    flush(1 if self._rp_can_fail(fwk) else 2)
-                    continue
-                if rec == "handled":
-                    continue
-                # pipelined fast path: same ≤2-in-flight discipline as the
-                # chain — the sig_scan kernel's state chains on device, so
-                # the harvest of batch k overlaps k+1's dispatch and the
-                # device link's round trip hides behind host work
-                frec = self._try_dispatch_fast(
-                    fwk,
-                    group,
-                    outcomes,
-                    chain_settled=not any(
-                        r.get("kind") != "fast" for r in pending
-                    ),
-                    pipeline_empty=not pending,
-                )
-                if frec == "flush":
-                    flush(0)
-                    frec = self._try_dispatch_fast(
-                        fwk, group, outcomes, chain_settled=True
+                    pending.append(ans.record)
+                    flush(
+                        0
+                        if ans.record.get("harvest_now")
+                        else 1 if self._rp_can_fail(fwk) else 2
                     )
-                if frec is not None:  # a record or "handled"
-                    self._book_route("fast", group)
-                if isinstance(frec, dict):
-                    pending.append(frec)
-                    if frec.get(
-                        "rstats_dev"
-                    ) is not None and not self.config.resident_serial_tail:
-                        # a resident run may finish its conflict tail on
-                        # the HOST committer, after which the chained
-                        # device state is stale — harvest immediately so
-                        # no later dispatch rides a state that a host
-                        # tail is about to overtake
-                        flush(0)
-                    else:
-                        flush(1 if self._rp_can_fail(fwk) else 2)
-                    continue
-                if frec == "handled":
-                    continue
-                # direct path: settle the pipeline first — its commits must
-                # land before a non-chained dispatch reads host state — and
-                # drop the chain (these commits happen outside it)
-                flush(0)
-                self._chain = None
-                t0 = time.perf_counter()
-                outs = self._schedule_batch(group)
-                dt = time.perf_counter() - t0
-                self._book_route("direct", group)
-                self._record_batch_metrics(profile_name, group, outs, dt)
-                outcomes.extend(outs)
+                elif ans.status is routing.Offer.SERIAL:
+                    # breaker fallback for an abandoned chained dispatch:
+                    # settle the pipeline (its commits must land first),
+                    # then drain the live batch serially OUTSIDE the
+                    # scheduler lock
+                    flush(0)
+                    t0 = time.perf_counter()
+                    outs = self._schedule_batch_serial(fwk, ans.batch)
+                    self._record_batch_metrics(
+                        profile_name, ans.batch, outs, time.perf_counter() - t0
+                    )
+                    outcomes.extend(outs)
             # hand this batch's buffered binds to the workers — they overlap
             # the next batch's device dispatch (the async binding pipeline)
             self._flush_binds()
@@ -1166,12 +1141,12 @@ class Scheduler:
         placed terms that verdict asked (``fast_gate.probes_asked``, the
         extension's asking included; absent where it asked none)."""
         self.phases.count("route." + route, len(group))
-        if self._fast_gate_refused is not None:
+        if self._gate.refused is not None:
             self.phases.count(
-                "fast_gate.refused." + self._fast_gate_refused, len(group)
+                "fast_gate.refused." + self._gate.refused, len(group)
             )
-        if self._fast_gate_asked:
-            self.phases.count("fast_gate.probes_asked", self._fast_gate_asked)
+        if self._gate.asked:
+            self.phases.count("fast_gate.probes_asked", self._gate.asked)
 
     # The loop's spans whose off-CPU seconds a per-layer metric reads: the
     # top-level ones that block on nothing by design (loop.off_cpu_s_per_kpod;
@@ -1324,7 +1299,7 @@ class Scheduler:
             )
             self._dc_cache = DeviceClusterCache(mesh=new_mesh)
             self._chain = None
-            holder = getattr(self, "_fastdev", None)
+            holder = self._fastdev
             if holder is not None:
                 # the old placement's device copy is suspect — the host
                 # committer stays authoritative; rematerialize on the
@@ -1626,63 +1601,44 @@ class Scheduler:
                 if wl_out is not None:
                     return wl_out + self._schedule_batch(rest)
 
+        # Host-stateful Filter plugins (volumebinding/DRA class) judge
+        # against cache state that earlier commits in the SAME batch
+        # mutate — their veto masks can't be batched; extender webhooks
+        # are serial per-pod HTTP round-trips by protocol.  Pods either
+        # could act on (cheap spec check — routing's one-pod gates)
+        # degrade to one-pod cycles (the reference's native granularity,
+        # schedule_one.go:65); contiguous runs of clean pods stay on the
+        # batched device path.  Runs preserve queue order, so decisions
+        # stay sequential-equivalent.
+        one_pod = self._pod_gates(fwk).one_pod
         if len(batch) > 1:
-            # Host-stateful Filter plugins (volumebinding/DRA class) judge
-            # against cache state that earlier commits in the SAME batch
-            # mutate — their veto masks can't be batched; extender webhooks
-            # are serial per-pod HTTP round-trips by protocol.  Pods either
-            # could act on (cheap spec check — maybe_relevant/is_interested)
-            # degrade to one-pod cycles (the reference's native granularity,
-            # schedule_one.go:65); contiguous runs of clean pods stay on the
-            # batched device path.  Runs preserve queue order, so decisions
-            # stay sequential-equivalent.
-            hf = fwk.host_filter_plugins()
-            ns_plugins = self._normalizing_score_plugins(fwk)
-            any_nom = any(qp.pod.nominated_node_name for qp in batch)
-            if hf or self.extenders or ns_plugins or any_nom:
-                run: List = []
-                split = False
-                for qp in batch:
-                    if (
-                        not qp.pod.nominated_node_name
-                        and not any(p.maybe_relevant(qp.pod) for p in hf)
-                        and not any(
-                            e.is_interested(qp.pod) for e in self.extenders
-                        )
-                        and not any(
-                            p.score_relevant(qp.pod) for p in ns_plugins
-                        )
-                    ):
-                        run.append(qp)
-                        continue
-                    split = True
-                    if run:
-                        outcomes.extend(self._schedule_batch(run))
-                        run = []
-                    if qp.pod.nominated_node_name:
-                        outcomes.extend(self._schedule_one_nominated(fwk, qp))
-                    else:
-                        outcomes.extend(self._schedule_batch([qp]))
-                if split:
-                    if run:
-                        outcomes.extend(self._schedule_batch(run))
-                    return outcomes
-
-        if len(batch) == 1 and batch[0].pod.nominated_node_name:
-            return self._schedule_one_nominated(fwk, batch[0])
-
-        if len(batch) == 1 and (
-            any(e.is_interested(batch[0].pod) for e in self.extenders)
-            # a host Score plugin with a CUSTOM normalize must score over
-            # the true feasible set (runtime/framework.go:1158 runs
-            # NormalizeScore post-Filter) — the oracle one-pod cycle does;
-            # the batched extra_score merge cannot
-            or any(
-                p.score_relevant(batch[0].pod)
-                for p in self._normalizing_score_plugins(fwk)
-            )
-        ):
-            return self._schedule_one_extender(fwk, batch[0])
+            run: List = []
+            split = False
+            for qp in batch:
+                reason = one_pod(qp.pod)
+                if reason is None:
+                    run.append(qp)
+                    continue
+                split = True
+                if run:
+                    outcomes.extend(self._schedule_batch(run))
+                    run = []
+                if reason == routing.NOMINATED_NODE:
+                    outcomes.extend(self._schedule_one_nominated(fwk, qp))
+                else:
+                    outcomes.extend(self._schedule_batch([qp]))
+            if split:
+                if run:
+                    outcomes.extend(self._schedule_batch(run))
+                return outcomes
+        else:
+            # (a pod that only a host Filter finds relevant stays here: its
+            # veto mask is exact for a batch of one)
+            reason = one_pod(batch[0].pod)
+            if reason == routing.NOMINATED_NODE:
+                return self._schedule_one_nominated(fwk, batch[0])
+            if reason in (routing.EXTENDER, routing.NORMALIZING_SCORE):
+                return self._schedule_one_extender(fwk, batch[0])
 
         # Host-side preparation reads cache/mirror/assume-cache state that
         # async binding workers mutate under self._mu — hold it for the
@@ -1747,10 +1703,7 @@ class Scheduler:
                 not active_host
                 and not active_scores
                 and self._fast_gate_ok(batch)
-                # the signature committer assumes the default fit scoring,
-                # full-width evaluation, and first-max tie-break
-                and fwk.fit_strategy() == gang.DEFAULT_FIT_STRATEGY
-                and not self._sampling_active(fwk)
+                and self._signature_profile(fwk)
             ):
                 fast = self._try_fast_schedule(
                     fwk, state, batch, enabled, weights, outcomes
@@ -1803,32 +1756,9 @@ class Scheduler:
                 (pb.want_ppk != PAD).any() or (self.mirror.nodes.used_ppk != PAD).any()
             )
 
-            # 1a'. WAVE eligibility: batches carrying their own cross-pod
-            # constraints — spread/inter-pod terms OR in-batch host ports
-            # — ride the speculative wave dispatch (ops/wave.py):
-            # speculation + term-factored conflict resolution,
-            # bit-identical to the scan at a fraction of its per-step
-            # cost.  Port users ride the [Tpt, N] occupancy carry and
-            # sampling-compat / seeded-tie drains replay their window +
-            # rotation per step, so neither falls back any more; the only
-            # remaining disqualifier is duplicate hostname labels
-            # (_wave_tables → mirror.hostnames_unique).  Every fallback
-            # bumps scheduler_tpu_wave_fallback_total{reason=}.
-            wave_shaped = bool(
-                (pb.aff_kind != PAD).any()
-                or (pb.tsc_topo_key != PAD).any()
-                or (pb.want_ppk != PAD).any()
-            )
-            wt = None
-            if wave_shaped:
-                if not self.config.wave_dispatch:
-                    self.prom.wave_fallback.inc(reason="kill_switch")
-                elif self._breaker_blocked("wave.wave_run"):
-                    pass  # open breaker: the batch rides the scan fallback
-                else:
-                    wt = self._wave_tables(pb)
-                    if wt is None:
-                        self.prom.wave_fallback.inc(reason="dup_hostname")
+            # 1a'. the wave where the batch is shaped for it; an open
+            # wave.wave_run breaker sends it to the scan fallback
+            wt = self._wave_tables_for(pb, breaker="wave.wave_run")
             # an OPEN gang-scan breaker has no device engine left under it:
             # the batch degrades to one-pod host-oracle cycles (the ladder's
             # floor, bit-identical by the parity property)
@@ -2157,45 +2087,32 @@ class Scheduler:
         # attempt — the direct path owns that state
         if self._sampling_active(fwk):
             return False
-        # gang members take the direct path's workloads dispatch (all-or-
-        # nothing admission with device-side rollback, ops/coscheduling.py)
-        if self.config.gang_dispatch and any(
-            wlg.group_key_of(qp.pod) is not None for qp in batch
-        ):
-            return False
         # the device append doesn't splice node port-usage rows, so pods
         # with host ports must take the direct path (which resyncs the
         # snapshot from host state every batch)
         if any(qp.pod.host_ports() for qp in batch):
             return False
         # nominated pods take the single-node fast path via the direct
-        # path's split (schedule_one.go:490)
-        if any(qp.pod.nominated_node_name for qp in batch):
-            return False
-        hf = fwk.host_filter_plugins()
-        if any(p.maybe_relevant(qp.pod) for p in hf for qp in batch):
-            return False
-        for p in fwk.host_score_plugins():
-            if fwk.score_weights.get(p.name, 0) and any(
-                p.score_relevant(qp.pod) for qp in batch
-            ):
+        # path's split (schedule_one.go:490); one-pod-only score plugins
+        # (normalize overrides, extended-resource fit strategies) force the
+        # same split routing
+        gates = self._pod_gates(fwk)
+        for qp in batch:
+            if gates.one_pod(qp.pod) or gates.host_score(qp.pod):
                 return False
-        # one-pod-only score plugins (normalize overrides, extended-resource
-        # fit strategies) force the direct path's split routing
-        for p in self._normalizing_score_plugins(fwk):
-            if any(p.score_relevant(qp.pod) for qp in batch):
-                return False
+        if not self._fast_gate_ok(batch, gates):
+            # gang members take the direct path's workloads dispatch (all-or-
+            # nothing admission with device-side rollback,
+            # ops/coscheduling.py); a nomination or a placed term is the
+            # chain's to honour
+            return self._gate.refused != routing.GANG
         # a batch the signature fast path can commit is cheaper there —
         # the keys computed here are memoized for _try_fast_schedule so the
         # per-pod signature work runs ONCE per batch, not twice
-        if (
-            self._fast_gate_ok(batch)
-            and fwk.fit_strategy() == gang.DEFAULT_FIT_STRATEGY
-        ):
-            keys = self._batch_signature_keys(batch)
-            if keys is not None:
-                return False
-        return True
+        return (
+            not self._signature_profile(fwk)
+            or self._batch_signature_keys(batch) is None
+        )
 
     def _repack_mirror(self) -> None:
         """mirror.update + key-width guard: one forced full repack when the
@@ -2205,15 +2122,15 @@ class Scheduler:
         pending usage delta is its own (same lineage epoch, nothing
         unharvested), its state flushes into the mirror in one vectorized
         pass first, so update()'s per-dirty-node walk sees clean rows."""
-        holder = getattr(self, "_fastdev", None)
+        holder = self._fastdev
         if (
             holder is not None
             and not holder["dev_inflight"]
-            and getattr(self, "_fc_key", None) is not None
+            and self._fc_key is not None
             and self._fc_key[:3]
             == (
                 self._external_mutations,
-                getattr(self, "_nonfast_commits", 0),
+                self._nonfast_commits,
                 self.mirror._full_packs,
             )
             and self.mirror.nodes is holder["nt"]
@@ -2223,150 +2140,88 @@ class Scheduler:
         if bucket_cap(len(self.mirror.vocab.label_keys)) > self.mirror.nodes.k_cap:
             self.mirror._force_full = True
             self.mirror.update(self.cache, self.namespace_labels)
-        self._mirror_sync = (
-            self._external_mutations,
-            getattr(self, "_nonfast_commits", 0),
+        self._mirror_sync = (self._external_mutations, self._nonfast_commits)
+
+    def _signature_profile(self, fwk) -> bool:
+        """The signature committer assumes the default fit scoring,
+        full-width evaluation, and first-max tie-break: properties of the
+        profile, asked once a group before any pod's gates."""
+        return (
+            fwk.fit_strategy() == gang.DEFAULT_FIT_STRATEGY
+            and not self._sampling_active(fwk)
         )
 
-    def _fast_gate_ok(self, batch) -> bool:
-        """Per-batch fast-path eligibility, replacing the old cluster-global
-        gates: nominations and placed (anti-)affinity terms only poison the
-        pods they can actually touch.
-
-        * nominations count as present only for pods of priority <= the
-          nomination's (runtime:973): if every batch pod outranks every
-          nomination, the signature committer's capacity view is exact;
-        * a placed pod's required anti-affinity (and symmetric term score)
-          affects only newcomers its term selectors ADMIT — checked per
-          batch label-group against the cache's registry of DISTINCT
-          placed terms, at any count of placed term pods;
-        * placed host-port users never constrain port-FREE pods (and port
-          users are already signature-ineligible), so no port gate at all.
-
-        Called from three places a batch may pass; ``_fast_gate_refused``
-        keeps the last verdict's reason (``gang``, ``nomination``,
-        ``term_admits``: a placed term admits a batch pod, ``term_count``:
-        the batch's label-groups have more candidate terms than one sweep
-        may ask, ``MAX_PROBES_ASKED``) for the loop to book once,
-        ``_book_route``.
-        """
-        self._fast_gate_asked = 0
-        self._fast_gate_refused = self._fast_gate_refusal(batch)
-        return self._fast_gate_refused is None
-
-    def _fast_gate_refusal(self, batch) -> Optional[str]:
-        """Why the fast gate refuses ``batch``; None where it does not."""
-        # gang members need the workloads tier's all-or-nothing admission —
-        # the signature committer has no rollback
-        if self.config.gang_dispatch and any(
-            wlg.group_key_of(qp.pod) is not None for qp in batch
-        ):
-            return "gang"
-        if len(self.nominator):
-            max_nom = max(p.priority for _, p in self.nominator.entries())
-            if any(qp.pod.priority <= max_nom for qp in batch):
-                return "nomination"
-        if self.cache.n_term_pods:
-            # an immutable view: this runs outside _mu (_chain_quickcheck)
-            # while the informer thread counts term pods in and out
-            view = self.cache.term_probe_view()
-            seen = set()
-            for qp in batch:
-                pod = qp.pod
-                gk = (pod.namespace, tuple(sorted(pod.labels.items())))
-                if gk not in seen:
-                    seen.add(gk)
-                    refusal = self._ask_probes(view, pod)
-                    if refusal is not None:
-                        return refusal
-        return None
-
-    def _ask_probes(self, view, pod) -> Optional[str]:
-        """Ask the placed terms that could admit ``pod`` (``view``'s
-        candidates for its labels) whether one does: ``term_admits`` where
-        one does, ``term_count`` where asking would take the batch past the
-        work bound, else None.  ``_fast_gate_asked`` counts the asking."""
-        candidates = view.candidates(pod)
-        if self._fast_gate_asked + len(candidates) > MAX_PROBES_ASKED:
-            return "term_count"
-        for pr in candidates:
-            self._fast_gate_asked += 1
-            if pr.admits(pod):
-                return "term_admits"
-        return None
-
-    def _fast_pod_predicate(self, fwk, group_name: str, known_rows=None):
-        """Per-pod closure mirroring _try_dispatch_fast's batch gates +
-        _fast_gate_ok + signature eligibility — the pop_batch_while feed
-        for fast-batch extension.  Pods it accepts are exactly the pods a
-        fresh batch through those gates would accept; with ``known_rows``
-        (the signature row cache) it additionally requires the pod's
-        signature to be already established as argmax-neutral, so the
-        extension can never force a post-pop bail-out."""
-        host_scores = [
-            p
-            for p in fwk.host_score_plugins()
-            if fwk.score_weights.get(p.name, 0)
-        ]
-        hf = fwk.host_filter_plugins()
-        ns_plugins = self._normalizing_score_plugins(fwk)
-        extenders = self.extenders
+    def _pod_gates(self, fwk, signature=None) -> routing.PodGates:
+        """``routing.pod_gates`` over what this scheduler observes NOW (a
+        batch's readings share one build); ``fwk`` None for the fast gate
+        alone, which reads no profile."""
         max_nom = None
         if len(self.nominator):
             max_nom = max(p.priority for _, p in self.nominator.entries())
-        view = self.cache.term_probe_view() if self.cache.n_term_pods else None
-        group_hit: Dict[tuple, bool] = {}
+        return routing.pod_gates(
+            fwk,
+            extenders=self.extenders,
+            gang_on=self.config.gang_dispatch,
+            max_nomination=max_nom,
+            # an immutable view: the gates run outside _mu
+            # (_chain_quickcheck) while the informer thread counts term
+            # pods in and out
+            view=self.cache.term_probe_view() if self.cache.n_term_pods else None,
+            tally=self._gate,
+            signature=signature,
+        )
+
+    def _fast_gate_ok(self, batch, gates=None) -> bool:
+        """Per-batch fast-path eligibility, replacing the old cluster-global
+        gates: nominations and placed (anti-)affinity terms only poison the
+        pods they can actually touch (``routing.pod_gates``' ``fast_gate``
+        has the clauses and why).
+
+        Called from three places a batch may pass; ``_gate`` keeps the last
+        verdict's reason (``gang``, ``nomination``, ``term_admits``: a
+        placed term admits a batch pod, ``term_count``: the batch's
+        label-groups have more candidate terms than one sweep may ask,
+        ``MAX_PROBES_ASKED``) and its asking for the loop to book once,
+        ``_book_route``.
+        """
+        gate = self._gate
+        gate.asked = 0
+        gate.refused = (gates or self._pod_gates(None)).fast_gate(batch)
+        return gate.refused is None
+
+    def _fast_pod_predicate(self, fwk, group_name: str, known_rows=None):
+        """The pop_batch_while feed for fast-batch extension: the group's
+        pods that routing's gates give no reason against (``first_reason``,
+        the signature included) — exactly the pods a fresh batch through
+        _try_dispatch_fast's gates would accept
+        (tests/test_routing.py); with ``known_rows``
+        (the signature row cache) it additionally requires the pod's
+        signature to be already established as argmax-neutral, so the
+        extension can never force a post-pop bail-out."""
         vocab = self.mirror.vocab
         n_lanes = self.mirror.nodes.allocatable.shape[1]
         params = (n_lanes, len(vocab.resources))
         lanes_box: list = [None]
+        sig_key = self._pod_sig_key
 
-        # the default registry leaves every gate list empty — guard each
-        # any() so the hot steady-state predicate is just the signature
-        # memo lookup (pop_batch_while runs this once per extended pod)
-        gang_on = self.config.gang_dispatch
-
-        def elig(qp) -> bool:
-            p = qp.pod
-            if p.scheduler_name != group_name or p.nominated_node_name:
-                return False
-            if gang_on and wlg.group_key_of(p) is not None:
-                return False  # gang members need the workloads dispatch
-            if max_nom is not None and p.priority <= max_nom:
-                return False
-            # explicit loops, not any(genexpr): this predicate runs once
-            # per extended pod and the genexpr closure allocation showed
-            # up in the drain profile
-            for pl in hf:
-                if pl.maybe_relevant(p):
-                    return False
-            for e in extenders:
-                if e.is_interested(p):
-                    return False
-            for pl in ns_plugins:
-                if pl.score_relevant(p):
-                    return False
-            for pl in host_scores:
-                if pl.score_relevant(p):
-                    return False
-            if view:
-                gk = (p.namespace, tuple(sorted(p.labels.items())))
-                hit = group_hit.get(gk)
-                if hit is None:
-                    hit = group_hit[gk] = self._ask_probes(view, p) is not None
-                if hit:
-                    return False
+        def signature(p):
+            # the hot steady-state predicate is just the signature memo
+            # lookup (pop_batch_while runs this once per extended pod)
             memo = p.__dict__.get("_sigkey_memo")
             if memo is not None and memo[0] == params:
                 k = memo[1]
             else:
-                k = self._pod_sig_key(p, params, lanes_box)
-            if k is None:
-                return False
-            if known_rows is not None:
-                row = known_rows.get(k)
-                return row is not None and row["const_ok"]
-            return True
+                k = sig_key(p, params, lanes_box)
+            if k is None or known_rows is None:
+                return k
+            row = known_rows.get(k)
+            return k if row is not None and row["const_ok"] else None
+
+        first_reason = self._pod_gates(fwk, signature).first_reason
+
+        def elig(qp) -> bool:
+            p = qp.pod
+            return p.scheduler_name == group_name and first_reason(p) is None
 
         return elig
 
@@ -2376,11 +2231,8 @@ class Scheduler:
         or non-fast commits (scan/extender paths, whose usage the fast
         committer didn't track).  Steady-state fast drains — where the only
         changes are the committer's own commits — skip the repack."""
-        sync = (
-            self._external_mutations,
-            getattr(self, "_nonfast_commits", 0),
-        )
-        if self.mirror.nodes is None or getattr(self, "_mirror_sync", None) != sync:
+        sync = (self._external_mutations, self._nonfast_commits)
+        if self.mirror.nodes is None or self._mirror_sync != sync:
             self._repack_mirror()
 
     def _pod_sig_key(self, pod, params, lanes_box):
@@ -2461,13 +2313,14 @@ class Scheduler:
             "epoch": epoch,
         }
 
-    def _try_dispatch_chained(self, fwk, batch, outcomes, can_restart: bool):
-        """Dispatch the batch on the chained device cluster.  Returns a
-        pending record (dict), "handled" (nothing left to schedule),
-        "flush" (pipeline must settle before the chain can restart), or
-        None (fall back to the direct path).
+    def _try_dispatch_chained(self, fwk, batch, outcomes, pipe) -> routing.Answer:
+        """Offer the batch to the chained device cluster: in flight (the
+        pending record), handled (nothing left to schedule), settle (the
+        pipeline must be empty before the chain can restart), declined
+        (fall back to the next engine), or serial (an abandoned dispatch's
+        live batch).
 
-        The caller's ``chain_dispatch`` span is divided into consecutive,
+        The ``chain_dispatch`` span is divided into consecutive,
         disjoint parts (``chain_dispatch.lock_wait``, ``.pack``, ``.repack``,
         ``.sync``, ``.prefilter``, ``.h2d``, ``.tables``, ``.submit``,
         ``.release``); the capacity checks and the record stay outside them.
@@ -2477,11 +2330,15 @@ class Scheduler:
         flight reads (the ``DeviceBatch``'s) hands the interpreter lock
         away, and beside binding the wait to win it back is most of the
         interval."""
-        sp_release = self._span("chain_dispatch.release")
-        rec = self._dispatch_chained(fwk, batch, outcomes, can_restart, sp_release)
-        if isinstance(rec, dict):
-            sp_release.end()
-        return rec
+        # the host's side of one chained dispatch (prep under
+        # the lock, tables, the dispatch call): its own phase —
+        # the chained path books no pack/h2d/device
+        with self._span("chain_dispatch"):
+            sp_release = self._span("chain_dispatch.release")
+            ans = self._dispatch_chained(fwk, batch, outcomes, pipe.empty, sp_release)
+            if ans.status is routing.Offer.IN_FLIGHT:
+                sp_release.end()
+            return ans
 
     def _dispatch_chained(self, fwk, batch, outcomes, can_restart, sp_release):
         """``_try_dispatch_chained``'s body, in a frame of its own so that
@@ -2499,9 +2356,9 @@ class Scheduler:
                     for k, v in qp.pod.labels.items():
                         vocab.intern_label(k, v)
                 epoch = self._chain_epoch(vocab)
-                ch = getattr(self, "_chain", None)
+                ch = self._chain
                 if (ch is None or ch["epoch"] != epoch) and not can_restart:
-                    return "flush"
+                    return routing.SETTLE
 
             # ---- side-effect-free preparation: every bail-out below must
             # happen BEFORE PreFilter runs (its failures mutate outcomes/
@@ -2519,16 +2376,16 @@ class Scheduler:
                     namespace_labels=self.namespace_labels,
                 )
                 epoch = self._chain_epoch(vocab)  # interning may have grown it
-                ch = getattr(self, "_chain", None)
+                ch = self._chain
             if ch is None or ch["epoch"] != epoch:
                 if not can_restart:
                     # packing interned new vocab (epoch moved) — the
                     # pipeline must settle before a host-state restart
-                    return "flush"
-                # this is still the side-effect-free prep, so None is safe
+                    return routing.SETTLE
+                # this is still the side-effect-free prep, so declining is safe
                 ch = self._chain_restart(vocab, epoch)
                 if ch is None:
-                    return None
+                    return routing.DECLINED
             # capacity/width checks against the CHAINED cluster's own
             # tensors — the live host mirror may have repacked to different
             # buckets mid-chain
@@ -2540,7 +2397,7 @@ class Scheduler:
                 cdc.epod_labels.shape[1],
             )
             if not chain_ops.caps_compatible(dc_shapes, pb):
-                return None
+                return routing.DECLINED
             P = pb.valid.shape[0]
             append_terms = bool((pb.aff_kind != PAD).any())
             AT = pb.aff_kind.shape[1] if append_terms else 0
@@ -2552,7 +2409,7 @@ class Scheduler:
                 # then restart the chain once from the repacked state
                 self._chain = None
                 if not can_restart:
-                    return "flush"
+                    return routing.SETTLE
                 self.mirror._m_cap_max = max(
                     self.mirror._m_cap_max,
                     bucket_cap(max((ch["m"] + P * AT) * 2, 1), 1),
@@ -2564,12 +2421,12 @@ class Scheduler:
                 self.mirror._existing_version = -1
                 ch = self._chain_restart(vocab, epoch)
                 if ch is None:
-                    return None  # direct path owns the serial fallback
+                    return routing.DECLINED  # direct path owns the serial fallback
                 cdc = ch["dc"]
                 E = cdc.epod_node.shape[0]
                 M = cdc.term_pod.shape[0]
                 if ch["e"] + P > E or ch["m"] + P * AT > M:
-                    return None  # genuinely beyond capacity — direct path
+                    return routing.DECLINED  # genuinely beyond capacity — direct path
 
             # ---- PreFilter (side effects OK now: the dispatch is certain)
             with self._span("chain_dispatch.prefilter"):
@@ -2588,7 +2445,7 @@ class Scheduler:
                         )
                     batch = live
                     if not batch:
-                        return "handled"
+                        return routing.HANDLED
             if pf_failures:
                 # repack without the rejected pods (their rows must not
                 # reach the device as schedulable entries)
@@ -2631,24 +2488,8 @@ class Scheduler:
                 # cross-pod-constraint batches ride the speculative wave
                 # inside the chained dispatch (same self-append, wave
                 # scheduling) — computed from the FINAL pb (post-PreFilter
-                # repack).  Port batches never reach here (_chain_quickcheck
-                # refuses them: the device append doesn't splice port rows),
-                # so the want_ppk arm and the wave_ports pass-through below
-                # are inert today — kept so the wave surface stays uniform
-                # with the direct path.
-                wave_shaped = bool(
-                    (pb.aff_kind != PAD).any()
-                    or (pb.tsc_topo_key != PAD).any()
-                    or (pb.want_ppk != PAD).any()
-                )
-                wt = None
-                if wave_shaped:
-                    if self.config.wave_dispatch:
-                        wt = self._wave_tables(pb)
-                        if wt is None:
-                            self.prom.wave_fallback.inc(reason="dup_hostname")
-                    else:
-                        self.prom.wave_fallback.inc(reason="kill_switch")
+                # repack); the chained dispatch consults no wave breaker
+                wt = self._wave_tables_for(pb)
                 wave_kw = {}
                 ss = None
                 if wt is not None:
@@ -2704,7 +2545,7 @@ class Scheduler:
                     # engine follows.
                     self._note_dispatch_failure(e)
                     self._chain = None
-                    return ("serial", batch)
+                    return routing.Answer(routing.Offer.SERIAL, batch=batch)
             if wt is not None:
                 dc2, results, reasons, wstats = out
             else:
@@ -2743,8 +2584,9 @@ class Scheduler:
                 "t0": t0,
             }
             self._trace_dispatch("wave" if wt is not None else "chain", t0, batch, rec)
+            ans = routing.Answer(routing.Offer.IN_FLIGHT, rec)
             sp_release.begin()
-            return rec
+            return ans
 
     def _finish_chained(self, rec) -> List[ScheduleOutcome]:
         """Harvest one pipelined batch: fetch its results and walk the
@@ -2881,6 +2723,39 @@ class Scheduler:
             )
             self._tables_key = tkey
         return self._tables
+
+    def _wave_tables_for(self, pb, breaker: Optional[str] = None):
+        """WAVE eligibility, decided once for the direct and the chained
+        dispatch: batches carrying their own cross-pod
+        constraints — spread/inter-pod terms OR in-batch host ports
+        — ride the speculative wave dispatch (ops/wave.py):
+        speculation + term-factored conflict resolution,
+        bit-identical to the scan at a fraction of its per-step
+        cost.  Port users ride the [Tpt, N] occupancy carry and
+        sampling-compat / seeded-tie drains replay their window +
+        rotation per step, so neither falls back any more; the only
+        remaining disqualifier is duplicate hostname labels
+        (_wave_tables → mirror.hostnames_unique).  Every fallback
+        bumps scheduler_tpu_wave_fallback_total{reason=}.  Returns the
+        wave's tables, or None: the batch rides the gang scan.  ``breaker``
+        names the kernel whose open breaker also sends it there (the direct
+        path's ``wave.wave_run``; port batches never reach the chained
+        dispatch, _chain_quickcheck refuses them)."""
+        if not (
+            (pb.aff_kind != PAD).any()
+            or (pb.tsc_topo_key != PAD).any()
+            or (pb.want_ppk != PAD).any()
+        ):
+            return None
+        if not self.config.wave_dispatch:
+            self.prom.wave_fallback.inc(reason="kill_switch")
+            return None
+        if breaker is not None and self._breaker_blocked(breaker):
+            return None
+        wt = self._wave_tables(pb)
+        if wt is None:
+            self.prom.wave_fallback.inc(reason="dup_hostname")
+        return wt
 
     def _wave_tables(self, pb):
         """Host half of the wave's interaction partitioner: distinct-term
@@ -3052,12 +2927,8 @@ class Scheduler:
             for qp in batch
         ):
             return False
-        ns_plugins = self._normalizing_score_plugins(fwk)
-        host_scores = [
-            p
-            for p in fwk.host_score_plugins()
-            if fwk.score_weights.get(p.name, 0)
-        ]
+        ns_plugins = routing.normalizing_score_plugins(fwk)
+        host_scores = routing.weighted_host_scores(fwk)
         for qp in batch:
             pod = qp.pod
             if pod.nominated_node_name or pod.host_ports():
@@ -3964,7 +3835,7 @@ class Scheduler:
     def _fast_key(self, fwk, enabled, weights):
         return (
             self._external_mutations,
-            getattr(self, "_nonfast_commits", 0),
+            self._nonfast_commits,
             self.mirror._full_packs,
             enabled,
             weights,
@@ -3993,7 +3864,7 @@ class Scheduler:
         cache = self._sig_cache
         check_fit = "NodeResourcesFit" in enabled
         fc_key = self._fast_key(fwk, enabled, weights)
-        holder = getattr(self, "_fastdev", None)
+        holder = self._fastdev
         if holder is None or self._fc_key != fc_key:
             nt = self.mirror.nodes
             holder = self._fastdev = {
@@ -4030,7 +3901,7 @@ class Scheduler:
             # that node would pass for current at the next batch, and at
             # every batch until another event; keyed by the sync it was
             # built from, the next batch finds it stale and rebuilds it.
-            self._fc_key = getattr(self, "_mirror_sync", fc_key[:2]) + fc_key[2:]
+            self._fc_key = (self._mirror_sync or fc_key[:2]) + fc_key[2:]
             self._sig_objs: Dict[object, fp.Signature] = {}
             self._sig_list: List[fp.Signature] = []
 
@@ -4623,21 +4494,17 @@ class Scheduler:
         return outcomes
 
 
-    def _try_dispatch_fast(
-        self, fwk, batch, outcomes, chain_settled: bool, pipeline_empty: bool = True
-    ):
+    def _try_dispatch_fast(self, fwk, batch, outcomes, pipe) -> routing.Answer:
         """Pipelined fast-path dispatch from the scheduling loop: run the
         eligibility gates and PreFilter, dispatch the sig_scan kernel, and
-        return a pending record the loop harvests later — the fast-path
-        analogue of _try_dispatch_chained's ≤2-in-flight discipline, which
-        hides the device link's round-trip latency behind the next batch's
-        host work.  Returns the record, "handled" (nothing left), "flush"
-        (chain records must settle first — their commits move host state the
-        fast rebuild reads), or None (not eligible — direct path)."""
-        if self._sampling_active(fwk):
-            return None
-        if fwk.fit_strategy() != gang.DEFAULT_FIT_STRATEGY:
-            return None
+        answer in flight with a pending record the loop harvests later — the
+        fast-path analogue of _try_dispatch_chained's ≤2-in-flight discipline,
+        which hides the device link's round-trip latency behind the next
+        batch's host work.  Else handled (nothing left), settle (chain
+        records must settle first — their commits move host state the
+        fast rebuild reads), or declined (not eligible — direct path)."""
+        if not self._signature_profile(fwk):
+            return routing.DECLINED
         if self.mirror.nodes is None:
             # first batch of a fresh scheduler: pack the mirror now so the
             # very first dispatch already takes the pipelined (and batch-
@@ -4647,48 +4514,36 @@ class Scheduler:
                 if self.mirror.nodes is None:
                     self._repack_mirror()
             if self.mirror.nodes is None:  # no nodes yet
-                return None
-        hf = fwk.host_filter_plugins()
-        ns_plugins = self._normalizing_score_plugins(fwk)
+                return routing.DECLINED
+        gates = self._pod_gates(fwk)
         for qp in batch:
-            p = qp.pod
-            if p.nominated_node_name:
-                return None
-            if any(pl.maybe_relevant(p) for pl in hf):
-                return None
-            if any(e.is_interested(p) for e in self.extenders):
-                return None
-            if any(pl.score_relevant(p) for pl in ns_plugins):
-                return None
-        if not self._fast_gate_ok(batch):
-            return None
+            if gates.one_pod(qp.pod) is not None:
+                return routing.DECLINED
+        if not self._fast_gate_ok(batch, gates):
+            return routing.DECLINED
         keys = self._batch_signature_keys(batch)
         if keys is None:
-            return None
-        if not chain_settled:
-            return "flush"
+            return routing.DECLINED
+        if not pipe.only_fast:
+            return routing.SETTLE
         # a lineage rebuild (external events moved the ground truth) must
         # not happen under unharvested records: their commits reach the
         # cache only at harvest, and a rebuild reads the mirror — settle
         # the pipeline first, then rebuild on the retry
-        if not pipeline_empty:
-            enabled_probe = fwk.device_enabled()
-            weights_probe = tuple(
-                fwk.score_weights.get(n, 0) for n in gang.WEIGHT_ORDER
-            )
-            if getattr(self, "_fastdev", None) is None or self._fc_key != self._fast_key(
-                fwk, enabled_probe, weights_probe
-            ):
-                return "flush"
+        enabled = fwk.device_enabled()
+        weights = tuple(fwk.score_weights.get(n, 0) for n in gang.WEIGHT_ORDER)
+        if not pipe.empty and (
+            self._fastdev is None
+            or self._fc_key != self._fast_key(fwk, enabled, weights)
+        ):
+            return routing.SETTLE
         # spec-level host-score probe on the SEED batch (extension pods are
         # probed inside the predicate) — the pre-PreFilter equivalent of the
         # sync path's Skip-state check: a pod whose spec is irrelevant Skips
         # in PreScore by the stateful-plugin contract
-        for p in fwk.host_score_plugins():
-            if fwk.score_weights.get(p.name, 0) and any(
-                p.score_relevant(qp.pod) for qp in batch
-            ):
-                return None
+        for qp in batch:
+            if gates.host_score(qp.pod) is not None:
+                return routing.DECLINED
 
         sp_pack = self._span("pack").begin()
         with self._mu:
@@ -4697,10 +4552,6 @@ class Scheduler:
                 for k, v in qp.pod.labels.items():
                     vocab.intern_label(k, v)
             self._sync_mirror_external()
-            enabled = fwk.device_enabled()
-            weights = tuple(
-                fwk.score_weights.get(n, 0) for n in gang.WEIGHT_ORDER
-            )
         # Establish the SEED batch's signature rows (and their argmax-
         # neutrality verdicts) BEFORE extending: every bail-out must happen
         # while the seed group is the only thing popped — extension pods
@@ -4708,7 +4559,7 @@ class Scheduler:
         rows = self._fast_sig_rows(fwk, batch, keys, enabled, weights)
         sp_pack.end()
         if rows is None:
-            return None
+            return routing.DECLINED
 
         # Extend the batch from the queue head while pods stay eligible AND
         # their signatures are already established as argmax-neutral: per-
@@ -4769,7 +4620,7 @@ class Scheduler:
                 batch = live
                 if not batch:
                     sp_pack.end()
-                    return "handled"
+                    return routing.HANDLED
                 keys = self._batch_signature_keys(batch)
         sp_pack.end()
         # fast commits happen outside the chain's device state — drop it
@@ -4786,9 +4637,17 @@ class Scheduler:
                 for qp in batch:
                     self._handle_failure(qp, s)
                     outcomes.append(ScheduleOutcome(qp.pod, None, s, 0))
-            return "handled"
+            return routing.HANDLED
         rec["record_metrics"] = True
-        return rec
+        # a resident run may finish its conflict tail on the HOST committer,
+        # after which the chained device state is stale — harvest
+        # immediately so no later dispatch rides a state that a host tail
+        # is about to overtake
+        rec["harvest_now"] = (
+            rec.get("rstats_dev") is not None
+            and not self.config.resident_serial_tail
+        )
+        return routing.Answer(routing.Offer.IN_FLIGHT, rec)
 
 
     def _run_pre_filter_fast(self, fwk, state, batch, keys):
@@ -4809,7 +4668,7 @@ class Scheduler:
             return fwk.run_pre_filter(state, [qp.pod for qp in batch])
         mkey = (
             self._external_mutations,
-            getattr(self, "_nonfast_commits", 0),
+            self._nonfast_commits,
             self.mirror._full_packs,
             fwk.profile_name,
         )
@@ -5412,32 +5271,6 @@ class Scheduler:
             or self.config.tie_break_seed is not None
         )
 
-    @staticmethod
-    def _normalizing_score_plugins(fwk):
-        """Enabled host Score plugins that OVERRIDE normalize — their
-        scores depend on the feasible set, which only the one-pod oracle
-        cycle knows (see the routing in _schedule_batch).  Also includes
-        NodeResourcesFit when its scoringStrategy weighs resources beyond
-        the device kernel's cpu/memory lanes (device_score=False): its
-        score evolves with every in-batch commit, so only the one-pod
-        cycle (whose fit_scorer recomputes per attempt) is exact."""
-        from kubernetes_tpu.framework.interface import ScorePlugin
-
-        out = [
-            p
-            for p in fwk.host_score_plugins()
-            if fwk.score_weights.get(p.name, 0)
-            and type(p).normalize is not ScorePlugin.normalize
-        ]
-        fit = fwk.plugin_instance("NodeResourcesFit")
-        if (
-            fit is not None
-            and not getattr(fit, "device_score", True)
-            and fwk.score_weights.get(fit.name, 0)
-        ):
-            out.append(fit)
-        return out
-
     def _batched_preemption_narrow(
         self, fwk, state, failed, batch=None, chosen=None, node_names=None
     ) -> None:
@@ -5721,7 +5554,7 @@ class Scheduler:
             if not from_fast:
                 # scan/extender-path commits advance cache state the fast
                 # committer didn't see — its cache key must change
-                self._nonfast_commits = getattr(self, "_nonfast_commits", 0) + 1
+                self._nonfast_commits += 1
             return self._commit_under_lock(
                 fwk, state, qp, node_name, n_feas, binder_override, has_rp
             )
@@ -5897,9 +5730,7 @@ class Scheduler:
             if nonfast:
                 # scan/wave-path commits advance cache state the fast
                 # committer didn't see — its cache key must change
-                self._nonfast_commits = (
-                    getattr(self, "_nonfast_commits", 0) + len(run)
-                )
+                self._nonfast_commits += len(run)
             with self._span("commit.assume"):
                 results = self.cache.assume_pods_bulk(
                     list(zip((qp.pod for qp in run), names))

@@ -540,7 +540,7 @@ def test_what_a_placed_term_admits_and_where_the_registry_files_it(case):
     def verdict(pod):
         ok = sched._fast_gate_ok([_qp(pod)])
         assert ok != _walk_admits(sched.cache, pod)
-        return sched._fast_gate_refused
+        return sched._gate.refused
 
     def pod(labels, namespace="default"):
         return Pod(name="x", namespace=namespace, labels=labels,
@@ -591,8 +591,8 @@ def test_what_a_placed_term_admits_and_where_the_registry_files_it(case):
         sched.on_pod_add(_sel_pod("t", sel))
         assert list(view().by_pair) == [("app", "db")]
         assert verdict(pod({"app": "db", "color": "red"})) == "term_admits"
-        assert verdict(pod({"app": "db"})) is None and sched._fast_gate_asked == 1
-        assert verdict(pod({"color": "red"})) is None and sched._fast_gate_asked == 0
+        assert verdict(pod({"app": "db"})) is None and sched._gate.asked == 1
+        assert verdict(pod({"color": "red"})) is None and sched._gate.asked == 0
         assert verdict(pod({"app": "db", "color": "red", "canary": "1"})) is None
     else:  # a selector that will not hash: under a key of the pod's own, never deduped, still exact
         for i in range(3):
@@ -600,7 +600,7 @@ def test_what_a_placed_term_admits_and_where_the_registry_files_it(case):
             sched.on_pod_add(_sel_pod(f"t{i}", sel, node_name=f"n{i % 2}"))
         assert sorted(_registry_counts(sched.cache).values()) == [1, 1, 1]
         assert verdict(pod({"tier": "api"})) == "term_admits"
-        assert verdict(pod({"tier": "db"})) is None and sched._fast_gate_asked == 3
+        assert verdict(pod({"tier": "db"})) is None and sched._gate.asked == 3
         for p in list(sched.cache.term_pods.values()):
             sched.on_pod_delete(p)
         assert not _registry_counts(sched.cache) and verdict(pod({"tier": "api"})) is None
@@ -614,10 +614,10 @@ def test_a_cluster_of_distinct_terms_costs_a_plain_pod_its_own_labels_not_the_te
         sched.on_pod_add(_anti_pod(f"d{i}", group=f"dep-{i}", node_name=f"n{i % 4}"))
     assert len(_registry_counts(sched.cache)) == 2000
     batch = [_qp(_plain(i)) for i in range(64)] + [_qp(Pod(name="bare"))]
-    assert sched._fast_gate_ok(batch) and sched._fast_gate_asked == 0
+    assert sched._fast_gate_ok(batch) and sched._gate.asked == 0
     mine = Pod(name="mine", labels={"g": "dep-7", "app": "x"})
     assert not sched._fast_gate_ok(batch + [_qp(mine)])
-    assert sched._fast_gate_refused == "term_admits" and sched._fast_gate_asked == 1
+    assert sched._gate.refused == "term_admits" and sched._gate.asked == 1
 
 
 def test_term_count_is_the_work_bound_of_one_batchs_sweep():
@@ -630,26 +630,26 @@ def test_term_count_is_the_work_bound_of_one_batchs_sweep():
         sched.on_pod_add(_sel_pod(f"t{i}", sel, node_name=f"n{i % 4}"))
     groups = MAX_PROBES_ASKED // n_terms  # 49 label-groups can be asked in full
     pods = [_qp(Pod(name=f"p{i}", labels={"app": f"a{i}"})) for i in range(groups + 1)]
-    assert sched._fast_gate_ok(pods[:groups]) and sched._fast_gate_asked == groups * n_terms
+    assert sched._fast_gate_ok(pods[:groups]) and sched._gate.asked == groups * n_terms
     assert sched._fast_gate_ok(pods[:groups] * 3)  # pods of a group already asked cost nothing
     assert not sched._fast_gate_ok(pods)
-    assert sched._fast_gate_refused == "term_count" and sched._fast_gate_asked == groups * n_terms
+    assert sched._gate.refused == "term_count" and sched._gate.asked == groups * n_terms
     # a term that admits is found before the bound is reached
     pods[0].pod.labels["k5"] = "x"
-    assert not sched._fast_gate_ok(pods) and sched._fast_gate_refused == "term_admits"
-    assert sched._fast_gate_asked == 6
+    assert not sched._fast_gate_ok(pods) and sched._gate.refused == "term_admits"
+    assert sched._gate.asked == 6
     # the batch extension's predicate shares the bound: past it, it extends no further
     del pods[0].pod.labels["k5"]
     with sched._mu:
         sched._repack_mirror()
     fwk = next(iter(sched.profiles.values()))
-    assert sched._fast_gate_ok(pods[:1]) and sched._fast_gate_asked == n_terms
+    assert sched._fast_gate_ok(pods[:1]) and sched._gate.asked == n_terms
     elig = sched._fast_pod_predicate(fwk, pods[0].pod.scheduler_name)
-    assert elig(pods[1]) is True and sched._fast_gate_asked == 2 * n_terms
-    assert elig(pods[1]) is True and sched._fast_gate_asked == 2 * n_terms  # its group is remembered
+    assert elig(pods[1]) is True and sched._gate.asked == 2 * n_terms
+    assert elig(pods[1]) is True and sched._gate.asked == 2 * n_terms  # its group is remembered
     assert sched._fast_gate_ok(pods[:groups])
     elig = sched._fast_pod_predicate(fwk, pods[0].pod.scheduler_name)
-    assert elig(pods[groups]) is False and sched._fast_gate_asked == groups * n_terms
+    assert elig(pods[groups]) is False and sched._gate.asked == groups * n_terms
 
 
 def test_the_gate_reads_a_consistent_view_beside_a_thread_that_counts_term_pods_in_and_out():
@@ -672,7 +672,7 @@ def test_the_gate_reads_a_consistent_view_beside_a_thread_that_counts_term_pods_
         try:
             while not stop.is_set():
                 sched._fast_gate_ok(batch)
-                verdicts.append(sched._fast_gate_refused)
+                verdicts.append(sched._gate.refused)
                 verdicts.append(sched._fast_gate_ok(plain_only))
                 _registry_counts(sched.cache)
         except Exception as e:  # noqa: BLE001 — the test's finding

@@ -105,8 +105,8 @@ def _check(sched, rng, step):
     pods = [_batch_pod(rng, f"{step}-{j}") for j in range(6)]
     for pod in pods:
         ok = sched._fast_gate_ok([SimpleNamespace(pod=pod)])
-        assert ok != _walk_admits(cache, pod), (step, pod.namespace, pod.labels, sched._fast_gate_refused)
-        assert sched._fast_gate_refused in (None, "term_admits")
+        assert ok != _walk_admits(cache, pod), (step, pod.namespace, pod.labels, sched._gate.refused)
+        assert sched._gate.refused in (None, "term_admits")
     # and as ONE batch: refused exactly where some pod of it is admitted
     ok = sched._fast_gate_ok([SimpleNamespace(pod=p) for p in pods])
     assert ok != any(_walk_admits(cache, p) for p in pods)
