@@ -150,7 +150,9 @@ def _slot_content(n_slots, parts):
     return np.concatenate(cols, axis=1)
 
 
-def wave_tables(pb, node_label_vals, hostname_id: int, hostnames_unique=None):
+def wave_tables(
+    pb, node_label_vals, hostname_id: int, hostnames_unique=None, t_floor=(1, 1, 1)
+):
     """Dedup the batch's constraint terms into distinct-term tables — the
     host half of the interaction partitioner.
 
@@ -167,8 +169,13 @@ def wave_tables(pb, node_label_vals, hostname_id: int, hostnames_unique=None):
     hostname label values among nodes (the factored hostname-domain counts
     assume hostname ≡ node identity).  ``hostnames_unique`` is the
     once-per-snapshot bit from SnapshotMirror.hostnames_unique; None
-    re-derives it here (standalone/test callers).  Otherwise a dict of
-    device-ready arrays + static caps:
+    re-derives it here (standalone/test callers).  ``t_floor``: the least
+    (spread, inter-pod, port) term buckets — a caller that keeps each at the
+    largest it has met (``t_caps`` of the batches before) compiles ONE
+    admission program a bucket it grows into, not one a batch whose count of
+    distinct terms falls in a smaller bucket (a drain of many Deployments:
+    WAVE.md "Many terms a batch").  Otherwise a dict of device-ready arrays
+    + static caps:
 
       tid_sp  i32 [P, C]   distinct spread-term id per slot (-1 empty)
       rep_sp_p/rep_sp_c  i32 [Tsp]  a representative slot per term
@@ -181,6 +188,7 @@ def wave_tables(pb, node_label_vals, hostname_id: int, hostnames_unique=None):
       port_conf bool [Tpt, Tpt]  static term-pair conflict matrix
       has_ports bool       batch carries in-batch host ports
       n_terms int  total distinct terms (spread + inter-pod + port)
+      t_caps  (Tsp, Tip, Tpt)  the term buckets the tables were built at
     """
     import numpy as np
 
@@ -220,7 +228,7 @@ def wave_tables(pb, node_label_vals, hostname_id: int, hostnames_unique=None):
         tid_flat = np.zeros((0,), np.int64)
         rep_flat = np.zeros((0,), np.int64)
     tid_sp = tid_flat.reshape(P, C).astype(np.int32)
-    t_sp = bucket_cap(max(len(rep_flat), 1), 1)
+    t_sp = bucket_cap(len(rep_flat), t_floor[0])
     rep_sp_p = np.full(t_sp, -1, np.int32)
     rep_sp_c = np.zeros(t_sp, np.int32)
     rep_sp_p[: len(rep_flat)] = rep_flat // C if C else 0
@@ -251,7 +259,7 @@ def wave_tables(pb, node_label_vals, hostname_id: int, hostnames_unique=None):
         tid_flat = np.zeros((0,), np.int64)
         rep_flat = np.zeros((0,), np.int64)
     tid_ip = tid_flat.reshape(P, AT).astype(np.int32)
-    t_ip = bucket_cap(max(len(rep_flat), 1), 1)
+    t_ip = bucket_cap(len(rep_flat), t_floor[1])
     rep_ip_p = np.full(t_ip, -1, np.int32)
     rep_ip_u = np.zeros(t_ip, np.int32)
     rep_ip_p[: len(rep_flat)] = rep_flat // AT if AT else 0
@@ -265,6 +273,7 @@ def wave_tables(pb, node_label_vals, hostname_id: int, hostnames_unique=None):
     want_ppk = np.asarray(pb.want_ppk)
     W = want_ppk.shape[1]
     n_pt = 0
+    t_pt = 1
     if W and (want_ppk != PAD).any():
         pt_content = _slot_content(
             P * W, [want_ppk, pb.want_ip, pb.want_wild]
@@ -273,7 +282,7 @@ def wave_tables(pb, node_label_vals, hostname_id: int, hostnames_unique=None):
         tid_flat, rep_flat = _dedup_slots(pt_content, pt_live)
         tid_pt = tid_flat.reshape(P, W).astype(np.int32)
         n_pt = len(rep_flat)
-        t_pt = bucket_cap(max(n_pt, 1), 1)
+        t_pt = bucket_cap(n_pt, t_floor[2])
         r_ppk = want_ppk.reshape(-1)[rep_flat]
         r_ip = np.asarray(pb.want_ip).reshape(-1)[rep_flat]
         r_wild = np.asarray(pb.want_wild).reshape(-1)[rep_flat]
@@ -319,6 +328,7 @@ def wave_tables(pb, node_label_vals, hostname_id: int, hostnames_unique=None):
         port_conf=jnp.asarray(port_conf),
         has_ports=n_pt > 0,
         n_terms=n_sp + n_ip + n_pt,
+        t_caps=(t_sp, t_ip, t_pt),
     )
 
 

@@ -331,6 +331,9 @@ class Scheduler:
 
         self._dc_cache = DeviceClusterCache(mesh=self.mesh)
         self._p_cap_max = 1  # sticky batch bucket: avoids per-size recompiles
+        # sticky (spread, inter-pod, port) distinct-term buckets of the wave's
+        # tables, for the same reason (wave.wave_tables' t_floor)
+        self._t_cap_max = (1, 1, 1)
         if self.mesh is not None:
             # pod buckets must split evenly over the pods axis — seed the
             # sticky bucket so bucket_cap(n, 1) growth stays a multiple
@@ -1930,6 +1933,7 @@ class Scheduler:
                 self.mirror.e_used,
                 kernel=kroot,
                 static_sigs=ss["n_valid"] if ss else None,
+                n_terms=wt["n_terms"],
             )
         self._process_results(
             fwk,
@@ -2580,6 +2584,7 @@ class Scheduler:
                 "reasons": reasons,
                 "wave_stats": wstats,
                 "static_sigs": ss["n_valid"] if ss else None,
+                "n_terms": wt["n_terms"] if wt else 0,
                 "e_rows": ch["e"],
                 "t0": t0,
             }
@@ -2643,6 +2648,7 @@ class Scheduler:
                 rec["e_rows"],
                 kernel="chain.chain_dispatch",
                 static_sigs=rec["static_sigs"],
+                n_terms=rec["n_terms"],
             )
         self._process_results(
             rec["fwk"],
@@ -2765,7 +2771,15 @@ class Scheduler:
 
         Memoized like _gang_tables: template-stamped drains repeat the
         same term content batch after batch, so the np.unique row-dedup
-        and per-key domain compaction collapse to one digest check."""
+        and per-key domain compaction collapse to one digest check.
+
+        The term buckets are STICKY (``_t_cap_max``, as ``_p_cap_max`` is for
+        the pod axis): a batch is given at least the largest bucket a batch
+        before it was, so a drain whose batches hold pods of hundreds of
+        Deployments — a count of distinct terms that moves from batch to
+        batch, and falls on the drain's short last one — dispatches ONE
+        admission program once the bucket has grown, and the window meets no
+        bucket its warm-up did not (WAVE.md "Many terms a batch")."""
         import hashlib
 
         import numpy as np
@@ -2803,6 +2817,7 @@ class Scheduler:
             self.mirror._full_packs,
             len(self.mirror.vocab.label_vals),
             hk_id,
+            self._t_cap_max,
             h.digest(),
         )
         cached = getattr(self, "_wave_tables_memo", None)
@@ -2813,7 +2828,10 @@ class Scheduler:
             self.mirror.nodes.label_vals,
             hk_id,
             hostnames_unique=self.mirror.hostnames_unique,
+            t_floor=self._t_cap_max,
         )
+        if wt is not None:
+            self._t_cap_max = wt["t_caps"]
         self._wave_tables_memo = (key, wt)
         return wt
 
@@ -3588,7 +3606,15 @@ class Scheduler:
         sp_commit.end()
 
     def _wave_resolve(
-        self, fwk, batch, chosen, wstats_dev, e_rows, kernel=None, static_sigs=None
+        self,
+        fwk,
+        batch,
+        chosen,
+        wstats_dev,
+        e_rows,
+        kernel=None,
+        static_sigs=None,
+        n_terms=0,
     ):
         """Harvest one wave's speculation stats: admitted/demoted counters
         (``wave.demoted`` and, with ``e_rows`` — the existing-pod rows live
@@ -3596,7 +3622,9 @@ class Scheduler:
         as does each conflict kind as ``wave.conflicts.<kind>``;
         ``static_sigs`` — the distinct valid pod rows the dispatch computed
         its statics for — goes to ``wave.static_sigs``, and None, a
-        dispatch that computed them per pod, counts one ``wave.static_full``),
+        dispatch that computed them per pod, counts one ``wave.static_full``;
+        ``n_terms`` — the batch's distinct cross-pod terms, ``wave_tables``'
+        count before the bucket — goes to ``wave.terms``),
         a ``wave_demoted`` flight-recorder event (with the conflicting
         term) per corrected pod, and — when the framework permits lean
         binds — the interaction-group split the bulk commit path uses.
@@ -3648,6 +3676,7 @@ class Scheduler:
         self.prom.wave_admitted.inc(admitted)
         self.phases.count("wave.demoted", len(demoted))
         self.phases.count("wave.epod_rows", e_rows)
+        self.phases.count("wave.terms", n_terms)
         for kind, cnt in conflicts.items():
             self.prom.wave_conflicts.inc(cnt, kind=kind)
             self.phases.count(f"wave.conflicts.{kind}", cnt)

@@ -470,12 +470,14 @@ CHAIN_PARTS = ("lock_wait", "repack", "pack", "sync", "prefilter", "h2d", "table
 COMMIT_PARTS = ("requests", "assume", "outcomes")
 # the cells whose batches take the chained dispatch: the three whose pods carry a
 # cross-pod constraint, and (PR 41) the one whose plain pods land on a base of
-# term-carrying pods and are sent there by the fast gate's count
+# term-carrying pods and are sent there by the fast gate's count, and (PR 48)
+# the one whose pods carry the default soft spread constraints of many Deployments
 CROSS_POD_CELLS = ["spread-5k.backlog", "interpod-5k.backlog", "antiaffinity-5k.backlog",
-                   "mixedbase-5k.backlog-on-base"]
+                   "mixedbase-5k.backlog-on-base", "cl2load-5k.backlog-of-deployments"]
 ALL_CELLS = ["basic-5k.backlog", "spread-5k.backlog", "interpod-5k.backlog",
              "unsched-5k.backlog-pending-first", "antiaffinity-5k.backlog", "mixedbase-5k.backlog-on-base",
-             "northstar-10k.backlog"]  # PR 44: the resident set's lists gained the north star's cell
+             "northstar-10k.backlog",  # PR 44: the resident set's lists gained the north star's cell
+             "cl2load-5k.backlog-of-deployments"]
 TOP_LEVEL_LOOP_SPANS = ("queue_pop", "chain_dispatch", "pack", "h2d", "commit", "wave_resolve",
                         "resident_rounds", "flush_binds")
 # metric -> (the phases its data file names, the cells BENCHMARK.json lists it for)

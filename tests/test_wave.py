@@ -641,3 +641,112 @@ def test_mirror_hostnames_unique_memoizes():
     with s._mu:
         s.mirror.update(s.cache, s.namespace_labels)
         assert not s.mirror.hostnames_unique
+
+
+# ---------------------------------------------------------------------------
+# As many distinct SOFT spread terms as pods (cl2load-5k's shape, WAVE.md
+# "Many terms a batch"): every pod carries the two built-in default
+# constraints — maxSkew 3 on the hostname key, maxSkew 5 on the zone key, both
+# ScheduleAnyway — over its OWN Deployment's selector, so a batch of pods of D
+# Deployments has T = 2·D distinct terms, C = 2 slots a pod, one of them the
+# hostname slot, and the normalised score, not a mask, decides.
+# ---------------------------------------------------------------------------
+
+
+def _deployment_pod(name, deployment, node_name=""):
+    from kubernetes_tpu.api.types import (
+        Container,
+        LabelSelector,
+        Pod,
+        TopologySpreadConstraint,
+    )
+
+    sel = LabelSelector(match_labels={"name": deployment})
+    return Pod(
+        name=name,
+        namespace="default",
+        uid=f"default/{name}",
+        labels={"name": deployment},
+        node_name=node_name,
+        topology_spread_constraints=(
+            TopologySpreadConstraint(
+                max_skew=3, topology_key=HOSTNAME_LABEL,
+                when_unsatisfiable="ScheduleAnyway", label_selector=sel,
+            ),
+            TopologySpreadConstraint(
+                max_skew=5, topology_key="topology.kubernetes.io/zone",
+                when_unsatisfiable="ScheduleAnyway", label_selector=sel,
+            ),
+        ),
+        containers=[Container(name="c", requests={"cpu": "100m", "memory": "500Mi"})],
+    )
+
+
+def _wave_run_batch(state, pending, t_floor):
+    """One fused ``wave_run`` (per-pod statics) of ``pending`` against
+    ``state``: (placements, the term buckets its tables were built at, the
+    demotion kinds)."""
+    _vocab, pc, pb, dc, db, v_cap, hk_id, hostname_key, tables = _pack(state, pending)
+    wt = wave.wave_tables(pb, pc.nodes.label_vals, hk_id, t_floor=t_floor)
+    chosen, _, _, _, stats = wave.wave_run(
+        dc, db, hostname_key, v_cap,
+        wt["tid_sp"], wt["rep_sp_p"], wt["rep_sp_c"], wt["tid_ip"], wt["rep_ip_p"], wt["rep_ip_u"],
+        wt["ip_cdv_tab"], d2_cap=wt["d2_cap"], has_ports=wt["has_ports"], tid_pt=wt["tid_pt"],
+        port_conf=wt["port_conf"], **tables,
+    )
+    names = list(state.nodes)
+    placed = [names[int(c)] if int(c) >= 0 else None for c in np.asarray(chosen)[: len(pending)]]
+    return placed, wt, np.asarray(stats)[1, : len(pending)]
+
+
+@pytest.mark.parametrize(
+    "seed,n_nodes,batches,sticky",
+    [(5, 12, (14, 3), True), (5, 12, (14, 3), False), (17, 24, (16, 16, 5), True), (29, 9, (3, 12), True)],
+    ids=["14-then-3-deployments-sticky", "14-then-3-deployments-own-bucket", "three-batches-sticky",
+         "growing-bucket-sticky"],
+)
+def test_wave_run_equals_the_serial_oracle_with_as_many_soft_spread_terms_as_pods(seed, n_nodes, batches, sticky):
+    """Consecutive batches of 16 pods, batch b of ``batches[b]`` Deployments
+    (T = twice that), each against the state the batches before left: the
+    fused wave equals the serial oracle at every position whether a batch's
+    tables are built at its own bucket or, as the loop builds them, at the
+    largest bucket a batch before it met (``t_floor``); a pod that loses its
+    speculated node loses it on SCORE — nothing here is a hard mask."""
+    from kubernetes_tpu.api.resource import Resource
+    from kubernetes_tpu.api.types import Node
+
+    rng = random.Random(seed)
+    nodes = [
+        Node(
+            name=f"n{i}",
+            labels={HOSTNAME_LABEL: f"n{i}", "topology.kubernetes.io/zone": f"z{i % 3}"},
+            capacity=Resource.from_map({"cpu": "4", "memory": "32Gi", "pods": 110}),
+        )
+        for i in range(n_nodes)
+    ]
+    n_deployments = max(batches)
+    placed = [
+        _deployment_pod(f"placed-{d}-{j}", f"d{d}", node_name=rng.choice(nodes).name)
+        for d in range(n_deployments) for j in range(rng.randrange(0, 4))
+    ]
+    state_w = OracleState.build(nodes, placed)
+    state_s = OracleState.build(nodes, placed)
+    floor, caps, kinds = (1, 1, 1), [], []
+    for b, n_dep in enumerate(batches):
+        owners = list(range(n_dep)) + [rng.randrange(n_dep) for _ in range(16 - n_dep)]
+        rng.shuffle(owners)
+        got, wt, kind = _wave_run_batch(
+            state_w, [_deployment_pod(f"w{b}-{i}", f"d{d}") for i, d in enumerate(owners)], floor)
+        want = run_serial(state_s, [_deployment_pod(f"w{b}-{i}", f"d{d}") for i, d in enumerate(owners)])
+        assert got == want and None not in got
+        assert wt["n_terms"] == 2 * n_dep  # C = 2 slots a pod, one term a slot a Deployment
+        for i, (d, node) in enumerate(zip(owners, got)):
+            state_w.place(_deployment_pod(f"w{b}-{i}", f"d{d}", node_name=node))
+        caps.append(wt["t_caps"][0])
+        kinds += list(kind)
+        if sticky:
+            floor = wt["t_caps"]
+    own = [bucket_cap(2 * n_dep, 1) for n_dep in batches]
+    assert len(set(own)) > 1  # the batches' own buckets differ ...
+    assert caps == ([max(own[: b + 1]) for b in range(len(own))] if sticky else own)  # ... a sticky one only grows
+    assert set(kinds) <= {wave.DEMOTE_NONE, wave.DEMOTE_SCORE} and wave.DEMOTE_SCORE in kinds
