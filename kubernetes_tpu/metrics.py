@@ -715,6 +715,16 @@ class SchedulerMetrics:
                 "vetoes, computes them per pod and adds nothing.",
             )
         )
+        self.statics_host_by_domain = r.register(
+            Counter(
+                "scheduler_tpu_statics_host_by_domain_total",
+                "Dispatches whose cross-pod statics (gang.precompute) summed "
+                "a hostname spread constraint's domains by the hostname "
+                "key's node-to-domain map instead of by node identity: two "
+                "nodes share a hostname label value.  Zero on a cluster "
+                "whose hostnames are unique.",
+            )
+        )
         self.wave_fallback = r.register(
             Counter(
                 "scheduler_tpu_wave_fallback_total",

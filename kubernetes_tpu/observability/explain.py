@@ -184,7 +184,6 @@ def explain_pod(
                 vocab.label_keys.lookup(HOSTNAME_LABEL),
             )
         )
-        tables.pop("d_cap", None)
         has_interpod = bool(
             (pb.aff_kind != PAD).any()
             or (sched.mirror.existing.term_kind != PAD).any()

@@ -75,7 +75,7 @@ def caps_compatible(dc_shapes, pb) -> bool:
 
 # ktpu: axes(dc=DeviceCluster, db=DeviceBatch, hostname_key=i32, e_cursor=i32, m_cursor=i32)
 # ktpu: axes(nom_node=i32[G], nom_prio=i32[G], nom_req=i32[G,Rn])
-# ktpu: axes(sp_keys=i32[Kd], sp_cdv_tab=i32[Kd,N], ip_keys=i32[Kd2])
+# ktpu: axes(sp_keys=i32[Kd], sp_cdv_tab=i32[Kd,N], sp_host_cdv=i32[N], ip_keys=i32[Kd2])
 # ktpu: axes(tid_sp=i32[P,C], rep_sp_p=i32[Tsp], rep_sp_c=i32[Tsp])
 # ktpu: axes(tid_ip=i32[P,A], rep_ip_p=i32[Tip], rep_ip_u=i32[Tip], ip_cdv_tab=i32[Kd2,N])
 # ktpu: axes(tid_pt=i32[P,UP], port_conf=bool[Tpt,Tpt])
@@ -125,6 +125,7 @@ def chain_dispatch(
     sp_keys=None,
     sp_cdv_tab=None,
     ip_keys=None,
+    sp_host_cdv=None,
     d_cap: int = 8,
     append_terms: bool = True,
     fit_strategy: tuple = gang.DEFAULT_FIT_STRATEGY,
@@ -186,6 +187,8 @@ def chain_dispatch(
         sp_keys=sp_keys,
         sp_cdv_tab=sp_cdv_tab,
         ip_keys=ip_keys,
+        d_cap=d_cap,
+        sp_host_cdv=sp_host_cdv,
         sig=sig,
         rep_pod=rep_pod,
     )

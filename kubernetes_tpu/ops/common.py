@@ -361,6 +361,10 @@ _KTPU_N_COLLECTIVES = {
     "into topology domains and gather back per node — per-shard partial "
     "domain sums psum into the small replicated [D] domain table, then "
     "the per-node gather reads it shard-locally",
+    "compact_domain_stats": "resolved(collective): the same aggregate as a "
+    "[D, N] compare+reduce over compact domain ids — per-shard partial "
+    "domain sums psum into the small replicated [D] domain table, and the "
+    "read-back per node (a compare+reduce over D) is shard-local",
 }
 
 
@@ -526,6 +530,33 @@ def domain_stats(count_n, present_n, dv, v_cap: int):
         mn.reshape(lead),
         ndom.reshape(lead),
     )
+
+
+def compact_domain_stats(count_n, present_n, cdv, d_cap: int):
+    """``domain_stats`` over COMPACT domain ids, as a dense compare+reduce.
+
+    ``cdv`` lead+(N,) holds each node's domain as an id in ``[0, d_cap)``
+    (``gang.batch_tables``: one map a topology key, the same for every row
+    of that key; <0 absent).  With the ids that small a domain's total is a
+    masked sum over N and a node reads it back by a masked sum over D:
+    elementwise and fusible, where ``domain_stats``' segment ids — private
+    to each row once vmapped — lower to a scatter and a gather of
+    prod(lead)·N scalars.  The work grows with ``d_cap``.
+
+    Returns (per_node_total, per_node_domain_present, n_domains): the
+    first, second and fourth of ``domain_stats``, with 0 / False at a node
+    whose domain is absent (there ``domain_stats`` reads its overflow
+    segment back).
+    """
+    d_ids = jnp.arange(d_cap, dtype=I32)[:, None]
+    hit = cdv[..., None, :] == d_ids  # lead+(D, N)
+    tot_d = jnp.sum(jnp.where(hit, count_n[..., None, :], 0), axis=-1, dtype=I32)
+    pres_d = jnp.any(hit & present_n[..., None, :], axis=-1)  # lead+(D,)
+    per_node_tot = jnp.sum(
+        jnp.where(hit, tot_d[..., None], 0), axis=-2, dtype=I32
+    )
+    per_node_pres = jnp.any(hit & pres_d[..., None], axis=-2)
+    return per_node_tot, per_node_pres, jnp.sum(pres_d.astype(I32), axis=-1)
 
 
 def gather_rows(matrix, idx):

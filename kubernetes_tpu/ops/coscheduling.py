@@ -442,7 +442,7 @@ def workloads_schedule(
 # ktpu: axes(q_valid=bool[P,DQ], ref_cl=i32[P,CQ], claim_node0=i32[CL])
 # ktpu: axes(vol_table=DTable[P,PV2,VT], vol_valid=bool[P,PV2], vol_bad=bool[P])
 # ktpu: axes(nom_node=i32[G], nom_prio=i32[G], nom_req=i32[G,Rn], extra_score=i64[P,N])
-# ktpu: axes(sp_keys=i32[Kd], sp_cdv_tab=i32[Kd,N], ip_keys=i32[Kd2])
+# ktpu: axes(sp_keys=i32[Kd], sp_cdv_tab=i32[Kd,N], sp_host_cdv=i32[N], ip_keys=i32[Kd2])
 # ktpu: accum(i64, i32, bool)
 # ktpu: static(v_cap=16, g_cap=4)
 @functools.partial(
@@ -508,6 +508,7 @@ def workloads_run(
     sp_keys=None,
     sp_cdv_tab=None,
     ip_keys=None,
+    sp_host_cdv=None,
     d_cap: int = 8,
     d2_cap: int = 8,
     extra_score=None,
@@ -537,6 +538,8 @@ def workloads_run(
         sp_keys=sp_keys,
         sp_cdv_tab=sp_cdv_tab,
         ip_keys=ip_keys,
+        d_cap=d_cap,
+        sp_host_cdv=sp_host_cdv,
     )
     return workloads_schedule(
         dc,

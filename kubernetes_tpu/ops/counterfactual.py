@@ -126,7 +126,7 @@ def fork_density(alive, alloc, used):
 # ktpu: axes(fk_nz=i32[KF,N,2], fk_npods=i32[KF,N], fk_epod_valid=bool[KF,E], fk_nvalid=i32[KF])
 # ktpu: axes(fk_pod_live=bool[KF,P])
 # ktpu: axes(vol_table=DTable[P,PV2,VT], vol_valid=bool[P,PV2], vol_bad=bool[P])
-# ktpu: axes(sp_keys=i32[Kd], sp_cdv_tab=i32[Kd,N], ip_keys=i32[Kd2], extra_score=i64[P,N])
+# ktpu: axes(sp_keys=i32[Kd], sp_cdv_tab=i32[Kd,N], sp_host_cdv=i32[N], ip_keys=i32[Kd2], extra_score=i64[P,N])
 # ktpu: accum(i64, i32, bool)
 # ktpu: static(v_cap=16, g_cap=4)
 @functools.partial(
@@ -184,6 +184,7 @@ def counterfactual_run(
     sp_keys=None,
     sp_cdv_tab=None,
     ip_keys=None,
+    sp_host_cdv=None,
     d_cap: int = 8,
     d2_cap: int = 8,
     fit_strategy: tuple = gang.DEFAULT_FIT_STRATEGY,
@@ -241,6 +242,7 @@ def counterfactual_run(
             sp_keys=sp_keys,
             sp_cdv_tab=sp_cdv_tab,
             ip_keys=ip_keys,
+            sp_host_cdv=sp_host_cdv,
             d_cap=d_cap,
             d2_cap=d2_cap,
             extra_score=extra_score,

@@ -51,7 +51,7 @@ _KTPU_N_COLLECTIVES = {
 
 
 # ktpu: axes(dc=DeviceCluster, db=DeviceBatch, hostname_key=i32, extra_mask=bool[P,N])
-# ktpu: axes(sp_keys=i32[Kd], sp_cdv_tab=i32[Kd,N], ip_keys=i32[Kd2])
+# ktpu: axes(sp_keys=i32[Kd], sp_cdv_tab=i32[Kd,N], sp_host_cdv=i32[N], ip_keys=i32[Kd2])
 # ktpu: static(v_cap=16)
 @functools.partial(
     jax.jit,
@@ -62,6 +62,7 @@ _KTPU_N_COLLECTIVES = {
         "has_ports",
         "enabled",
         "check_fit",
+        "d_cap",
     ),
 )
 def explain_masks(
@@ -78,6 +79,8 @@ def explain_masks(
     sp_keys=None,
     sp_cdv_tab=None,
     ip_keys=None,
+    sp_host_cdv=None,
+    d_cap=None,
 ):
     """Returns bool [N_DIAG, P, N] per-kernel pass masks (gang.DIAG_KERNELS
     row order) plus the combined feasibility [P, N] as the last element of
@@ -96,6 +99,8 @@ def explain_masks(
         sp_keys=sp_keys,
         sp_cdv_tab=sp_cdv_tab,
         ip_keys=ip_keys,
+        d_cap=d_cap,
+        sp_host_cdv=sp_host_cdv,
     )
     P, N = g.static_mask.shape
     Rn = dc.requested.shape[1]

@@ -45,6 +45,7 @@ from kubernetes_tpu.ops.common import (
     DeviceCluster,
     I32,
     I64,
+    compact_domain_stats,
     domain_stats,
     eval_table,
     gather_at,
@@ -202,28 +203,50 @@ class GangStatics(NamedTuple):
     d_extra: jnp.ndarray  # bool [P, N] (host-filter veto mask)
 
 
-def batch_tables(tsc_topo, aff_topo, node_label_vals, hostname_id: int):
-    """Host-side per-batch key tables for the scan's dense domain math.
+def batch_tables(
+    tsc_topo, aff_topo, node_label_vals, hostname_id: int, hostnames_unique=None
+):
+    """Host-side per-batch key tables for the dense domain math of the
+    statics and the scan.
 
     tsc_topo/aff_topo: numpy [P, C]/[P, AT] interned topology-key ids of the
     batch (PAD in empty slots); node_label_vals: numpy [N, K] interned node
-    label values (the mirror's column-per-key layout).
+    label values (the mirror's column-per-key layout).  ``hostnames_unique``
+    is the once-per-snapshot bit from SnapshotMirror.hostnames_unique; None
+    re-derives it here (standalone/test callers).
 
     Returns a dict of gang_run kwargs:
       sp_keys    i32 [Kd]   distinct NON-hostname spread topology keys
       sp_cdv_tab i32 [Kd,N] per-key compact domain id per node (-1: absent)
+      sp_host_cdv i32 [N]   the hostname key's compact map, for precompute
+                 alone; None (no argument of the program) unless a spread
+                 slot of the batch is on the hostname key AND two nodes
+                 share a hostname value
       ip_keys    i32 [Kd2]  distinct inter-pod topology keys (incl hostname)
       d_cap      int        static bucket over the max distinct-domain count
 
-    Compact ids let the scan count distinct-domains-with-feasible-nodes as a
+    Compact ids let the statics sum, and the scan count, domains as a
     [C, N, d_cap] fused compare+reduce instead of a vocab-wide segment op
     (the TPU-hostile pattern this file avoids); hostname-topology constraints
-    use node identity directly so their domain count never inflates d_cap.
+    use node identity directly so their domain count never inflates d_cap —
+    where hostnames repeat, identity does not hold and precompute sums the
+    key by ``sp_host_cdv``, whose ids the node count bounds.
     """
     import numpy as np
 
     lv = np.asarray(node_label_vals)
     n_cap, K = lv.shape
+
+    def _compact(col):
+        """(compact id per node, -1 absent; the number of distinct values)"""
+        cdv = np.full(n_cap, -1, np.int32)
+        pos = col >= 0
+        n_uniq = 0
+        if pos.any():
+            uniq, inv = np.unique(col[pos], return_inverse=True)
+            cdv[pos] = inv.astype(np.int32)
+            n_uniq = len(uniq)
+        return cdv, n_uniq
 
     def _distinct(keys_arr, exclude_host: bool):
         ids = np.unique(np.asarray(keys_arr).reshape(-1))
@@ -241,13 +264,8 @@ def batch_tables(tsc_topo, aff_topo, node_label_vals, hostname_id: int):
     d_max = 1
     rows = []
     for k in sp_ids:
-        col = lv[:, k]
-        cdv = np.full(n_cap, -1, np.int32)
-        pos = col >= 0
-        if pos.any():
-            uniq, inv = np.unique(col[pos], return_inverse=True)
-            cdv[pos] = inv.astype(np.int32)
-            d_max = max(d_max, len(uniq))
+        cdv, n_uniq = _compact(lv[:, k])
+        d_max = max(d_max, n_uniq)
         rows.append(cdv)
     kd = bucket_cap(max(len(sp_ids), 1), 1)
     sp_keys = np.full(kd, -1, np.int32)
@@ -255,6 +273,16 @@ def batch_tables(tsc_topo, aff_topo, node_label_vals, hostname_id: int):
     sp_cdv_tab = np.full((kd, n_cap), -1, np.int32)
     for i, r in enumerate(rows):
         sp_cdv_tab[i] = r
+
+    sp_host_cdv = None
+    if (
+        not hostnames_unique
+        and 0 <= hostname_id < K
+        and (np.asarray(tsc_topo) == hostname_id).any()
+    ):
+        cdv, n_uniq = _compact(lv[:, hostname_id])
+        if n_uniq < int((cdv >= 0).sum()):
+            sp_host_cdv = jnp.asarray(cdv)
 
     ip_ids = _distinct(aff_topo, exclude_host=False)
     kd2 = bucket_cap(max(len(ip_ids), 1), 1)
@@ -264,6 +292,7 @@ def batch_tables(tsc_topo, aff_topo, node_label_vals, hostname_id: int):
     return dict(
         sp_keys=jnp.asarray(sp_keys),
         sp_cdv_tab=jnp.asarray(sp_cdv_tab),
+        sp_host_cdv=sp_host_cdv,
         ip_keys=jnp.asarray(ip_keys),
         d_cap=bucket_cap(d_max, 8),
     )
@@ -286,6 +315,8 @@ def precompute(
     ip_keys=None,
     sig=None,
     rep_pod=None,
+    d_cap=None,
+    sp_host_cdv=None,
 ) -> GangStatics:
     """When a has_* flag is False the corresponding statics are built with a
     ZERO-width constraint axis; the scan step's reductions over that axis
@@ -293,6 +324,16 @@ def precompute(
     driven rather than flag-plumbed).  ``enabled`` reflects the profile's
     Filter plugin set.  sp_keys/sp_cdv_tab/ip_keys come from batch_tables();
     they are required whenever the matching has_* flag is set.
+
+    The spread aggregates are summed by topology KEY over the key's compact
+    node→domain map (``sp_cdv_tab``; every row of a key reads the same map),
+    a [P, C, d_cap, N] compare+reduce (``compact_domain_stats``) — never
+    with a segment-id vector private to a (pod, slot) row, which lowers to
+    P·C·N scalar scatters and gathers.  ``d_cap`` (static) is batch_tables'
+    bucket; None: the node count, which bounds every compact id.  The
+    hostname key is in no table: with ``sp_host_cdv`` None every node is its
+    own domain (no two share a hostname value) and the aggregate is the
+    identity; else it is summed like any other key by that map.
 
     ``sig`` i32 [P] / ``rep_pod`` i32 [U] (wave.static_signatures): the
     statics are computed for the batch's U representative rows instead of
@@ -336,49 +377,63 @@ def precompute(
             _, C, _ = spre.dv.shape
             cnt_n = per_node_counts(spre.sel_match.astype(I32), dc.epod_node, N)
             te = spre.tracked[:, None, :] & spre.eligible
-            dom_tot, dom_pres, _, n_dom = domain_stats(
-                jnp.where(te, cnt_n, 0), te, spre.dv, v_cap
-            )
             soft = spre.exists & ~db.tsc_hard
             topo_present = spre.dv >= 0
             all_keys = jnp.all(~soft[:, :, None] | topo_present, axis=1)  # [P, N]
             counting = all_keys[:, None, :] & spre.eligible
-            sc_dom, _, _, _ = domain_stats(
-                jnp.where(counting, cnt_n, 0), counting, spre.dv, v_cap
-            )
             b_sel = eval_table(db.tsc_table, db.labels, dc.val_ints)  # [P, C, J]
             same_ns = db.ns_id[:, None] == db.ns_id[None, :]
             sp_bmatch = b_sel & same_ns[:, None, :] & db.valid[None, None, :]
             if sp_keys is None:
-                # Missing tables would silently zero n_dom for every non-host
-                # soft constraint (wrong topologyNormalizingWeight) — fail loud.
+                # Missing tables would silently zero every non-host domain
+                # aggregate (and the topologyNormalizingWeight) — fail loud.
                 raise ValueError(
                     "precompute: sp_keys/sp_cdv_tab (from batch_tables) are "
                     "required when has_spread is set"
                 )
+            k_eq = (db.tsc_topo[:, :, None] == sp_keys[None, None, :]) & (
+                sp_keys >= 0
+            )[None, None, :]  # [P, C, Kd]
+            any_k = jnp.any(k_eq, axis=-1)
+            ki = jnp.argmax(k_eq, axis=-1)
+            sp_cdv = jnp.where(any_k[:, :, None], sp_cdv_tab[ki], -1)  # [P, C, N]
+            is_host = db.tsc_topo == hostname_key  # [P, C]
+            host_pc = is_host[:, :, None]
+            if sp_host_cdv is None:
+                agg_cdv, agg_cap = sp_cdv, (N if d_cap is None else d_cap)
             else:
-                k_eq = (db.tsc_topo[:, :, None] == sp_keys[None, None, :]) & (
-                    sp_keys >= 0
-                )[None, None, :]  # [P, C, Kd]
-                any_k = jnp.any(k_eq, axis=-1)
-                ki = jnp.argmax(k_eq, axis=-1)
-                sp_cdv = jnp.where(
-                    any_k[:, :, None], sp_cdv_tab[ki], -1
-                )  # [P, C, N]
+                agg_cdv, agg_cap = jnp.where(host_pc, sp_host_cdv, sp_cdv), N
+
+            def by_domain(gate):
+                """(total, present, n_domains) of the gated matching counts
+                over each node's domain; 0 / False where the key is absent"""
+                cnt = jnp.where(gate, cnt_n, 0)
+                tot, pres, n = compact_domain_stats(cnt, gate, agg_cdv, agg_cap)
+                if sp_host_cdv is None:
+                    # a hostname slot's compact ids are all absent: every
+                    # node that carries the label is its own domain
+                    own = host_pc & topo_present & gate
+                    tot = jnp.where(own, cnt, tot)
+                    pres = pres | own
+                    n = n + jnp.sum(own.astype(I32), axis=-1)
+                return tot, pres, n
+
+            dom_tot, dom_pres, n_dom = by_domain(te)
+            sc_dom, _, _ = by_domain(counting)
             sp = dict(
                 sp_hard=spre.exists & db.tsc_hard,
                 sp_soft=soft,
                 sp_dv=spre.dv,
                 sp_te=te,
-                sp_dom_cnt=jnp.where(dom_pres, dom_tot, 0),
+                sp_dom_cnt=dom_tot,
                 sp_dom_pres=dom_pres,
                 sp_ndom=n_dom,
                 sp_self=spre.self_match,
                 sp_bmatch=sp_bmatch,
-                sp_is_host=db.tsc_topo == hostname_key,
+                sp_is_host=is_host,
                 sp_counting=counting,
                 sp_node_cnt=cnt_n,
-                sp_sc_dom=jnp.where(spre.dv >= 0, sc_dom, 0),
+                sp_sc_dom=sc_dom,
                 sp_all_keys=all_keys,
                 sp_cdv=sp_cdv,
             )
@@ -1299,7 +1354,7 @@ def gang_schedule(
 
 # ktpu: axes(dc=DeviceCluster, db=DeviceBatch, hostname_key=i32, extra_mask=bool[P,N])
 # ktpu: axes(nom_node=i32[G], nom_prio=i32[G], nom_req=i32[G,Rn], extra_score=i64[P,N])
-# ktpu: axes(sp_keys=i32[Kd], sp_cdv_tab=i32[Kd,N], ip_keys=i32[Kd2])
+# ktpu: axes(sp_keys=i32[Kd], sp_cdv_tab=i32[Kd,N], sp_host_cdv=i32[N], ip_keys=i32[Kd2])
 # ktpu: axes(sample_k=i32, sample_start=i32, tie_key=key, attempt_base=i32)
 # ktpu: accum(i64, i32, bool)
 # ktpu: static(v_cap=16)
@@ -1337,6 +1392,7 @@ def gang_run(
     sp_keys=None,
     sp_cdv_tab=None,
     ip_keys=None,
+    sp_host_cdv=None,
     d_cap: int = 8,
     extra_score=None,
     fit_strategy: tuple = DEFAULT_FIT_STRATEGY,
@@ -1361,6 +1417,8 @@ def gang_run(
         sp_keys=sp_keys,
         sp_cdv_tab=sp_cdv_tab,
         ip_keys=ip_keys,
+        d_cap=d_cap,
+        sp_host_cdv=sp_host_cdv,
     )
     return gang_schedule(
         dc,

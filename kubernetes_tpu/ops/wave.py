@@ -1044,7 +1044,7 @@ def wave_schedule(
 # ktpu: axes(tid_ip=i32[P,A], rep_ip_p=i32[Tip], rep_ip_u=i32[Tip], ip_cdv_tab=i32[Kd2,N])
 # ktpu: axes(tid_pt=i32[P,UP], port_conf=bool[Tpt,Tpt])
 # ktpu: axes(nom_node=i32[G], nom_prio=i32[G], nom_req=i32[G,Rn], extra_score=i64[P,N])
-# ktpu: axes(sp_keys=i32[Kd], sp_cdv_tab=i32[Kd,N], ip_keys=i32[Kd2])
+# ktpu: axes(sp_keys=i32[Kd], sp_cdv_tab=i32[Kd,N], sp_host_cdv=i32[N], ip_keys=i32[Kd2])
 # ktpu: axes(sample_k=i32, sample_start=i32, tie_key=key, attempt_base=i32)
 # ktpu: axes(sig=i32[P], rep_pod=i32[U])
 # ktpu: accum(i64, i32, bool)
@@ -1090,6 +1090,7 @@ def wave_run(
     sp_keys=None,
     sp_cdv_tab=None,
     ip_keys=None,
+    sp_host_cdv=None,
     d_cap: int = 8,
     d2_cap: int = 8,
     extra_score=None,
@@ -1125,6 +1126,8 @@ def wave_run(
         sp_keys=sp_keys,
         sp_cdv_tab=sp_cdv_tab,
         ip_keys=ip_keys,
+        d_cap=d_cap,
+        sp_host_cdv=sp_host_cdv,
         sig=sig,
         rep_pod=rep_pod,
     )

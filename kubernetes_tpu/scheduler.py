@@ -2709,7 +2709,10 @@ class Scheduler:
     def _gang_tables(self, pb, vocab):
         """batch_tables' device arrays, reused across batches with the same
         key sets + node labels (re-uploading them each batch costs a
-        host→device transfer per table)."""
+        host→device transfer per table).  Called once a dispatch: one whose
+        statics sum the hostname key by its domain map, not by node identity
+        (``sp_host_cdv``: a hostname spread slot in the batch and two nodes
+        under one hostname value), counts one ``statics.host_by_domain``."""
         import numpy as np
 
         hk_id = vocab.label_keys.lookup(HOSTNAME_LABEL)
@@ -2726,8 +2729,12 @@ class Scheduler:
                 pb.aff_topo_key,
                 self.mirror.nodes.label_vals,
                 hk_id,
+                hostnames_unique=self.mirror.hostnames_unique,
             )
             self._tables_key = tkey
+        if self._tables["sp_host_cdv"] is not None:
+            self.prom.statics_host_by_domain.inc()
+            self.phases.count("statics.host_by_domain", 1)
         return self._tables
 
     def _wave_tables_for(self, pb, breaker: Optional[str] = None):
