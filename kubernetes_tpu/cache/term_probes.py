@@ -3,8 +3,7 @@
 A probe is one selector-with-namespace-scope through which a pod's
 (anti-)affinity or spread term could interact with a newcomer.  The fast
 gate (``Scheduler._fast_gate_ok``) asks "could any placed pod's term admit
-this newcomer" of the cache's DISTINCT probes (``ProbeRegistry``); the wave's
-interaction sweep (``ops.wave.interaction_groups``) asks the batch's own.
+this newcomer" of the cache's DISTINCT probes (``ProbeRegistry``).
 Conservative: may claim interaction where none exists (only costs fast-path
 eligibility, never correctness).
 """
@@ -117,8 +116,8 @@ def probe_entries(pod: Pod) -> Tuple[Tuple[object, _Probe], ...]:
     """``(content key, probe)`` for each of ``_pod_probes(pod)``, memoized ON
     the pod object (spec updates arrive as new Pod objects, the
     ``compute_requests`` memo pattern).  The cache's assumed copy is made
-    from the queued pod's ``__dict__``, so what a wave's interaction sweep
-    derived for a batch pod is what the registry counts when that pod is
+    from the queued pod's ``__dict__``, so what was derived for a batch pod
+    before its commit is what the registry counts when that pod is
     committed."""
     d = pod.__dict__
     entries = d.get("_probe_entries_memo")
@@ -129,9 +128,9 @@ def probe_entries(pod: Pod) -> Tuple[Tuple[object, _Probe], ...]:
     return entries
 
 
-# One batch's sweep of probes is bounded (the bound the wave's interaction
-# sweep has always had): past this many admits() evaluations the asker
-# answers conservatively instead of finishing the sweep.
+# One batch's sweep of probes is bounded: past this many admits()
+# evaluations the asker (the fast gate, ``routing.py``) answers
+# conservatively instead of finishing the sweep.
 MAX_PROBES_ASKED = 100_000
 
 

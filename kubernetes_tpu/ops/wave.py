@@ -395,78 +395,6 @@ def static_signatures(pb, u_cap: int = STATIC_SIG_CAP):
     )
 
 
-def interaction_groups(pods):
-    """Partition a batch into components of mutually-interacting pods by
-    topology-term / affinity-probe footprint (fastpath-style host probes).
-
-    Two pods land in one group when they share a constraint term
-    (spec-content identity) or one pod's term selector ADMITS the other
-    (the probe direction — anti-affinity constrains pods that carry no
-    terms themselves).  Conservative by construction: probes may claim
-    interaction where none exists, never the reverse.  Non-interacting
-    groups' placements are independent post-decision, so their binding
-    runs flow through the bulk-commit path concurrently.
-
-    Returns (group_id per pod, n_groups).
-    """
-    from kubernetes_tpu.cache.term_probes import MAX_PROBES_ASKED, probe_entries
-
-    n = len(pods)
-    parent = list(range(n))
-
-    def find(x: int) -> int:
-        while parent[x] != x:
-            parent[x] = parent[parent[x]]
-            x = parent[x]
-        return x
-
-    def union(a: int, b: int) -> None:
-        ra, rb = find(a), find(b)
-        if ra != rb:
-            parent[rb] = ra
-
-    # dedup probes by content so template-stamped pods share one probe and
-    # the admits sweep runs per (probe, label-group) pair, not per pod²
-    probe_owner: dict = {}
-    probes = []  # (owner pod index, probe) — distinct by content
-    for i, pod in enumerate(pods):
-        for key, pr in probe_entries(pod):
-            if key is None:
-                probes.append((i, pr))
-                continue
-            owner = probe_owner.get(key)
-            if owner is None:
-                probe_owner[key] = i
-                probes.append((i, pr))
-            else:
-                union(i, owner)  # same term content ⇒ same group
-    # The admits sweep memoizes by (namespace, labels) group; batches of
-    # pods with DISTINCT label sets defeat the cache, so bound the worst
-    # case: past ~100k (probe, pod) pairs fall back to one conservative
-    # all-interacting component (a single bulk run — always safe).
-    if len(probes) * n > MAX_PROBES_ASKED:
-        return [0] * n, 1
-    hit_cache: dict = {}
-    for i, pod in enumerate(pods):
-        try:
-            lg = (pod.namespace, tuple(sorted(pod.labels.items())))
-        except TypeError:
-            lg = None
-        hits = hit_cache.get(lg) if lg is not None else None
-        if hits is None:
-            hits = [j for j, (_, pr) in enumerate(probes) if pr.admits(pod)]
-            if lg is not None:
-                hit_cache[lg] = hits
-        for j in hits:
-            union(i, probes[j][0])
-    roots: dict = {}
-    gids = []
-    for i in range(n):
-        r = find(i)
-        gids.append(roots.setdefault(r, len(roots)))
-    return gids, len(roots)
-
-
 # ---------------------------------------------------------------------------
 # Device kernels
 # ---------------------------------------------------------------------------

@@ -1921,11 +1921,11 @@ class Scheduler:
 
         # 3. per-pod commit: assume → reserve → permit → bind.  Wave
         # batches additionally resolve their speculation stats and, when
-        # the framework allows lean binds, commit through the bulk path
-        # split by interaction group.
-        wave_groups = None
+        # the framework allows lean binds, commit their successes as ONE
+        # bulk run.
+        wave_bulk = False
         if wstats_dev is not None:
-            wave_groups = self._wave_resolve(
+            wave_bulk = self._wave_resolve(
                 fwk,
                 batch,
                 chosen,
@@ -1945,7 +1945,7 @@ class Scheduler:
             outcomes,
             host_diags,
             host_plugin_sets,
-            wave_groups=wave_groups,
+            wave_bulk=wave_bulk,
             kernel=kroot,
         )
         trace.step("Commits done")
@@ -1963,15 +1963,16 @@ class Scheduler:
         outcomes,
         host_diags=None,
         host_plugin_sets=None,
-        wave_groups=None,
+        wave_bulk=False,
         kernel=None,
     ) -> None:
         """The per-pod result walk shared by the direct and chained paths:
         failures → diagnosis + PostFilter, successes → _commit (which hands
-        binding to the async workers).  ``wave_groups`` (per-pod
-        interaction-group ids from the wave partitioner) routes successes
-        through the bulk-commit path instead, one bulk run per group, so
-        non-interacting groups' bindings flow concurrently."""
+        binding to the async workers).  ``wave_bulk`` (_wave_resolve's
+        verdict: a wave batch whose commits may skip the per-pod walk)
+        routes ALL the batch's successes through the bulk-commit path
+        instead, as one run and one bulk binding task, which _submit_binds
+        slices across the workers."""
         sp_commit = self._span("commit").begin()
         node_names = self.mirror.nodes.names
         n_nodes = len(self.cache.real_nodes())
@@ -2002,7 +2003,7 @@ class Scheduler:
         # per-op dict atomicity
         with self._mu:
             self.metrics["schedule_attempts"] += len(batch)
-        bulk_by_group: Dict[int, list] = {}
+        placed: List[int] = []
         for i, qp in enumerate(batch):
             idx = int(chosen[i])
             if idx < 0:
@@ -2033,16 +2034,15 @@ class Scheduler:
                     )
                 )
                 continue
-            if wave_groups is not None:
-                bulk_by_group.setdefault(wave_groups[i], []).append(i)
+            if wave_bulk:
+                placed.append(i)
                 continue
             node_name = node_names[idx]
             outcome = self._commit(fwk, state, qp, node_name, int(n_feas[i]))
             outcomes.append(outcome)
-        # wave bulk tail: one vectorized assume + one bulk bind task per
-        # interaction group (decisions are final; non-interacting groups'
-        # binds are independent, so each group rides its own task)
-        for gidxs in bulk_by_group.values():
+        # wave bulk tail: one vectorized assume + one bulk bind task for
+        # the batch's successes (decisions are final)
+        if placed:
             self._commit_fast_bulk(
                 fwk,
                 state,
@@ -2052,7 +2052,7 @@ class Scheduler:
                 0,
                 node_names,
                 outcomes,
-                idxs=gidxs,
+                idxs=placed,
                 n_feas=n_feas,
                 nonfast=True,
             )
@@ -2638,9 +2638,9 @@ class Scheduler:
             time.perf_counter() - rec["t0"],
             path="wave" if wstats is not None else "chain",
         )
-        wave_groups = None
+        wave_bulk = False
         if wstats is not None:
-            wave_groups = self._wave_resolve(
+            wave_bulk = self._wave_resolve(
                 rec["fwk"],
                 rec["batch"],
                 both[0],
@@ -2658,7 +2658,7 @@ class Scheduler:
             both[1],
             rec["reasons"],
             outcomes,
-            wave_groups=wave_groups,
+            wave_bulk=wave_bulk,
             kernel="chain.chain_dispatch",
         )
         self._record_batch_metrics(
@@ -3633,10 +3633,10 @@ class Scheduler:
         ``n_terms`` — the batch's distinct cross-pod terms, ``wave_tables``'
         count before the bucket — goes to ``wave.terms``),
         a ``wave_demoted`` flight-recorder event (with the conflicting
-        term) per corrected pod, and — when the framework permits lean
-        binds — the interaction-group split the bulk commit path uses.
-        Returns the per-pod group ids, or None when commits must walk the
-        per-pod path."""
+        term) per corrected pod.  Returns whether the batch's successes
+        may commit as one bulk run (the framework permits lean binds and no
+        per-pod extension point could act); False when commits must walk
+        the per-pod path."""
         import numpy as np
 
         from kubernetes_tpu.ops import wave as wave_ops
@@ -3699,7 +3699,6 @@ class Scheduler:
         # carry host-filter-relevant pods (the extra_mask route), whose
         # Reserve/PreBind walks must run, so prove irrelevance per pod
         # before routing anything around the per-pod commit path.
-        groups = None
         hf = fwk.host_filter_plugins()
         hf_clean = not hf or not any(
             pl.maybe_relevant(qp.pod) for qp in batch for pl in hf
@@ -3707,21 +3706,14 @@ class Scheduler:
         rp_ok = not fwk.has_reserve_or_permit() or (
             fwk.reserve_permit_covered_by_host_filters() and hf_clean
         )
-        if (
+        bulk_ok = (
             fwk.lean_bind_ok()
             and hf_clean
             and rp_ok
             and not self.extenders
-        ):
-            groups, n_groups = wave_ops.interaction_groups(
-                [qp.pod for qp in batch]
-            )
-            with self._mu:
-                self.metrics["wave_groups"] = (
-                    self.metrics.get("wave_groups", 0) + n_groups
-                )
+        )
         sp_resolve.end()
-        return groups
+        return bulk_ok
 
     def _static_device_cluster(self) -> DeviceCluster:
         """DeviceCluster cached across batches for STATIC reads only
@@ -5714,8 +5706,8 @@ class Scheduler:
         (_commit_under_lock) whenever reserve/permit could act or a
         non-default binder is configured — see _finish_fast's bulk_ok.
 
-        ``idxs`` replaces the [i:j) slice with an explicit index list (the
-        wave path's per-interaction-group runs); ``n_feas`` supplies
+        ``idxs`` replaces the [i:j) slice with an explicit index list (a
+        wave batch's placed pods); ``n_feas`` supplies
         per-pod feasible counts for the outcomes (-1 otherwise);
         ``nonfast`` marks commits the fast committer didn't make, bumping
         the mirror-sync epoch the way per-pod _commit does."""
@@ -5831,6 +5823,7 @@ class Scheduler:
             self._submit_binds(chunk)
 
     def _submit_binds(self, chunk: int) -> None:
+        tasks = 0  # the futures this flush hands to the pool
         bulk = self._bulk_bind_buffer
         if bulk:
             self._bulk_bind_buffer = []
@@ -5857,18 +5850,20 @@ class Scheduler:
                     self._inflight_binds.append(
                         self._bind_pool.submit(self._binding_bulk, part)
                     )
+                    tasks += 1
         buf = self._bind_buffer
-        if not buf:
-            return
-        chunk = min(chunk, max(1, -(-len(buf) // max(self.config.parallelism, 1))))
-        self._bind_buffer = []
-        self._ensure_bind_pool()
-        for i in range(0, len(buf), chunk):
-            part = buf[i : i + chunk]
-            part[0].t_submit = time.perf_counter()
-            self._inflight_binds.append(
-                self._bind_pool.submit(self._binding_chunk, part)
-            )
+        if buf:
+            chunk = min(chunk, max(1, -(-len(buf) // max(self.config.parallelism, 1))))
+            self._bind_buffer = []
+            self._ensure_bind_pool()
+            for i in range(0, len(buf), chunk):
+                part = buf[i : i + chunk]
+                part[0].t_submit = time.perf_counter()
+                self._inflight_binds.append(
+                    self._bind_pool.submit(self._binding_chunk, part)
+                )
+                tasks += 1
+        self.phases.count("bind.tasks", tasks)
 
     def _binding_bulk(self, t: "_BulkBindTask") -> None:
         """One worker's slice of a bulk fast-path binding run.

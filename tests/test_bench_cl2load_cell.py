@@ -18,6 +18,13 @@ positions, with the zone constraint alone stripped at many.  The pods of a
 batch carry many distinct terms (``wave.terms``: two a Deployment present),
 more distinct rows than the statics' signature table holds (``wave.static_full``
 every batch), and what a wave demotes it demotes on SCORE.
+
+Since PR 50 a batch here binds as ONE task, whatever its Deployments: 16
+probes x 32 pods is far under the cap at which the interaction sweep gave up
+(the full-size cell's short last batch, 368 pods of ≈ 260 Deployments, stood
+there at 92 % of seeds), so until then every batch of this cut bound one task
+a Deployment present.  ``bind.tasks`` counts them and
+``served.bind_tasks_per_kpod.backlog`` reads it.
 """
 
 import collections
@@ -42,6 +49,8 @@ WAVES = PODS // BATCH
 NEW_METRICS = {f"{m}.backlog": c for m, c in (
     ("loop.wave_terms_per_kpod", "wave.terms"), ("loop.wave_conflicts_score_per_kpod", "wave.conflicts.score"),
     ("loop.wave_static_full_per_kpod", "wave.static_full"))}
+TASKS_METRIC = "served.bind_tasks_per_kpod.backlog"
+CROSS_POD_CELLS = ["spread-5k.backlog", "interpod-5k.backlog", "antiaffinity-5k.backlog", CELL]
 STAGE_METRIC = "kernels.stage_ms_per_kpod.spread_constraints.backlog"
 SEED = 4800000007
 
@@ -69,6 +78,13 @@ def run():
     def watch(cluster):
         _small_batches(cluster)
         _watch(seen)(cluster)
+        release = cluster.release_loop
+
+        def release_loop():
+            seen["txns0"] = (cluster.apiserver.bulk_bind_txns, cluster.apiserver.bulk_bind_items)
+            release()
+
+        cluster.release_loop = release_loop
 
     def at_position(replay, pos, spec, decided, want):
         seen["decided"].append(decided)
@@ -81,6 +97,9 @@ def run():
         )
     cluster = seen.pop("cluster")  # the scheduler itself is let go
     seen["window"] = cluster.sched.phases.diff(seen.pop("phases1"), seen.pop("phases0"))
+    txns0, items0 = seen.pop("txns0")
+    seen["bind_txns"] = (cluster.apiserver.bulk_bind_txns - txns0, cluster.apiserver.bulk_bind_items - items0,
+                         cluster.apiserver.bulk_bind_fallback_items)
     seen["controls"] = controls.readings()
     return res, seen, bench
 
@@ -130,6 +149,19 @@ def test_a_batch_holds_many_terms_more_rows_than_the_table_and_demotes_on_score(
     assert window["wave.epod_rows"] == sum(PLACED + first for first in range(0, PODS, BATCH))
 
 
+def test_every_batch_binds_as_one_task_and_one_bulk_transaction_whatever_its_deployments(run):
+    """Ten batches of 32 pods of 8 to 16 Deployments: ten commit runs, ten
+    bind tasks, ten bulk transactions in the emulated API server and not one
+    single-pod POST (a task of ONE pod takes the per-pod sink), where the
+    parent of PR 50 bound a task a Deployment present."""
+    res, seen, _bench = run
+    window = seen["window"]
+    assert window["bind.tasks"] == WAVES
+    assert seen["bind_txns"] == (WAVES, PODS, 0)  # transactions, the pods they carried, per-item fallbacks
+    assert res["compared"]["identity.decisions_differing_from_reference"]["value"] == 0
+    assert res["compared"]["identity.positions_compared"]["value"] == PODS
+
+
 @pytest.mark.parametrize("control,least,most", [
     ("all_stripped", PODS // 2, PODS), ("zone_stripped", PODS // 3, PODS), ("hostname_stripped", PODS // 3, PODS),
     ("stale_lag1", PODS // 8, PODS // 2), (f"stale_lag{BATCH}", 3 * PODS // 4, PODS - 1),
@@ -167,6 +199,34 @@ def test_the_new_metrics_read_the_windows_counters_through_the_phase_reader(run)
     # at the source's counts: twelve batches of about 340 Deployments for 6,000 pods
     terms = listed["loop.wave_terms_per_kpod.backlog"]
     assert terms["read"]({"phases": {"wave.terms": 8160.0}, "pods_in_window": 6000}, terms["params"]) == 1360.0
+
+
+def test_the_bind_tasks_metric_reads_the_windows_counter_and_divides_it_by_the_pods_bound(run):
+    _res, seen, bench = run
+    spec = {s["name"]: s for s in cells.layer_metrics(CELL, bench)}[TASKS_METRIC]
+    assert (spec["reader"], spec["layer"], spec["unit"], spec["source"]) == ("phase", "served path", "tasks/kpod", "program_counter")
+    assert spec["params"] == {"phases": ["bind.tasks"]} and spec["better"] == "lower" and spec["moves"] == "pods_per_s"
+    assert spec["read"]({"phases": seen["window"], "pods_in_window": PODS}, spec["params"]) == 1000.0 * WAVES / PODS
+    # at the cells' counts: one task a batch of 512 (twelve for 6,000 pods, ten for 5,000, four for 2,000);
+    # the parent of PR 50 on a swept last batch of this cell: 259 to 281 tasks a window (270 and 281 in two traced runs)
+    def read(tasks, pods):
+        return spec["read"]({"phases": {"bind.tasks": tasks}, "pods_in_window": pods}, spec["params"])
+
+    assert (read(12.0, 6000), read(10.0, 5000), read(4.0, 2000)) == (2.0, 2.0, 2.0)
+    assert 45.0 <= read(270.0, 6000) < read(281.0, 6000) < 47.0
+    # no window: nothing said.  A program without the counter (the parent) reads 0.0 and raises nothing
+    assert spec["read"]({"phases": {}, "pods_in_window": PODS}, spec["params"]) is None
+    assert spec["read"]({"phases": {"bind": 1.0}, "pods_in_window": PODS}, spec["params"]) == 0.0
+
+
+@pytest.mark.parametrize("cell", [c["name"] for c in cells.benchmark()["workloads"]])
+def test_the_bind_tasks_metric_is_listed_in_the_four_cross_pod_cells_and_no_other(cell):
+    bench = cells.benchmark()
+    listed = {s["name"] for s in cells.layer_metrics(cell, bench)}
+    assert (TASKS_METRIC in listed) == (cell in CROSS_POD_CELLS)
+    entry = next(m for m in bench["per_layer"] if m["name"] == TASKS_METRIC)
+    assert entry == {"name": TASKS_METRIC, "unit": "tasks/kpod", "better": "lower", "source": "program_counter",
+                     "layer": "served path", "moves": "pods_per_s", "workloads": CROSS_POD_CELLS}
 
 
 # ---- the plan at the file's own size: nothing runs ----------------------------

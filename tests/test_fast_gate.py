@@ -484,16 +484,18 @@ def test_the_registrys_reference_counts_follow_every_route_of_count_pod():
     assert _counts(sched) == []
 
 
-def test_what_the_waves_sweep_derived_for_a_batch_pod_is_what_the_registry_counts_at_its_commit():
-    """The registry's upkeep derives nothing a second time: the wave's interaction sweep keys every batch
-    pod's probes, the memo rides the assumed copy, and ``add`` / ``remove`` read it."""
-    from kubernetes_tpu.ops.wave import interaction_groups
+def test_what_was_derived_once_for_a_batch_pod_is_what_the_registry_counts_at_its_commit():
+    """The registry's upkeep derives nothing a second time: ``probe_entries`` keys a batch pod's probes
+    once, for whoever asks first, the memo rides the assumed copy, and ``add`` / ``remove`` read it."""
+    from kubernetes_tpu.cache.term_probes import probe_entries
 
     sched, _ = _mk(4)
     cache = sched.cache
     batch = [_anti_pod("w0"), _anti_pod("w1"), _anti_pod("w2", group="other")]
-    assert interaction_groups(batch) == ([0, 0, 1], 2)  # one template, one group: keyed by content
+    keys = [[key for key, _pr in probe_entries(p)] for p in batch]
+    assert keys[0] == keys[1] != keys[2] and len(keys[0]) == 1  # one template, one key: keyed by content
     memos = [p.__dict__["_probe_entries_memo"] for p in batch]
+    assert all(probe_entries(p) is m for p, m in zip(batch, memos))  # asked again: the memo, not a second derivation
     with sched._mu:
         assumed = cache.assume_pods_bulk([(p, f"n{i}") for i, p in enumerate(batch)])
     assert [a.__dict__["_probe_entries_memo"] for a in assumed] == memos
